@@ -19,6 +19,8 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "artifacts")
 
 ENGINE_BACKENDS = ("lax", "pallas", "matmul")
@@ -71,6 +73,7 @@ def bench_cli(run_fn, argv=None) -> None:
         elif isinstance(d, (int, float, str)):
             ap.add_argument(flag, type=type(d), default=d)
     args = vars(ap.parse_args(argv))
+    enable_compile_cache()
     for line in run_fn(**{k: v for k, v in args.items() if v is not None}):
         print(line)
 

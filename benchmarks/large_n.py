@@ -47,7 +47,7 @@ import numpy as np
 from repro.core import build_plan, execute_plan, random_geometric_graph
 from repro.core.plan_cache import setup_plan
 
-from .common import csv_line, save_artifact, timed
+from .common import csv_line, enable_compile_cache, save_artifact, timed
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -225,6 +225,7 @@ if __name__ == "__main__":
     args = ap.parse_args()
     if args.smoke:
         args.n, args.artifact = 20_000, args.artifact or "large_n_smoke"
+    enable_compile_cache()
     for line in run(
         n=args.n, overlap_n=args.overlap_n, trials=args.trials,
         eps=args.eps, fixed_ticks_scale=args.scale, backend=args.backend,

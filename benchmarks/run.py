@@ -17,6 +17,7 @@ aggregated here if present.
 from __future__ import annotations
 
 import argparse
+import os
 import subprocess
 import sys
 import traceback
@@ -33,6 +34,15 @@ def main() -> None:
     ap.add_argument("--schedule", default="presampled",
                     help="engine schedule mode for every figure benchmark")
     args = ap.parse_args()
+    keep = set(args.only.split(",")) if args.only else None
+
+    # the sync suite lowers on 32 emulated host devices in a process of
+    # its own; it runs to its end before this process imports JAX, so
+    # the two never hold a device at once
+    sync_proc = (_run_child("benchmarks.sync_collectives")
+                 if keep is None or "sync" in keep else None)
+
+    from repro.launch.compile_cache import enable_compile_cache
 
     from . import (
         fig2_levels, fig3_vs_path_averaging, fig4_cdf, fig5_failures,
@@ -40,6 +50,7 @@ def main() -> None:
         table1_node_utilization,
     )
 
+    enable_compile_cache()
     # figure suites share one run() signature; each entry is
     # (module, default-profile kwargs, --full overrides)
     figures = {
@@ -65,7 +76,7 @@ def main() -> None:
     suites = {name: fig_suite(*spec) for name, spec in figures.items()}
     suites.update({
         "kernels": kernel_bench.run,
-        "sync": lambda: _subprocess_lines("benchmarks.sync_collectives"),
+        "sync": lambda: _child_lines(sync_proc),
         "roofline": roofline.run,
         "gossip": gossip_trajectory.run,
         "large_n": lambda: large_n.run(
@@ -73,11 +84,11 @@ def main() -> None:
         ),
         "serve": serve_bench.run,
     })
-    if args.only:
-        keep = set(args.only.split(","))
+    if keep is not None:
         suites = {k: v for k, v in suites.items() if k in keep}
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in suites.items():
         try:
             for line in fn():
@@ -85,18 +96,25 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc(file=sys.stderr)
             print(f"{name}/ERROR,0.0,{type(e).__name__}: {e}", flush=True)
+            failed.append(name)
+    if failed:
+        raise SystemExit(f"failed suites: {', '.join(failed)}")
 
 
-def _subprocess_lines(module: str) -> list[str]:
+def _run_child(module: str) -> subprocess.CompletedProcess:
     """Run a benchmark that needs its own XLA device count in a fresh
-    process (the forced count must precede jax init)."""
-    proc = subprocess.run(
+    process on the CPU (the forced count must precede jax init)."""
+    return subprocess.run(
         [sys.executable, "-m", module], capture_output=True, text=True,
-        timeout=1800,
+        timeout=1800, env=dict(os.environ, JAX_PLATFORMS="cpu"),
     )
+
+
+def _child_lines(proc: subprocess.CompletedProcess) -> list[str]:
     if proc.returncode != 0:
-        return [f"{module}/ERROR,0.0,exit={proc.returncode}: "
-                f"{proc.stderr.strip().splitlines()[-1] if proc.stderr else ''}"]
+        raise RuntimeError(
+            f"{proc.args[-1]} exited {proc.returncode}:\n"
+            f"{proc.stderr[-3000:]}")
     return [l for l in proc.stdout.splitlines() if l.strip()]
 
 

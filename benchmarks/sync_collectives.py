@@ -72,7 +72,6 @@ def run(wallclock: bool = False) -> list[str]:
         wire_fraction,
     )
     from repro.launch.hlo_analysis import collective_bytes, device_pod_map
-    from repro.launch.mesh import set_mesh
     from .common import csv_line, load_artifact, save_artifact
 
     R = 32
@@ -175,7 +174,7 @@ def run(wallclock: bool = False) -> list[str]:
     for name, cfg_s in strategies.items():
         plan = build_sync_plan(cfg_s, R)
         compressed = cfg_s.compression.scheme != "none"
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if compressed:  # residuals ride along as a second input pytree
                 fn = lambda g, r, s, p=plan: execute_sync(p, g, r, s)
                 jitted = jax.jit(fn, in_shardings=(sh, sh, step_sh),

@@ -29,10 +29,10 @@ compiled call.  `mesh=` shards that computation over real hardware:
 Backends: ``backend="lax"`` is the reference inner kernel;
 ``backend="pallas"`` walks each chunk's presampled schedule with the
 `kernels.pair_apply` TPU kernel, streaming cell state through VMEM in
-cell blocks (bitwise-identical to lax; non-TPU hosts dispatch to the
-jnp oracle); ``backend="matmul"`` composes each chunk's mixing matrix
-with a log2 tree of batched MXU matmuls (values agree up to f32
-rounding).  ``schedule="per_tick"`` keeps the legacy sequential scan as
+cell blocks (bitwise-identical to lax; off the TPU the kernel runs in
+the Pallas interpreter); ``backend="matmul"`` composes each chunk's
+mixing matrix with a log2 tree of batched MXU matmuls (values agree up
+to f32 rounding).  ``schedule="per_tick"`` keeps the legacy sequential scan as
 the parity reference (see `core.gossip`).
 """
 from __future__ import annotations
@@ -60,12 +60,13 @@ from .schedule import CsrGraphs
 
 __all__ = ["EngineResult", "execute_plan", "fi_ticks"]
 
-# Lighter XLA pipeline for the executor: these are small scatter/gather
-# loops where full optimization buys nothing measurable at runtime but
-# more than doubles compile time (the single-shot benchmark bottleneck
-# on CPU).  The LLVM expensive-pass cut matters most: the executor's
-# scatter bodies spend their compile budget in LLVM, not in HLO passes.
-_COMPILER_OPTS = {
+# Lighter XLA pipeline for the executor when it is compiled for the
+# CPU: these are small scatter/gather loops where full optimization buys
+# nothing measurable at runtime but more than doubles compile time.  The
+# LLVM expensive-pass cut matters most: the executor's scatter bodies
+# spend their compile budget in LLVM, not in HLO passes.  Other backends
+# compile with their defaults.
+_CPU_COMPILER_OPTS = {
     "xla_backend_optimization_level": 0,
     "xla_llvm_disable_expensive_passes": True,
 }
@@ -331,8 +332,10 @@ def execute_plan(
             "failure scenarios require fixed_ticks_scale > 0: scenario "
             "event times are fractions of the finest level's tick budget, "
             "which the eps-oracle mode leaves unbounded")
+    platform = (mesh.devices.flat[0].platform if mesh is not None
+                else jax.default_backend())
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = platform != "tpu"
     n = plan.graph.n
     x0 = np.asarray(x0, np.float32)
     T = len(seeds)
@@ -545,8 +548,8 @@ def execute_plan(
         jnp.asarray(maxt_levels, jnp.int32),
     )
     cache_key = (
-        T, per_trial_x0, weighted, failures, cost, backend, schedule, mesh,
-        interpret, tuple(chk_levels), collect_usage,
+        platform, T, per_trial_x0, weighted, failures, cost, backend,
+        schedule, mesh, interpret, tuple(chk_levels), collect_usage,
         # scenario event ticks are baked into the trace as constants
         # derived from maxt_levels (see _failure_consts), so executors
         # traced for different tick budgets must not collide
@@ -572,12 +575,11 @@ def execute_plan(
         else:
             run_v = jax.vmap(_run, in_axes=(0 if per_trial_x0 else None, 0, None, None))
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             if node_mesh:
                 Pt = P("trials")
-                run_v = shard_map(
+                run_v = jax.shard_map(
                     run_v, mesh=mesh,
                     in_specs=(Pt if per_trial_x0 else P(), Pt, P(), P()),
                     out_specs=(
@@ -585,20 +587,17 @@ def execute_plan(
                         tuple(P("trials", "nodes") for _ in plan.levels),
                         Pt, Pt, (), (), (),
                     ),
-                    check_rep=False,
+                    check_vma=False,
                 )
             else:
                 (axis,) = mesh.axis_names
-                run_v = shard_map(
+                run_v = jax.shard_map(
                     run_v, mesh=mesh,
                     in_specs=(P(axis) if per_trial_x0 else P(), P(axis), P(), P()),
-                    out_specs=P(axis), check_rep=False,
+                    out_specs=P(axis), check_vma=False,
                 )
-        jitted = jax.jit(run_v)
-        try:
-            fn = jitted.lower(*args).compile(compiler_options=_COMPILER_OPTS)
-        except Exception:  # options unsupported on this backend
-            fn = jitted
+        opts = _CPU_COMPILER_OPTS if platform == "cpu" else None
+        fn = jax.jit(run_v).lower(*args).compile(compiler_options=opts)
         plan.exec_cache[cache_key] = fn
     xf, sends, lm, lt, lc, usages, lretx, lcong = fn(*args)
     if pad:

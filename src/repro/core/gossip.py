@@ -33,9 +33,8 @@ value backend:
 * ``backend="pallas"`` — the `kernels.pair_apply` TPU kernel walks the
   schedule with cell state streamed through VMEM in blocks (no HBM
   round-trips within a block); its f32 op sequence matches the oracle
-  exactly, so results are bitwise-identical to the lax backend (non-TPU
-  hosts dispatch to the oracle; the kernel itself is validated in
-  interpret mode by the kernel tests);
+  exactly, so results are bitwise-identical to the lax backend (off the
+  TPU it runs in the Pallas interpreter, ``interpret=True``);
 * ``backend="matmul"`` — `core.schedule.compose_schedule` folds the
   chunk's elementary pair-average matrices with a log2(T) tree of
   batched matmuls and applies the result via `kernels.cell_mixing`
@@ -331,16 +330,12 @@ def _presampled_chunk(adj, key, loss_p, check_every, backend, interpret,
         if backend == "lax":
             x = pair_apply_ref(x, s.i, s.j, upd_i, upd_j)
         elif backend == "pallas":
-            # non-TPU hosts take the bitwise-identical oracle; the TPU
-            # kernel walks the schedule with the state in VMEM
-            x = pair_apply(x, s.i, s.j, upd_i, upd_j,
-                           use_pallas=not interpret, interpret=interpret)
+            x = pair_apply(x, s.i, s.j, upd_i, upd_j, interpret=interpret)
         else:  # matmul: associative composition, applied on the MXU
             from repro.kernels.cell_mixing import cell_mixing
 
             m = compose_schedule(C, s.i, s.j, upd_i, upd_j, x.dtype)
-            x = cell_mixing(m, x, rounds=1, use_pallas=not interpret,
-                            interpret=interpret)
+            x = cell_mixing(m, x, rounds=1, interpret=interpret)
         ticks = ticks + jnp.where(done, 0, check_every)
         done = done | (err(x) <= tol)
         out = (x, usage, msgs, done, ticks)
@@ -380,8 +375,7 @@ def _per_tick_chunk(adj, key, loss_p, check_every, backend, interpret,
             (m, usage, msgs, done), _ = jax.lax.scan(
                 tick, (eye.astype(x.dtype), usage, msgs, done), ts
             )
-            x = cell_mixing(m, x, rounds=1, use_pallas=True,
-                            interpret=interpret)
+            x = cell_mixing(m, x, rounds=1, interpret=interpret)
         ticks = ticks + jnp.where(done, 0, check_every)
         done = done | (err(x) <= tol)
         return (x, usage, msgs, done, ticks, t0 + check_every)

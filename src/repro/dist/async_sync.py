@@ -33,7 +33,7 @@ Two executors:
 
 `execute_sync_sharded(plan, grads, residuals, step, mesh=...)`
     The same mixing semantics expressed as explicit per-replica
-    collectives under `jax.experimental.shard_map`: the replica axis is
+    collectives under `jax.shard_map`: the replica axis is
     laid out over a mesh shaped like `plan.levels`, per-cell ring
     gossip is `ppermute` along one mesh axis, grouped fusion is `pmean`
     along one mesh axis, and dissemination is a masked-`psum`
@@ -49,8 +49,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .compression import compress, decompress, init_residual
 from .failures import apply_payload_faults, replica_fault_masks
@@ -150,7 +149,9 @@ def _level_mesh(plan: SyncPlan, mesh: Mesh, axis_name: str) -> tuple[Mesh, tuple
     shape = plan.levels if plan.strategy in ("hierarchical", "multiscale") \
         else (plan.R,)
     names = tuple(_AXIS_FMT.format(i) for i in range(len(shape)))
-    return Mesh(mesh.devices.reshape(shape), names), names
+    inner = Mesh(mesh.devices.reshape(shape), names,
+                 axis_types=mesh.axis_types * len(names))
+    return inner, names
 
 
 def _ring_pairs(L: int, shift: int) -> list[tuple[int, int]]:
@@ -358,17 +359,26 @@ def execute_sync_sharded(
             payload = decompress(payload, plan.compression)
             return _mix_body(payload, g, r, new_r, s)
 
-        return shard_map(
-            body, mesh=inner, in_specs=(spec, spec, sspec),
-            out_specs=(spec, spec), check_rep=False,
-        )(grads, residuals, jnp.asarray(step, jnp.int32))
+        args, in_specs, out_specs = (grads, residuals), (spec, spec), (spec, spec)
+    else:
+        def body(g, s):
+            mixed, _ = _mix_body(g, g, None, None, s)
+            return mixed
 
-    def body(g, s):
-        mixed, _ = _mix_body(g, g, None, None, s)
-        return mixed
-
-    mixed = shard_map(
-        body, mesh=inner, in_specs=(spec, sspec), out_specs=spec,
-        check_rep=False,
-    )(grads, jnp.asarray(step, jnp.int32))
-    return mixed, residuals
+        args, in_specs, out_specs = (grads,), (spec,), spec
+    mixed = jax.shard_map(
+        body, mesh=inner, in_specs=in_specs + (sspec,),
+        out_specs=out_specs, check_vma=False,
+    )
+    s = jnp.asarray(step, jnp.int32)
+    if AxisType.Explicit not in mesh.axis_types:
+        out = mixed(*args, s)
+    else:
+        # the level axes are a reshape of the caller's replica axis: the
+        # rows stay on their devices, only their sharding type changes
+        with jax.sharding.use_abstract_mesh(inner.abstract_mesh):
+            args = jax.sharding.reshard(args, NamedSharding(inner, spec))
+            s = jax.sharding.reshard(s, NamedSharding(inner, sspec))
+            out = mixed(*args, s)
+        out = jax.sharding.reshard(out, NamedSharding(mesh, P(axis_name)))
+    return out if compressed else (out, residuals)

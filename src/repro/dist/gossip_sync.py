@@ -63,11 +63,13 @@ host-replicated array it is plain arithmetic; under a sharded
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import AxisType
 
 from .compression import compress, decompress, init_residual
 from .failures import apply_payload_faults, replica_fault_masks
@@ -113,7 +115,33 @@ def execute_sync(
             )
     if R == 1:
         return grads, residuals
+    mesh = _explicit_mesh(leaves)
+    if mesh is None:
+        return _execute_sync(plan, grads, residuals, step)
+    # the strategies reshape, roll and gather the replica axis, which an
+    # Explicit mesh axis cannot carry through those ops: mix with the
+    # axes switched to Auto and hand back the callers' shardings
+    spec = lambda a: jax.typeof(a).sharding.spec
+    if residuals is None and plan.compression.scheme != "none":
+        residuals = init_residual(grads)
+    out = (jax.tree.map(spec, grads), jax.tree.map(spec, residuals))
+    with jax.sharding.use_abstract_mesh(mesh):
+        return jax.sharding.auto_axes(
+            partial(_execute_sync, plan), out_sharding=out,
+        )(grads, residuals, step)
 
+
+def _explicit_mesh(leaves):
+    """The abstract mesh of the first leaf typed with Explicit axes."""
+    for leaf in leaves:
+        mesh = jax.typeof(leaf).sharding.mesh
+        if AxisType.Explicit in mesh.axis_types:
+            return mesh
+    return None
+
+
+def _execute_sync(plan: SyncPlan, grads, residuals, step):
+    R = plan.R
     if plan.compression.scheme != "none":
         if residuals is None:
             residuals = init_residual(grads)

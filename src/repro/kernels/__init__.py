@@ -1,14 +1,3 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
-# kernel. Kernel subpackages aside, this __init__ carries only the
-# version-portability shims the kernels share.
-
-
-def pallas_compiler_params():
-    """The Pallas TPU CompilerParams class under its version-portable
-    name: `pltpu.CompilerParams` (jax >= 0.5) or `TPUCompilerParams`
-    (0.4.x).  Imported lazily so merely importing repro.kernels never
-    touches jax."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    return getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+# kernel.

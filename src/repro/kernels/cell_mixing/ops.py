@@ -75,8 +75,9 @@ def cell_mixing(
     """Apply `rounds` synchronous gossip rounds per cell: W[b]^R @ x[b].
 
     Inputs may be unaligned; they are identity/zero padded, mixed, and
-    cropped back.  `use_pallas=False` selects the pure-jnp oracle (used
-    for the XLA lowering path on non-TPU hosts).
+    cropped back.  `use_pallas=False` selects the pure-jnp oracle;
+    `use_pallas=True` runs the kernel, in the Pallas interpreter when
+    `interpret=True`.
     """
     wp, xp, (m, d) = pad_mixing(w, x)
     if use_pallas:

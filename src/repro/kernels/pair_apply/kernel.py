@@ -40,32 +40,31 @@ __all__ = ["pair_apply_pallas"]
 def _pair_apply_kernel(
     i_ref, j_ref, ui_ref, uj_ref, x_ref, o_ref, *, ticks: int, cells: int
 ):
-    def cell_body(l, _):
-        x0 = pl.load(
-            x_ref, (pl.dslice(l, 1), slice(None), slice(None))
-        )[0].astype(jnp.float32)                 # (C_pad, V_pad)
+    # the schedule is walked on the output block itself: rows are read
+    # and written through the ref, so each tick moves two rows, never
+    # the whole (C_pad, V_pad) cell
+    o_ref[...] = x_ref[...]
 
-        def body(t, x):
+    def cell_body(l, carry):
+        def tick(t, carry):
             it = i_ref[l, t]
             jt = j_ref[l, t]
-            xi = jax.lax.dynamic_slice_in_dim(x, it, 1, 0)   # (1, V_pad)
-            xj = jax.lax.dynamic_slice_in_dim(x, jt, 1, 0)
+            xi = o_ref[l, pl.ds(it, 1), :]               # (1, V_pad)
+            xj = o_ref[l, pl.ds(jt, 1), :]
             avg = 0.5 * (xi + xj)
-            # partner row first, then initiator — the oracle's write order
-            x = jax.lax.dynamic_update_slice_in_dim(
-                x, jnp.where(uj_ref[l, t] > 0, avg, xj), jt, 0
-            )
-            x = jax.lax.dynamic_update_slice_in_dim(
-                x, jnp.where(ui_ref[l, t] > 0, avg, xi), it, 0
-            )
-            return x
 
-        y = jax.lax.fori_loop(0, ticks, body, x0)
-        pl.store(
-            o_ref, (pl.dslice(l, 1), slice(None), slice(None)),
-            y[None].astype(o_ref.dtype),
-        )
-        return 0
+            # partner row first, then initiator — the oracle's write order
+            @pl.when(uj_ref[l, t] > 0)
+            def _():
+                o_ref[l, pl.ds(jt, 1), :] = avg
+
+            @pl.when(ui_ref[l, t] > 0)
+            def _():
+                o_ref[l, pl.ds(it, 1), :] = avg
+
+            return carry
+
+        return jax.lax.fori_loop(0, ticks, tick, carry)
 
     jax.lax.fori_loop(0, cells, cell_body, 0)
 
@@ -84,11 +83,13 @@ def pair_apply_pallas(
     """Apply a (B, T) presampled schedule to (B, C_pad, V_pad) state,
     `block_b` cells per grid step.
 
-    The caller (ops.pair_apply) is responsible for MXU/lane alignment
-    (C_pad multiple of 8, V_pad multiple of 128), for padding B up to a
-    `block_b` multiple (padded cells carry an all-masked schedule, so
-    their rows pass through untouched), and for transposing the
-    schedule to graph-major (B, T) int32.
+    The caller (ops.pair_apply) is responsible for sublane/lane
+    alignment (C_pad multiple of 8, V_pad multiple of 128), for a
+    `block_b` that is a multiple of 8 or B itself (the (8, 128) rule of
+    the SMEM schedule tiles), for padding B up to a `block_b` multiple
+    (padded cells carry an all-masked schedule, so their rows pass
+    through untouched), and for transposing the schedule to graph-major
+    (B, T) int32.  `x` is donated to the output.
     """
     B, C, V = x.shape
     T = i.shape[1]
@@ -106,5 +107,6 @@ def pair_apply_pallas(
         ],
         out_specs=pl.BlockSpec((block_b, C, V), lambda g: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        input_output_aliases={4: 0},
         interpret=interpret,
     )(i, j, upd_i, upd_j, x)

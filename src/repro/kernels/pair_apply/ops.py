@@ -2,18 +2,20 @@
 padding, cell-block tiling, schedule layout, and the Pallas-vs-oracle
 dispatch.
 
-`use_pallas=False` (or any non-TPU engine run) takes the jnp oracle —
-the same scan the lax backend uses, bitwise-identical to the kernel's
-f32 op sequence, so backend choice never changes simulation results.
-The Pallas kernel itself is validated in interpret mode by the kernel
-tests and runs for real only on TPU hosts.
+`use_pallas=True` always runs the kernel: compiled for the TPU, or in
+the Pallas interpreter with `interpret=True` (the only way it runs on
+other backends).  `use_pallas=False` takes the jnp oracle — the scan
+the lax backend uses, bitwise-identical to the kernel's op sequence,
+so backend choice never changes simulation results.
 
 `block_b` controls how many cells are resident per grid step (see
 kernel.py).  The default sizes the block so the state tile stays
 within ~512 KiB of VMEM and the four schedule tiles within ~128 KiB of
-SMEM — large-n levels stream through in blocks, tiny fig3-scale levels
-still run as a single block.  Results are bitwise-independent of the
-block size (cells never interact).
+SMEM, rounded down to a multiple of 8 (or the whole batch) so the
+SMEM schedule tiles obey the TPU's (8, 128) block rule — large-n
+levels stream through in blocks, tiny fig3-scale levels still run as
+a single block.  Results are bitwise-independent of the block size
+(cells never interact).
 """
 from __future__ import annotations
 
@@ -37,9 +39,11 @@ def _round_up(v: int, mult: int) -> int:
 
 
 def _auto_block(B: int, Cp: int, Vp: int, T: int) -> int:
-    vmem_cap = max(1, _VMEM_BLOCK_BYTES // (Cp * Vp * 4))
-    smem_cap = max(1, _SMEM_BLOCK_BYTES // (4 * T * 4))
-    return max(1, min(B, vmem_cap, smem_cap))
+    """Cells per grid step: the whole batch when it fits the budgets,
+    else the largest multiple of 8 that does (at least 8)."""
+    cap = min(_VMEM_BLOCK_BYTES // (Cp * Vp * 4),
+              _SMEM_BLOCK_BYTES // (4 * T * 4))
+    return B if B <= cap else max(8, cap // 8 * 8)
 
 
 @functools.partial(

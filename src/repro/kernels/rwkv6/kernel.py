@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compiler_params
-
-_CompilerParams = pallas_compiler_params()
-
 __all__ = ["rwkv6_pallas"]
 
 
@@ -81,7 +77,7 @@ def rwkv6_pallas(
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((BH, T, N), r.dtype),
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

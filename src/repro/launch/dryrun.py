@@ -29,7 +29,7 @@ import numpy as np
 
 from repro.configs.registry import ARCH_IDS, SHAPES, cell_is_runnable, get_config
 from repro.launch.hlo_analysis import CollectiveStats, collective_bytes
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell
 
 HW = {
@@ -72,7 +72,7 @@ def model_flops(cfg, shape_name: str) -> float:
 
 def _compile(cfg, shape_name, mesh, model_axis=16):
     cell = build_cell(cfg, shape_name, mesh, model_axis=model_axis)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             cell.fn,
             in_shardings=cell.in_shardings,

@@ -9,21 +9,7 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_host_mesh", "batch_axes", "set_mesh"]
-
-
-def set_mesh(mesh):
-    """Version-portable mesh context: `jax.set_mesh` (jax >= 0.7), else
-    `jax.sharding.use_mesh` (the 0.5/0.6 spelling), else the Mesh object
-    itself (a context manager in 0.4.x).  Usage: ``with set_mesh(mesh):``.
-    `models.layers.current_mesh` is the matching reader — it prefers the
-    abstract mesh these setters install and falls back to the physical
-    thread-resources mesh that `with mesh:` sets."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
+__all__ = ["make_production_mesh", "make_host_mesh", "batch_axes"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):

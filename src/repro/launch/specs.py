@@ -14,7 +14,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import SHAPES
 from repro.models import Transformer, decode_step, forward, init_cache
@@ -166,6 +166,22 @@ class Cell:
 
 def build_cell(cfg: ModelConfig, shape_name: str, mesh,
                model_axis: int = 16) -> Cell:
+    cell = _build_cell(cfg, shape_name, mesh, model_axis)
+    if AxisType.Explicit in mesh.axis_types:
+        # the model's shardings are propagation hints
+        # (`with_sharding_constraint`), which act as asserts on Explicit
+        # axes: run the step with the mesh's axes switched to Auto, and
+        # replicate the outputs the cell leaves unconstrained
+        outs = jax.tree.map(
+            lambda s: P() if s is None else s, cell.out_shardings,
+            is_leaf=lambda s: s is None,
+        )
+        cell.fn = jax.sharding.auto_axes(cell.fn, out_sharding=outs)
+    return cell
+
+
+def _build_cell(cfg: ModelConfig, shape_name: str, mesh,
+                model_axis: int) -> Cell:
     S, B, mode = SHAPES[shape_name]
     dp = tuple(n for n in mesh.axis_names if n != "model")
     model = Transformer(cfg, model_axis=model_axis)

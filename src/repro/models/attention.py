@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
-from .layers import P_, current_mesh, dense, mrope, rope
+from .layers import P_, dense, mrope, rope
 
 
 def _constrain_heads(x, dp):
@@ -25,8 +25,8 @@ def _constrain_heads(x, dp):
     the S x S score tensors head-sharded instead of replicated."""
     if dp is None:
         return x
-    mesh = current_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.shape:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.shape:
         return x
     dp_size = 1
     for a in (dp if isinstance(dp, tuple) else (dp,)):

@@ -88,33 +88,13 @@ def count_params(tree) -> int:
 # ----------------------------- layers ---------------------------------
 
 
-def current_mesh():
-    """Version-portable mesh-in-context lookup, mirroring
-    `launch.mesh.set_mesh`: prefer the abstract mesh installed by
-    `jax.set_mesh`/`use_mesh` when one is actually set, else the
-    physical thread-resources mesh that `with mesh:` (0.4.x fallback)
-    sets — so a non-empty mesh is found on every jax version rather
-    than an empty abstract mesh shadowing an active physical one.
-    Callers tolerate None / an empty mesh (constraints become no-ops)."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    try:  # private fallback; jax has relocated thread_resources before
-        from jax._src import mesh as _mesh_lib
-
-        return _mesh_lib.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        return None
-
-
 def constrain_act(x, dp, axis: int = -1):
     """Shard an activation's last dim over "model" (and dim 0 over dp)
     when a mesh is in context and the dims divide; no-op otherwise."""
     if dp is None:
         return x
-    mesh = current_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.shape:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.shape:
         return x
     spec = [None] * x.ndim
     dp_size = 1

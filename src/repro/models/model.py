@@ -27,7 +27,7 @@ from .attention import (
 )
 from .config import ModelConfig
 from .layers import (
-    P_, abstract_tree, count_params, current_mesh, dense, init_tree,
+    P_, abstract_tree, count_params, dense, init_tree,
     layer_norm, mlp, mlp_params, rms_norm, spec_tree, DTYPES,
 )
 from .moe import moe_ffn, moe_params
@@ -131,8 +131,8 @@ def model_params(cfg: ModelConfig, model_axis: int = 16) -> dict:
 def _constrain(x, dp):
     if dp is None:                       # decentralized per-replica mode
         return x
-    mesh = current_mesh()
-    if mesh is None or mesh.empty:       # single-device smoke tests
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:       # single-device smoke tests
         return x
     spec = (
         P(dp, None, "model")

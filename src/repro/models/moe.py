@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
-from .layers import P_, current_mesh
+from .layers import P_
 
 __all__ = ["moe_params", "moe_ffn"]
 
@@ -23,8 +23,8 @@ def _constrain_tokens(x, dp):
     """Shard a (T, ...) flattened-token tensor over dp on dim 0."""
     if dp is None:
         return x
-    mesh = current_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     dp_size = 1
     for a in (dp if isinstance(dp, tuple) else (dp,)):
@@ -40,8 +40,8 @@ def _constrain_bsd(x, dp):
     """Shard a (B, S, D) tensor over dp on batch (post-combine)."""
     if dp is None:
         return x
-    mesh = current_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     dp_size = 1
     for a in (dp if isinstance(dp, tuple) else (dp,)):
@@ -58,8 +58,8 @@ def _constrain_ecd(x, dp):
     21x flops and 20 GiB fp32 activations on grok-1)."""
     if dp is None:
         return x
-    mesh = current_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.shape:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.shape:
         return x
     E = x.shape[0]
     model = mesh.shape["model"]
@@ -127,8 +127,8 @@ def moe_ffn(params: dict, cfg: ModelConfig, x: jax.Array,
     # constrain the STACKED (n, tc, D) scan output: per-iteration
     # constraints inside the body do not bind the stack buffer
     if dp is not None:
-        mesh = current_mesh()
-        if mesh is not None and not mesh.empty:
+        mesh = jax.sharding.get_abstract_mesh()
+        if not mesh.empty:
             dp_size = 1
             for a in (dp if isinstance(dp, tuple) else (dp,)):
                 dp_size *= mesh.shape.get(a, 1)

@@ -6,8 +6,8 @@ Time-mix (per head of width N):
 with w_t = exp(-exp(w0 + tanh(x_w A) B)) — the defining Finch feature
 (data-dependent decay, paper arXiv:2404.05892).  r/k/v/g use static
 token-shift lerps; the decay path carries the low-rank data-dependent
-delta.  The wkv recurrence lowers through `repro.kernels.rwkv6` (lax.scan
-oracle on non-TPU hosts, Pallas kernel on TPU).
+delta.  The wkv recurrence lowers through `repro.kernels.rwkv6`: the
+lax.scan oracle unless `use_pallas=True` asks for the Pallas kernel.
 """
 from __future__ import annotations
 

@@ -9,19 +9,16 @@ jax (see tests/test_dist_multidevice.py).
 import numpy as np
 import pytest
 
-# Optional-hypothesis policy lives in one place: tests/hypothesis_compat.py
-# (offline container -> property tests skip, everything else runs).
-from hypothesis_compat import HAVE_HYPOTHESIS, HealthCheck, settings
+from hypothesis import HealthCheck, settings
 
-if HAVE_HYPOTHESIS:
-    # Single-core CPU container: keep property tests small and undeadlined.
-    settings.register_profile(
-        "ci",
-        max_examples=15,
-        deadline=None,
-        suppress_health_check=[HealthCheck.too_slow, HealthCheck.data_too_large],
-    )
-    settings.load_profile("ci")
+# Keep property tests small and undeadlined.
+settings.register_profile(
+    "ci",
+    max_examples=15,
+    deadline=None,
+    suppress_health_check=[HealthCheck.too_slow, HealthCheck.data_too_large],
+)
+settings.load_profile("ci")
 
 
 # ---- test tiering (markers registered in pyproject.toml) ----

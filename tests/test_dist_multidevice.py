@@ -25,12 +25,12 @@ def _run(body: str) -> str:
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import set_mesh
         assert len(jax.devices()) == 8
         """
     ) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # a child never reaches for a chip
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
@@ -54,7 +54,7 @@ def test_train_step_executes_on_multipod_mesh():
     )
     SHAPES["tiny_train"] = (32, 8, "train")
     cell = build_cell(cfg, "tiny_train", mesh, model_axis=2)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                          out_shardings=cell.out_shardings)
         # materialize real inputs per the abstract specs
@@ -88,7 +88,7 @@ def test_sync_strategies_execute_with_collectives():
     for strat in ("allreduce", "hierarchical", "ring", "multiscale"):
         cfg = SyncConfig(strategy=strat, levels=suggest_levels(R),
                          rounds=(64,) if strat == "ring" else ())
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             f = jax.jit(lambda x: sync_gradients(x, cfg, R),
                         in_shardings=(dict(w=sh),), out_shardings=dict(w=sh))
             out = f(g)
@@ -115,7 +115,7 @@ def test_sync_strategies_execute_with_collectives():
                    compression=CompressionConfig("topk", topk_fraction=0.25),
                    rotation_period=3),
         R)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         f = jax.jit(lambda x, r, s: execute_sync(plan, x, r, s),
                     in_shardings=((dict(w=sh), dict(w=sh), None)),
                     out_shardings=(dict(w=sh), dict(w=sh)))

@@ -2,7 +2,7 @@
 accounting, failure semantics."""
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import batched_graphs, gossip_until, random_geometric_graph
 

@@ -1,7 +1,7 @@
 """Partition invariants (paper §III, §V)."""
 import numpy as np
 import pytest
-from hypothesis_compat import given, strategies as st
+from hypothesis import given, strategies as st
 
 from repro.core import auto_levels, build_partition, random_geometric_graph
 

@@ -183,10 +183,11 @@ def test_pallas_backend_matches_lax():
         options=ExecOptions(backend="pallas"),
     )
     # identical exchange sequence => identical message/send accounting;
-    # values agree up to f32 matmul rounding
+    # the kernel (interpreted off the TPU) keeps the oracle's f32 op
+    # sequence, so values are bitwise equal too
     assert a.messages == b.messages
     np.testing.assert_array_equal(a.node_sends, b.node_sends)
-    np.testing.assert_allclose(a.x_final, b.x_final, atol=2e-4, rtol=1e-4)
+    np.testing.assert_array_equal(a.x_final, b.x_final)
 
 
 def test_unknown_backend_rejected(rgg500, x0_500):

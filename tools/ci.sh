@@ -46,9 +46,6 @@
 # The slow tier (multi-device subprocess + vmap-/backend-parity tests) is
 # NOT run here — .github/workflows/ci.yml's second job runs `-m slow`.
 # A bare `python -m pytest -x -q` still runs both tiers.
-#
-# Works offline: hypothesis is optional (property tests skip cleanly,
-# see tests/hypothesis_compat.py).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

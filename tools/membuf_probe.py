@@ -144,7 +144,7 @@ def probe(arch, shape, unit=None, layers=None, top=15, multi_pod=False):
 
     from repro.configs import get_config
     from repro.launch.hlo_analysis import DTYPE_BYTES
-    from repro.launch.mesh import make_production_mesh, set_mesh
+    from repro.launch.mesh import make_production_mesh
     from repro.launch.specs import build_cell
 
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -159,7 +159,7 @@ def probe(arch, shape, unit=None, layers=None, top=15, multi_pod=False):
     if changes:
         cfg = dataclasses.replace(cfg, **changes)
     cell = build_cell(cfg, shape, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = (
             jax.jit(cell.fn, in_shardings=cell.in_shardings,
                     out_shardings=cell.out_shardings,
