@@ -1,0 +1,8 @@
+"""Executor lowering in set-up: seconds of the program's
+`/repro/core/executor_lower` duration events (`jax.monitoring`) between
+process start and the first timed call."""
+from bench import scopes
+
+
+def read(run):
+    return scopes.event_s(run, "/repro/core/executor_lower")

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Optional, Sequence
 
 import jax
@@ -59,6 +60,9 @@ from .plan import HierarchyPlan
 from .schedule import CsrGraphs
 
 __all__ = ["EngineResult", "execute_plan", "fi_ticks"]
+
+_scope = jax.named_scope
+_span = jax.profiler.TraceAnnotation
 
 # Lighter XLA pipeline for the executor when it is compiled for the
 # CPU: these are small scatter/gather loops where full optimization buys
@@ -304,8 +308,35 @@ def execute_plan(
     `options.collect_usage` additionally returns the raw per-level flat
     exchange counters (for attribution audits); leave it off on the hot
     path.
+
+    A profiler trace names the call's host steps:
+    ``repro.execute_plan`` spans the call, ``.prepare`` everything up to
+    and including the dispatch of the executor (option checks, trial
+    keys, argument transfer, cache lookup), ``.build`` with its children
+    ``.build.lower`` and ``.build.compile`` a cache miss, and
+    ``.readback`` every device-to-host read and host reduction after
+    the dispatch.  A cache miss also records the lowering and compile
+    seconds as the `jax.monitoring` duration events
+    ``/repro/core/executor_lower`` and ``/repro/core/executor_compile``.
+    On the device every executor op sits under a ``level_<i>`` (or
+    ``final``) scope and one of `core.gossip.LAYER_SCOPES`.
     """
-    options = options if options is not None else ExecOptions()
+    with _span("repro.execute_plan"):
+        with _span("repro.execute_plan.prepare"):
+            options = options if options is not None else ExecOptions()
+            fn, args = _executor(
+                plan, x0, eps=eps, seeds=seeds, weighted=weighted,
+                fixed_ticks_scale=fixed_ticks_scale, options=options,
+                failures=failures, cost=cost)
+            out = fn(*args)
+        with _span("repro.execute_plan.readback"):
+            return _readback(plan, out, len(seeds), options.backend, cost)
+
+
+def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
+              options, failures, cost):
+    """The compiled executor for this call (from `plan.exec_cache`, or
+    lowered and compiled on a miss) and its device arguments."""
     backend, schedule, mesh = options.backend, options.schedule, options.mesh
     interpret, collect_usage = options.interpret, options.collect_usage
     check_every = options.check_every
@@ -415,126 +446,150 @@ def execute_plan(
         return jnp.minimum(sidx, B - 1), sidx < B, sidx
 
     def _run(x0_row, key, eps_arr, maxt_arr):
-        node_sends = jnp.zeros(n + 1, jnp.int32)  # slot n swallows padding
+        with _scope("level_0"), _scope("accounting"):
+            node_sends = jnp.zeros(n + 1, jnp.int32)  # slot n swallows padding
         lvl_msgs, lvl_ticks, lvl_conv, usages = [], [], [], []
         lvl_retx, lvl_cong = [], []
         xb = None
         frozen_vals = None
         for li, (lp, c, chk) in enumerate(zip(plan.levels, consts, chk_levels)):
-            B = lp.num_graphs
+            with _scope(f"level_{li}"):
+                B = lp.num_graphs
+                with _scope("schedule"):
+                    if node_mesh:
+                        cols, ok, _ = _shard_cols(B)
+                        mask = c["node_mask"][cols] & ok[:, None]
+                        shard = (cols, ok)
+                    else:
+                        cols, ok, shard = slice(None), None, None
+                        mask = c["node_mask"]
+                    level_key = jax.random.fold_in(key, li)
+                with _scope("convergence_check"):
+                    eps_l, maxt_l = eps_arr[li], maxt_arr[li]
+                with _scope("promote"):
+                    if lp.kind == "cells":
+                        slots = jnp.clip(c["slot_node"][cols], 0)
+                        vals = jnp.where(mask, x0_row[slots], 0.0)
+                        if weighted:
+                            w = mask.astype(jnp.float32)
+                            xb_loc = jnp.stack([vals * w, w], axis=-1)
+                        else:
+                            xb_loc = vals[..., None]
+                    else:
+                        # promotion left xb global (the psum halo); take
+                        # our block
+                        xb_loc = xb[cols] if node_mesh else xb
+                out = gossip_core(
+                    xb_loc, c["adj"], mask,
+                    eps_l, level_key,
+                    max_ticks=maxt_l, check_every=chk, loss_p=loss_p,
+                    backend=backend, schedule=schedule, interpret=interpret,
+                    node_shard=shard,
+                    failure_ctx=fail_ctxs[li] if scenario else None,
+                    cost_model=cost, hop_cap=max(1, int(lp.max_hops)),
+                )
+                if cost is not None:
+                    x, usage, msgs, done, ticks, retx_l, cong_l = out
+                    lvl_retx.append(retx_l)
+                    lvl_cong.append(cong_l)
+                else:
+                    x, usage, msgs, done, ticks = out
+                # per-graph counters stay int32 on device; they are summed
+                # on the host in int64 (jnp.sum would wrap without x64)
+                lvl_msgs.append(msgs)
+                with _scope("accounting"):
+                    if node_mesh:
+                        lvl_ticks.append(jax.lax.pmax(ticks.max(), "nodes"))
+                        lvl_conv.append(
+                            jax.lax.psum((done & ok).sum(), "nodes") / B
+                        )
+                    else:
+                        lvl_ticks.append(ticks.max())
+                        lvl_conv.append(done.mean())
+                if collect_usage:
+                    usages.append(usage)
+                # a frozen node's own post-gossip value at the finest level
+                # is its value for the rest of the run: snapshot it before
+                # promotion for the dissemination freeze-out
+                if li == 0 and scenario and freeze_c \
+                        and freeze_c[0] is not None:
+                    with _scope("promote"):
+                        fz = freeze_c[0]
+                        e0 = (x[..., 0] if V == 1
+                              else x[..., 0] / jnp.maximum(x[..., 1], 1e-30))
+                        frozen_vals = e0[fz["graph0"], fz["slot0"]]
+                # attribution: gathers through the plan CSR + one scatter-add
+                # per level.  Under node sharding `usage` is the shard's
+                # partial flat counter (both directed entries of an overlay
+                # edge live in one graph, hence one shard), so the partial
+                # node_sends just psum at the end.
+                with _scope("accounting"):
+                    if lp.kind == "cells":
+                        node_sends = node_sends.at[c["row_node"]].add(usage)
+                        node_sends = node_sends.at[
+                            c["partner_flat"]].add(usage)
+                    else:
+                        usage_e = (usage[c["edge_pos_i"]]
+                                   + usage[c["edge_pos_j"]])
+                        node_sends = node_sends.at[c["inc_node"]].add(
+                            usage_e[c["inc_edge"]] * c["inc_count"]
+                        )
+                # promotion (gathers; Alg.1 line 16 on the finest level)
+                if lp.rep_slot is not None:
+                    with _scope("promote"):
+                        Bl = x.shape[0]
+                        v = x[jnp.arange(Bl), c["rep_slot"][cols]]  # (Bl, V)
+                        if weighted:
+                            v = v * c["adj"].n_nodes[cols, None].astype(
+                                jnp.float32)
+                        else:
+                            v = v * c["line16"][cols, None]
+                        B2, C2 = plan.levels[li + 1].node_mask.shape
+                        if node_mesh:
+                            # reps hop shards here: scatter into a
+                            # trash-rowed global buffer and psum the halo
+                            # over node blocks
+                            tg = jnp.where(ok, c["next_graph"][cols], B2)
+                            full = jnp.zeros(
+                                (B2 + 1, C2, V), jnp.float32).at[
+                                    tg, c["next_slot"][cols]
+                            ].set(jnp.where(ok[:, None], v, 0.0))
+                            xb = jax.lax.psum(full, "nodes")[:B2]
+                        else:
+                            xb = jnp.zeros((B2, C2, V), jnp.float32).at[
+                                c["next_graph"], c["next_slot"]
+                            ].set(v)
+        # after the last level: the final estimate, the dissemination
+        # down-pass and the per-node counters' read-out
+        with _scope("final"), _scope("promote"):
+            est = (x[..., 0] if V == 1
+                   else x[..., 0] / jnp.maximum(x[..., 1], 1e-30))
             if node_mesh:
-                cols, ok, _ = _shard_cols(B)
-                mask = c["node_mask"][cols] & ok[:, None]
-                shard = (cols, ok)
-            else:
-                cols, ok, mask, shard = slice(None), None, c["node_mask"], None
-            if lp.kind == "cells":
-                vals = jnp.where(
-                    mask, x0_row[jnp.clip(c["slot_node"][cols], 0)], 0.0
+                BL, CL = plan.levels[-1].node_mask.shape
+                cols, ok, sidx = _shard_cols(BL)
+                tg = jnp.where(ok, sidx, BL)
+                full = jnp.zeros((BL + 1, CL), jnp.float32).at[tg].set(
+                    jnp.where(ok[:, None], est, 0.0)
                 )
-                if weighted:
-                    w = mask.astype(jnp.float32)
-                    xb_loc = jnp.stack([vals * w, w], axis=-1)
-                else:
-                    xb_loc = vals[..., None]
-            else:
-                # promotion left xb global (the psum halo); take our block
-                xb_loc = xb[cols] if node_mesh else xb
-            out = gossip_core(
-                xb_loc, c["adj"], mask,
-                eps_arr[li], jax.random.fold_in(key, li),
-                max_ticks=maxt_arr[li], check_every=chk, loss_p=loss_p,
-                backend=backend, schedule=schedule, interpret=interpret,
-                node_shard=shard,
-                failure_ctx=fail_ctxs[li] if scenario else None,
-                cost_model=cost, hop_cap=max(1, int(lp.max_hops)),
-            )
-            if cost is not None:
-                x, usage, msgs, done, ticks, retx_l, cong_l = out
-                lvl_retx.append(retx_l)
-                lvl_cong.append(cong_l)
-            else:
-                x, usage, msgs, done, ticks = out
-            # per-graph counters stay int32 on device; they are summed on
-            # the host in int64 (jnp.sum would wrap without x64 mode)
-            lvl_msgs.append(msgs)
+                est = jax.lax.psum(full, "nodes")[:BL]
+            x_final = est[plan.final_graph, plan.final_slot]
+            # Byzantine nodes discard the down-pass; churned / permanently
+            # regional-out nodes never hear it — they keep their frozen
+            # value
+            if frozen_vals is not None:
+                x_final = jnp.where(freeze_c[0]["frozen"], frozen_vals,
+                                    x_final)
+        with _scope("final"), _scope("accounting"):
+            node_sends = node_sends[:n]
             if node_mesh:
-                lvl_ticks.append(jax.lax.pmax(ticks.max(), "nodes"))
-                lvl_conv.append(
-                    jax.lax.psum((done & ok).sum(), "nodes") / B
-                )
-            else:
-                lvl_ticks.append(ticks.max())
-                lvl_conv.append(done.mean())
-            if collect_usage:
-                usages.append(usage)
-            # a frozen node's own post-gossip value at the finest level
-            # is its value for the rest of the run: snapshot it before
-            # promotion for the dissemination freeze-out
-            if li == 0 and scenario and freeze_c and freeze_c[0] is not None:
-                fz = freeze_c[0]
-                e0 = (x[..., 0] if V == 1
-                      else x[..., 0] / jnp.maximum(x[..., 1], 1e-30))
-                frozen_vals = e0[fz["graph0"], fz["slot0"]]
-            # attribution: gathers through the plan CSR + one scatter-add
-            # per level.  Under node sharding `usage` is the shard's
-            # partial flat counter (both directed entries of an overlay
-            # edge live in one graph, hence one shard), so the partial
-            # node_sends just psum at the end.
-            if lp.kind == "cells":
-                node_sends = node_sends.at[c["row_node"]].add(usage)
-                node_sends = node_sends.at[c["partner_flat"]].add(usage)
-            else:
-                usage_e = usage[c["edge_pos_i"]] + usage[c["edge_pos_j"]]
-                node_sends = node_sends.at[c["inc_node"]].add(
-                    usage_e[c["inc_edge"]] * c["inc_count"]
-                )
-            # promotion (gathers; Alg.1 line 16 on the finest level)
-            if lp.rep_slot is not None:
-                Bl = x.shape[0]
-                v = x[jnp.arange(Bl), c["rep_slot"][cols]]   # (Bl, V)
-                if weighted:
-                    v = v * c["adj"].n_nodes[cols, None].astype(jnp.float32)
-                else:
-                    v = v * c["line16"][cols, None]
-                B2, C2 = plan.levels[li + 1].node_mask.shape
-                if node_mesh:
-                    # reps hop shards here: scatter into a trash-rowed
-                    # global buffer and psum the halo over node blocks
-                    tg = jnp.where(ok, c["next_graph"][cols], B2)
-                    full = jnp.zeros((B2 + 1, C2, V), jnp.float32).at[
-                        tg, c["next_slot"][cols]
-                    ].set(jnp.where(ok[:, None], v, 0.0))
-                    xb = jax.lax.psum(full, "nodes")[:B2]
-                else:
-                    xb = jnp.zeros((B2, C2, V), jnp.float32).at[
-                        c["next_graph"], c["next_slot"]
-                    ].set(v)
-        # final estimate + dissemination down-pass
-        est = x[..., 0] if V == 1 else x[..., 0] / jnp.maximum(x[..., 1], 1e-30)
-        if node_mesh:
-            BL, CL = plan.levels[-1].node_mask.shape
-            cols, ok, sidx = _shard_cols(BL)
-            tg = jnp.where(ok, sidx, BL)
-            full = jnp.zeros((BL + 1, CL), jnp.float32).at[tg].set(
-                jnp.where(ok[:, None], est, 0.0)
+                node_sends = jax.lax.psum(node_sends, "nodes")
+            if plan.disseminate:
+                node_sends = node_sends + 1  # the n-message down-pass
+            return (
+                x_final, node_sends,
+                tuple(lvl_msgs), jnp.stack(lvl_ticks), jnp.stack(lvl_conv),
+                tuple(usages), tuple(lvl_retx), tuple(lvl_cong),
             )
-            est = jax.lax.psum(full, "nodes")[:BL]
-        x_final = est[plan.final_graph, plan.final_slot]
-        # Byzantine nodes discard the down-pass; churned / permanently
-        # regional-out nodes never hear it — they keep their frozen value
-        if frozen_vals is not None:
-            x_final = jnp.where(freeze_c[0]["frozen"], frozen_vals, x_final)
-        node_sends = node_sends[:n]
-        if node_mesh:
-            node_sends = jax.lax.psum(node_sends, "nodes")
-        if plan.disseminate:
-            node_sends = node_sends + 1  # the n-message down-pass
-        return (
-            x_final, node_sends,
-            tuple(lvl_msgs), jnp.stack(lvl_ticks), jnp.stack(lvl_conv),
-            tuple(usages), tuple(lvl_retx), tuple(lvl_cong),
-        )
 
     # throwaway padding trials bring T up to a mesh-device multiple
     pad_seeds = tuple(seeds) + tuple(seeds[:1]) * pad
@@ -557,50 +612,76 @@ def execute_plan(
     )
     fn = plan.exec_cache.get(cache_key)
     if fn is None:
-        consts.extend(_level_consts(lp) for lp in plan.levels)
-        if scenario:
-            ctxs, freeze = _failure_consts(plan, failures, maxt_levels, n)
-            fail_ctxs.extend(ctxs)
-            freeze_c.append(freeze)
-        if T == 1 and mesh is None:
-            # single-trial fast path: the batching interpreter roughly
-            # doubles trace time and XLA pays for size-1 batch dims on
-            # every op — run the trial unbatched and re-add the trial
-            # axis on the way out (per-trial results are independent of
-            # the batching, see test_trials_vmap_matches_sequential)
-            def run_v(x0_, keys_, eps_, maxt_):
-                out = _run(x0_[0] if per_trial_x0 else x0_, keys_[0],
-                           eps_, maxt_)
-                return jax.tree_util.tree_map(lambda a: a[None], out)
-        else:
-            run_v = jax.vmap(_run, in_axes=(0 if per_trial_x0 else None, 0, None, None))
-        if mesh is not None:
-            from jax.sharding import PartitionSpec as P
-
-            if node_mesh:
-                Pt = P("trials")
-                run_v = jax.shard_map(
-                    run_v, mesh=mesh,
-                    in_specs=(Pt if per_trial_x0 else P(), Pt, P(), P()),
-                    out_specs=(
-                        Pt, Pt,
-                        tuple(P("trials", "nodes") for _ in plan.levels),
-                        Pt, Pt, (), (), (),
-                    ),
-                    check_vma=False,
-                )
-            else:
-                (axis,) = mesh.axis_names
-                run_v = jax.shard_map(
-                    run_v, mesh=mesh,
-                    in_specs=(P(axis) if per_trial_x0 else P(), P(axis), P(), P()),
-                    out_specs=P(axis), check_vma=False,
-                )
-        opts = _CPU_COMPILER_OPTS if platform == "cpu" else None
-        fn = jax.jit(run_v).lower(*args).compile(compiler_options=opts)
+        with _span("repro.execute_plan.build"):
+            t0 = time.perf_counter()
+            with _span("repro.execute_plan.build.lower"):
+                consts.extend(_level_consts(lp) for lp in plan.levels)
+                if scenario:
+                    ctxs, freeze = _failure_consts(
+                        plan, failures, maxt_levels, n)
+                    fail_ctxs.extend(ctxs)
+                    freeze_c.append(freeze)
+                run_v = _over_trials(_run, T, per_trial_x0, mesh, node_mesh,
+                                     len(plan.levels))
+                lowered = jax.jit(run_v).lower(*args)
+            t1 = time.perf_counter()
+            with _span("repro.execute_plan.build.compile"):
+                opts = _CPU_COMPILER_OPTS if platform == "cpu" else None
+                fn = lowered.compile(compiler_options=opts)
+            t2 = time.perf_counter()
+        jax.monitoring.record_event_duration_secs(
+            "/repro/core/executor_lower", t1 - t0)
+        jax.monitoring.record_event_duration_secs(
+            "/repro/core/executor_compile", t2 - t1)
         plan.exec_cache[cache_key] = fn
-    xf, sends, lm, lt, lc, usages, lretx, lcong = fn(*args)
-    if pad:
+    return fn, args
+
+
+def _over_trials(run, T, per_trial_x0, mesh, node_mesh, num_levels):
+    """`run` (one trial) over the call's T trials: vmapped, and
+    shard_mapped over `mesh` when there is one."""
+    if T == 1 and mesh is None:
+        # single-trial fast path: the batching interpreter roughly
+        # doubles trace time and XLA pays for size-1 batch dims on
+        # every op — run the trial unbatched and re-add the trial
+        # axis on the way out (per-trial results are independent of
+        # the batching, see test_trials_vmap_matches_sequential)
+        def run_v(x0_, keys_, eps_, maxt_):
+            out = run(x0_[0] if per_trial_x0 else x0_, keys_[0],
+                      eps_, maxt_)
+            return jax.tree_util.tree_map(lambda a: a[None], out)
+        return run_v
+    run_v = jax.vmap(run, in_axes=(0 if per_trial_x0 else None, 0, None, None))
+    if mesh is None:
+        return run_v
+    from jax.sharding import PartitionSpec as P
+
+    if node_mesh:
+        Pt = P("trials")
+        return jax.shard_map(
+            run_v, mesh=mesh,
+            in_specs=(Pt if per_trial_x0 else P(), Pt, P(), P()),
+            out_specs=(
+                Pt, Pt,
+                tuple(P("trials", "nodes") for _ in range(num_levels)),
+                Pt, Pt, (), (), (),
+            ),
+            check_vma=False,
+        )
+    (axis,) = mesh.axis_names
+    return jax.shard_map(
+        run_v, mesh=mesh,
+        in_specs=(P(axis) if per_trial_x0 else P(), P(axis), P(), P()),
+        out_specs=P(axis), check_vma=False,
+    )
+
+
+def _readback(plan, out, T, backend, cost) -> EngineResult:
+    """The executor's device outputs as host arrays: padding trials
+    dropped, per-graph int32 counters reduced in int64, cost priced."""
+    n = plan.graph.n
+    xf, sends, lm, lt, lc, usages, lretx, lcong = out
+    if xf.shape[0] > T:
         xf, sends, lt, lc = xf[:T], sends[:T], lt[:T], lc[:T]
         lm = tuple(m[:T] for m in lm)
         usages = tuple(u[:T] for u in usages)

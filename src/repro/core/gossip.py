@@ -63,6 +63,15 @@ sharding.  Once a graph converges its exchanges freeze (writes become
 identity, accounting masks to zero), so shards may run different
 while-loop trip counts without affecting any output.
 
+Layer scopes: every op `gossip_core` emits sits under one of the
+`jax.named_scope`s in `LAYER_SCOPES` — ``schedule`` (sampling and its
+perturbation), ``value_pass`` (the pair-average backend),
+``accounting`` (usage, messages, ticks, cost counters) and
+``convergence_check`` (the tolerance, the per-chunk ``err <= tol`` and
+the chunk loop's own control); `core.engine` adds ``promote`` for the
+moves between levels.  Scopes are HLO metadata (``op_name``), so they
+name ops in a profiler trace and change nothing that runs.
+
 `gossip_core` is the pure-JAX function (usable inside a larger jit /
 vmap — the plan/execute engine in `core.engine` vmaps it over
 Monte-Carlo trial seeds); `gossip_until` is the host-facing wrapper.
@@ -93,9 +102,13 @@ from .schedule import (
 )
 
 __all__ = ["GossipResult", "gossip_core", "gossip_until", "batched_graphs",
-           "GOSSIP_BACKENDS"]
+           "GOSSIP_BACKENDS", "LAYER_SCOPES"]
 
 GOSSIP_BACKENDS = ("lax", "pallas", "matmul")
+LAYER_SCOPES = ("schedule", "value_pass", "accounting", "convergence_check",
+                "promote")
+
+_scope = jax.named_scope
 
 
 @dataclasses.dataclass
@@ -124,18 +137,21 @@ def _one_tick(state, t, adj, key, loss_p):
     so the two stay draw-for-draw identical by construction."""
     x, usage, msgs, done = state
     B = adj.degrees.shape[0]
-    bidx = jnp.arange(B)
-    s = sample_tick(t, key, adj, loss_p, x.dtype)
-    active = (~done) & s.valid
-    xi = x[bidx, s.i]
-    xj = x[bidx, s.j]
-    avg = 0.5 * (xi + xj)
-    upd_j = (active & s.fwd_ok)[:, None]           # j updates iff request arrived
-    upd_i = (active & s.fwd_ok & s.rep_ok)[:, None]  # i updates iff reply arrived
-    x = x.at[bidx, s.j].set(jnp.where(upd_j, avg, xj))
-    x = x.at[bidx, s.i].set(jnp.where(upd_i, avg, xi))
-    usage = usage.at[s.pos].add(active.astype(jnp.int32))
-    msgs = msgs + jnp.where(active, s.cost, 0)
+    with _scope("schedule"):
+        s = sample_tick(t, key, adj, loss_p, x.dtype)
+        active = (~done) & s.valid
+    with _scope("value_pass"):
+        bidx = jnp.arange(B)
+        xi = x[bidx, s.i]
+        xj = x[bidx, s.j]
+        avg = 0.5 * (xi + xj)
+        upd_j = (active & s.fwd_ok)[:, None]  # j updates iff request arrived
+        upd_i = (active & s.fwd_ok & s.rep_ok)[:, None]  # i iff reply arrived
+        x = x.at[bidx, s.j].set(jnp.where(upd_j, avg, xj))
+        x = x.at[bidx, s.i].set(jnp.where(upd_i, avg, xi))
+    with _scope("accounting"):
+        usage = usage.at[s.pos].add(active.astype(jnp.int32))
+        msgs = msgs + jnp.where(active, s.cost, 0)
     return (x, usage, msgs, done), None
 
 
@@ -204,11 +220,12 @@ def gossip_core(
             raise ValueError(
                 "failure scenarios / cost pricing are not supported on "
                 "the (trials, nodes) mesh")
-    live = node_mask.astype(x0.dtype)[..., None]  # (B, C, 1)
-    denom = jnp.maximum(live.sum(1), 1.0)
-    mean = (x0 * live).sum(1) / denom             # (B, V)
-    x0_norm = jnp.sqrt(((x0 * live) ** 2).sum((1, 2)))
-    tol = eps * jnp.maximum(x0_norm, 1e-30)
+    with _scope("convergence_check"):
+        live = node_mask.astype(x0.dtype)[..., None]  # (B, C, 1)
+        denom = jnp.maximum(live.sum(1), 1.0)
+        mean = (x0 * live).sum(1) / denom             # (B, V)
+        x0_norm = jnp.sqrt(((x0 * live) ** 2).sum((1, 2)))
+        tol = eps * jnp.maximum(x0_norm, 1e-30)
 
     def err(x):
         d = (x - mean[:, None, :]) * live
@@ -225,23 +242,26 @@ def gossip_core(
         )
 
     def cond(carry):
-        return (~jnp.all(carry[3])) & (carry[-1] < max_ticks)
+        with _scope("convergence_check"):
+            return (~jnp.all(carry[3])) & (carry[-1] < max_ticks)
 
-    usage0 = jnp.zeros(adj.nbr.shape, jnp.int32)
-    msgs0 = jnp.zeros(x0.shape[:1], jnp.int32)
-    done0 = err(x0) <= tol  # already-converged graphs (e.g. 1-node cells)
-    ticks0 = jnp.zeros(x0.shape[:1], jnp.int32)
-    if cost_model is not None:
-        # per-graph cost accumulators: sampled extra attempts (int32,
-        # exact) and concurrency pair counts (f32: a surcharge tally,
-        # not an exact-accounting channel)
-        extras = (jnp.zeros(x0.shape[:1], jnp.int32),
-                  jnp.zeros(x0.shape[:1], jnp.float32))
-    else:
-        extras = ()
-    carry = (x0, usage0, msgs0, done0, ticks0) + extras \
-        + (jnp.array(0, jnp.int32),)
-    out = jax.lax.while_loop(cond, chunk, carry)
+    with _scope("accounting"):
+        usage0 = jnp.zeros(adj.nbr.shape, jnp.int32)
+        msgs0 = jnp.zeros(x0.shape[:1], jnp.int32)
+        ticks0 = jnp.zeros(x0.shape[:1], jnp.int32)
+        if cost_model is not None:
+            # per-graph cost accumulators: sampled extra attempts (int32,
+            # exact) and concurrency pair counts (f32: a surcharge tally,
+            # not an exact-accounting channel)
+            extras = (jnp.zeros(x0.shape[:1], jnp.int32),
+                      jnp.zeros(x0.shape[:1], jnp.float32))
+        else:
+            extras = ()
+    with _scope("convergence_check"):
+        done0 = err(x0) <= tol  # already-converged graphs (e.g. 1-node cells)
+        carry = (x0, usage0, msgs0, done0, ticks0) + extras \
+            + (jnp.array(0, jnp.int32),)
+        out = jax.lax.while_loop(cond, chunk, carry)
     return out[:-1]  # drop the tick counter t0
 
 
@@ -271,77 +291,84 @@ def _presampled_chunk(adj, key, loss_p, check_every, backend, interpret,
         else:
             x, usage, msgs, done, ticks, t0 = carry
         C = x.shape[1]
-        ts = t0 + jnp.arange(check_every)
-        s = sample_schedule(ts, key, adj, loss_p, x.dtype)
-        if node_shard is not None:
-            cols, ok = node_shard
-            s = type(s)(*(f[:, cols] for f in s))
-            s = s._replace(valid=s.valid & ok[None, :])
-        active = s.valid & ~done[None, :]   # done is frozen within a chunk
-        if failure_ctx is None:
-            attempt = active
-            cost_t = s.cost
-            upd_j = active & s.fwd_ok
-            upd_i = upd_j & s.rep_ok
-        else:
-            fc = failure_ctx
-            bcols = jnp.arange(active.shape[1])[None, :]
-            when = ts[:, None]
-            churn_now = when >= fc.churn_tick
-            reg_now = (when >= fc.reg_t0) & (when < fc.reg_t1)
-            down_i = (fc.churned[bcols, s.i] & churn_now) | (
-                fc.regional[bcols, s.i] & reg_now)
-            down_j = (fc.churned[bcols, s.j] & churn_now) | (
-                fc.regional[bcols, s.j] & reg_now)
-            attempt = active & ~down_i      # a down initiator never wakes
-            delivered = attempt & ~down_j
-            slow = fc.straggler[bcols, s.i] | fc.straggler[bcols, s.j]
-            if fc.straggler_success < 1.0:
-                ku = jax.random.fold_in(
-                    jax.random.fold_in(key, _TAG_STRAGGLER), t0)
-                u = jax.random.uniform(ku, active.shape)
-                delivered = delivered & (
-                    ~slow | (u < fc.straggler_success))
-            upd_j = delivered & s.fwd_ok & ~fc.byz[bcols, s.j]
-            upd_i = delivered & s.fwd_ok & s.rep_ok & ~fc.byz[bcols, s.i]
-            # a wasted contact of a down partner still transmits the
-            # forward leg; straggler stalls burn the full exchange cost
-            cost_t = jnp.where(attempt & ~down_j, s.cost, adj.hops[s.pos])
-        usage = usage.at[s.pos].add(attempt.astype(jnp.int32))
-        hops_t = jnp.where(attempt, cost_t, 0)
-        msgs = msgs + hops_t.sum(0)
-        if sample_retx:
-            # iid Geometric(p) per single-hop transmission: extra
-            # attempts per hop slot, masked to the hops actually sent.
-            # The stream is fold_in(key, TAG) -> fold_in(., t0): tagged
-            # before the tick fold, disjoint from exchange draws.
-            kr = jax.random.fold_in(jax.random.fold_in(key, _TAG_RETX), t0)
-            q = 1.0 - cost_model.retransmit_p
-            u = jnp.maximum(
-                jax.random.uniform(kr, (*hops_t.shape, 2 * hop_cap)), 1e-12)
-            g = jnp.floor(jnp.log(u) / jnp.log(q)).astype(jnp.int32)
-            m = jnp.arange(2 * hop_cap)[None, None, :] < hops_t[..., None]
-            retx = retx + jnp.where(m, g, 0).sum((0, 2))
-        if track_cong:
-            conc = attempt.sum(1)  # concurrent exchanges at each tick
-            congp = congp + (
-                attempt * jnp.maximum(conc - 1, 0)[:, None]
-            ).sum(0).astype(jnp.float32)
-        if backend == "lax":
-            x = pair_apply_ref(x, s.i, s.j, upd_i, upd_j)
-        elif backend == "pallas":
-            x = pair_apply(x, s.i, s.j, upd_i, upd_j, interpret=interpret)
-        else:  # matmul: associative composition, applied on the MXU
-            from repro.kernels.cell_mixing import cell_mixing
+        with _scope("schedule"):
+            ts = t0 + jnp.arange(check_every)
+            s = sample_schedule(ts, key, adj, loss_p, x.dtype)
+            if node_shard is not None:
+                cols, ok = node_shard
+                s = type(s)(*(f[:, cols] for f in s))
+                s = s._replace(valid=s.valid & ok[None, :])
+            active = s.valid & ~done[None, :]  # done is frozen within a chunk
+            if failure_ctx is None:
+                attempt = active
+                cost_t = s.cost
+                upd_j = active & s.fwd_ok
+                upd_i = upd_j & s.rep_ok
+            else:
+                fc = failure_ctx
+                bcols = jnp.arange(active.shape[1])[None, :]
+                when = ts[:, None]
+                churn_now = when >= fc.churn_tick
+                reg_now = (when >= fc.reg_t0) & (when < fc.reg_t1)
+                down_i = (fc.churned[bcols, s.i] & churn_now) | (
+                    fc.regional[bcols, s.i] & reg_now)
+                down_j = (fc.churned[bcols, s.j] & churn_now) | (
+                    fc.regional[bcols, s.j] & reg_now)
+                attempt = active & ~down_i      # a down initiator never wakes
+                delivered = attempt & ~down_j
+                slow = fc.straggler[bcols, s.i] | fc.straggler[bcols, s.j]
+                if fc.straggler_success < 1.0:
+                    ku = jax.random.fold_in(
+                        jax.random.fold_in(key, _TAG_STRAGGLER), t0)
+                    u = jax.random.uniform(ku, active.shape)
+                    delivered = delivered & (
+                        ~slow | (u < fc.straggler_success))
+                upd_j = delivered & s.fwd_ok & ~fc.byz[bcols, s.j]
+                upd_i = delivered & s.fwd_ok & s.rep_ok & ~fc.byz[bcols, s.i]
+                # a wasted contact of a down partner still transmits the
+                # forward leg; straggler stalls burn the full exchange cost
+                cost_t = jnp.where(attempt & ~down_j, s.cost, adj.hops[s.pos])
+        with _scope("accounting"):
+            usage = usage.at[s.pos].add(attempt.astype(jnp.int32))
+            hops_t = jnp.where(attempt, cost_t, 0)
+            msgs = msgs + hops_t.sum(0)
+            if sample_retx:
+                # iid Geometric(p) per single-hop transmission: extra
+                # attempts per hop slot, masked to the hops actually sent.
+                # The stream is fold_in(key, TAG) -> fold_in(., t0): tagged
+                # before the tick fold, disjoint from exchange draws.
+                kr = jax.random.fold_in(jax.random.fold_in(key, _TAG_RETX), t0)
+                q = 1.0 - cost_model.retransmit_p
+                u = jnp.maximum(
+                    jax.random.uniform(kr, (*hops_t.shape, 2 * hop_cap)),
+                    1e-12)
+                g = jnp.floor(jnp.log(u) / jnp.log(q)).astype(jnp.int32)
+                m = jnp.arange(2 * hop_cap)[None, None, :] < hops_t[..., None]
+                retx = retx + jnp.where(m, g, 0).sum((0, 2))
+            if track_cong:
+                conc = attempt.sum(1)  # concurrent exchanges at each tick
+                congp = congp + (
+                    attempt * jnp.maximum(conc - 1, 0)[:, None]
+                ).sum(0).astype(jnp.float32)
+        with _scope("value_pass"):
+            if backend == "lax":
+                x = pair_apply_ref(x, s.i, s.j, upd_i, upd_j)
+            elif backend == "pallas":
+                x = pair_apply(x, s.i, s.j, upd_i, upd_j, interpret=interpret)
+            else:  # matmul: associative composition, applied on the MXU
+                from repro.kernels.cell_mixing import cell_mixing
 
-            m = compose_schedule(C, s.i, s.j, upd_i, upd_j, x.dtype)
-            x = cell_mixing(m, x, rounds=1, interpret=interpret)
-        ticks = ticks + jnp.where(done, 0, check_every)
-        done = done | (err(x) <= tol)
+                m = compose_schedule(C, s.i, s.j, upd_i, upd_j, x.dtype)
+                x = cell_mixing(m, x, rounds=1, interpret=interpret)
+        with _scope("accounting"):
+            ticks = ticks + jnp.where(done, 0, check_every)
+        with _scope("convergence_check"):
+            done = done | (err(x) <= tol)
+            t1 = t0 + check_every
         out = (x, usage, msgs, done, ticks)
         if cost_on:
             out = out + (retx, congp)
-        return out + (t0 + check_every,)
+        return out + (t1,)
 
     return chunk
 
@@ -360,25 +387,33 @@ def _per_tick_chunk(adj, key, loss_p, check_every, backend, interpret,
     # identity seed is built once here, not per while-loop iteration.
     eye = None
     if backend == "pallas":
-        eye = jnp.broadcast_to(jnp.eye(C, dtype=jnp.float32), (B, C, C))
+        with _scope("value_pass"):
+            eye = jnp.broadcast_to(jnp.eye(C, dtype=jnp.float32), (B, C, C))
 
     def chunk(carry):
         x, usage, msgs, done, ticks, t0 = carry
-        ts = t0 + jnp.arange(check_every)
-        if backend == "lax":
-            (x, usage, msgs, done), _ = jax.lax.scan(
-                tick, (x, usage, msgs, done), ts
-            )
-        else:
-            from repro.kernels.cell_mixing import cell_mixing
+        with _scope("schedule"):
+            ts = t0 + jnp.arange(check_every)
+        # the tick scan interleaves the three layers; its body scopes
+        # each of them (`_one_tick`), the loop itself is the value pass
+        with _scope("value_pass"):
+            if backend == "lax":
+                (x, usage, msgs, done), _ = jax.lax.scan(
+                    tick, (x, usage, msgs, done), ts
+                )
+            else:
+                from repro.kernels.cell_mixing import cell_mixing
 
-            (m, usage, msgs, done), _ = jax.lax.scan(
-                tick, (eye.astype(x.dtype), usage, msgs, done), ts
-            )
-            x = cell_mixing(m, x, rounds=1, interpret=interpret)
-        ticks = ticks + jnp.where(done, 0, check_every)
-        done = done | (err(x) <= tol)
-        return (x, usage, msgs, done, ticks, t0 + check_every)
+                (m, usage, msgs, done), _ = jax.lax.scan(
+                    tick, (eye.astype(x.dtype), usage, msgs, done), ts
+                )
+                x = cell_mixing(m, x, rounds=1, interpret=interpret)
+        with _scope("accounting"):
+            ticks = ticks + jnp.where(done, 0, check_every)
+        with _scope("convergence_check"):
+            done = done | (err(x) <= tol)
+            t1 = t0 + check_every
+        return (x, usage, msgs, done, ticks, t1)
 
     return chunk
 
