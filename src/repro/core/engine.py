@@ -127,13 +127,8 @@ class EngineResult:
 
 def _level_consts(lp):
     c = {
-        "adj": CsrGraphs(
-            start=jnp.asarray(lp.nbr_start, jnp.int32),
-            nbr=jnp.asarray(lp.nbr_flat, jnp.int32),
-            hops=jnp.asarray(lp.hop_flat, jnp.int32),
-            degrees=jnp.asarray(lp.degrees, jnp.int32),
-            n_nodes=jnp.asarray(lp.n_nodes, jnp.int32),
-        ),
+        "adj": jax.tree.map(jnp.asarray, CsrGraphs.from_flat(
+            lp.nbr_start, lp.nbr_flat, lp.hop_flat, lp.degrees, lp.n_nodes)),
         "node_mask": jnp.asarray(lp.node_mask, bool),
         "slot_node": jnp.asarray(lp.slot_node, jnp.int32),
     }
@@ -317,7 +312,9 @@ def execute_plan(
     ``.readback`` every device-to-host read and host reduction after
     the dispatch.  A cache miss also records the lowering and compile
     seconds as the `jax.monitoring` duration events
-    ``/repro/core/executor_lower`` and ``/repro/core/executor_compile``.
+    ``/repro/core/executor_lower`` and ``/repro/core/executor_compile``,
+    and one ``/repro/core/schedule_lookup`` event per level with how its
+    schedule reads partners and hops (`CsrGraphs.lookup`).
     On the device every executor op sits under a ``level_<i>`` (or
     ``final``) scope and one of `core.gossip.LAYER_SCOPES`.
     """
@@ -633,6 +630,9 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
             "/repro/core/executor_lower", t1 - t0)
         jax.monitoring.record_event_duration_secs(
             "/repro/core/executor_compile", t2 - t1)
+        for li, c in enumerate(consts):
+            jax.monitoring.record_event("/repro/core/schedule_lookup",
+                                        level=li, **c["adj"].lookup)
         plan.exec_cache[cache_key] = fn
     return fn, args
 

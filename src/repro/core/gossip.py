@@ -47,8 +47,8 @@ interleaved with value updates) as the bitwise-parity reference path;
 it supports the lax backend and the historical pallas
 eye-rebuild-then-scan branch.
 
-Adjacency is CSR (`core.schedule.CsrGraphs`): one flat entry per
-directed edge instead of ``(B, C, D)`` dense padding, with usage
+Adjacency is CSR-addressed (`core.schedule.CsrGraphs`): one flat entry
+per directed edge instead of ``(B, C, D)`` dense padding, with usage
 counted in a flat ``(nnz+1,)`` buffer via a 1-D scatter on the sampled
 `pos` field.  `gossip_until` keeps the historical dense host API — it
 packs dense inputs with `dense_to_csr` and scatters flat usage back to
@@ -78,7 +78,7 @@ Monte-Carlo trial seeds); `gossip_until` is the host-facing wrapper.
 
 Shapes (static under jit):
   x         : (B, C, V)   node values, padded with 0
-  adj       : CsrGraphs   start (B,C) / nbr,hops (nnz+1,) / degrees / n_nodes
+  adj       : CsrGraphs   start, degrees (C,B) / nbr, hops / n_nodes (B,)
   node_mask : (B, C)      live-node mask
 """
 from __future__ import annotations
@@ -136,7 +136,7 @@ def _one_tick(state, t, adj, key, loss_p):
     Sampling is shared with the presampled path (`schedule.sample_tick`)
     so the two stay draw-for-draw identical by construction."""
     x, usage, msgs, done = state
-    B = adj.degrees.shape[0]
+    B = adj.n_nodes.shape[0]
     with _scope("schedule"):
         s = sample_tick(t, key, adj, loss_p, x.dtype)
         active = (~done) & s.valid
@@ -246,7 +246,7 @@ def gossip_core(
             return (~jnp.all(carry[3])) & (carry[-1] < max_ticks)
 
     with _scope("accounting"):
-        usage0 = jnp.zeros(adj.nbr.shape, jnp.int32)
+        usage0 = jnp.zeros((adj.num_entries,), jnp.int32)
         msgs0 = jnp.zeros(x0.shape[:1], jnp.int32)
         ticks0 = jnp.zeros(x0.shape[:1], jnp.int32)
         if cost_model is not None:
@@ -327,7 +327,7 @@ def _presampled_chunk(adj, key, loss_p, check_every, backend, interpret,
                 upd_i = delivered & s.fwd_ok & s.rep_ok & ~fc.byz[bcols, s.i]
                 # a wasted contact of a down partner still transmits the
                 # forward leg; straggler stalls burn the full exchange cost
-                cost_t = jnp.where(attempt & ~down_j, s.cost, adj.hops[s.pos])
+                cost_t = jnp.where(attempt & ~down_j, s.cost, s.hops)
         with _scope("accounting"):
             usage = usage.at[s.pos].add(attempt.astype(jnp.int32))
             hops_t = jnp.where(attempt, cost_t, 0)
@@ -376,7 +376,7 @@ def _presampled_chunk(adj, key, loss_p, check_every, backend, interpret,
 def _per_tick_chunk(adj, key, loss_p, check_every, backend, interpret,
                     err, tol):
     """Legacy chunk body: the sequential sample-and-apply scan."""
-    B, C = adj.degrees.shape
+    C, B = adj.degrees.shape
 
     def tick(s, t):
         return _one_tick(s, t, adj, key, loss_p)
@@ -482,7 +482,7 @@ def gossip_until(
     if node_mask is None:
         node_mask = np.arange(C)[None, :] < np.asarray(n_nodes)[:, None]
     adj_np = dense_to_csr(neighbors, degrees, n_nodes, edge_hops)
-    adj = CsrGraphs(*(jnp.asarray(a) for a in adj_np))
+    adj = jax.tree.map(jnp.asarray, adj_np)
     key = jax.random.PRNGKey(seed)
     if fixed_ticks is not None:
         eps_eff = -1.0  # negative tol: the oracle never fires

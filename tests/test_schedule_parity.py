@@ -129,14 +129,14 @@ def test_sample_schedule_matches_sample_tick():
     import jax
     import jax.numpy as jnp
 
-    from repro.core import CsrGraphs, dense_to_csr
+    from repro.core import dense_to_csr
 
     g = _ring(16)
     key = jax.random.PRNGKey(4)
     adj_np = dense_to_csr(
         g.neighbors[None], g.degrees[None], np.array([16], np.int32)
     )
-    adj = CsrGraphs(*(jnp.asarray(a) for a in adj_np))
+    adj = jax.tree.map(jnp.asarray, adj_np)
     ts = jnp.arange(10, 42)
     sched = sample_schedule(ts, key, adj, 0.7)
     for idx, t in enumerate(np.asarray(ts)):
@@ -146,6 +146,126 @@ def test_sample_schedule_matches_sample_tick():
                 np.asarray(batch[idx]), np.asarray(getattr(one, field)),
                 err_msg=f"t={t} field={field}",
             )
+
+
+# ------------------- lookups: select against gather --------------------
+
+# dense batches: (B, C, D_max, hops); every batch has rows of degree 0
+# and ends with an edgeless graph, whose draws all land on the trailing
+# sentinel; rows of padded neighbour lists (C*D_max) from 6 to 2352, on
+# both sides of `ROW_SELECT_MAX`
+_LOOKUP_CASES = {
+    "cells": (6, 8, 7, "ones"),
+    "overlay": (9, 4, 3, "vary"),
+    "all-twos": (3, 5, 4, "twos"),    # the sentinel's 1 varies
+    "edgeless": (2, 6, 0, "ones"),
+    "at-128": (3, 16, 8, "vary"),
+    "wide": (4, 13, 12, "ones"),
+    "wide-hops": (2, 16, 15, "vary"),
+    "big-cells": (3, 49, 48, "ones"),
+    "big-cells-hops": (2, 40, 39, "vary"),
+}
+
+
+def _dense_batch(B, C, D, hops, seed):
+    rng = np.random.default_rng(seed)
+    n_nodes = rng.integers(1, C + 1, B).astype(np.int32)
+    n_nodes[-1] = C
+    degrees = np.where(np.arange(C)[None, :] < n_nodes[:, None],
+                       rng.integers(0, D + 1, (B, C)), 0).astype(np.int32)
+    degrees[:, 0] = 0  # slot 0 of every graph is isolated
+    degrees[0, -1] = D
+    degrees[-1] = 0
+    neighbors = (rng.integers(0, 1 << 20, (B, C, max(D, 1)))
+                 % n_nodes[:, None, None]).astype(np.int32)
+    edge_hops = (rng.integers(1, 6, neighbors.shape) if hops == "vary" else
+                 np.full(neighbors.shape, 2 if hops == "twos" else 1))
+    return neighbors, degrees, n_nodes, edge_hops.astype(np.int32)
+
+
+def _gather_schedule(ts, key, neighbors, degrees, n_nodes, edge_hops,
+                     loss_p):
+    """The lookups as gathers into the flat CSR arrays (``(B, C)`` row
+    starts and degrees, ``(nnz+1,)`` neighbours and hops with the
+    trailing sentinel), with `sample_tick`'s draws: the oracle."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.schedule import ExchangeSchedule, truncated_failure_hops
+
+    B, C, D = neighbors.shape
+    keep = np.arange(D)[None, None, :] < degrees[:, :, None]
+    start = jnp.asarray(np.concatenate(
+        [[0], np.cumsum(degrees.ravel())])[:-1].reshape(B, C), jnp.int32)
+    nbr = jnp.asarray(np.concatenate([neighbors[keep], [0]]), jnp.int32)
+    hop_flat = jnp.asarray(np.concatenate([edge_hops[keep], [1]]), jnp.int32)
+    deg, nn = jnp.asarray(degrees), jnp.asarray(n_nodes)
+
+    def tick(t):
+        kt = jax.random.fold_in(key, t)
+        ki, kj, kf, kr = jax.random.split(kt, 4)
+        u = jax.random.uniform(ki, (B,))
+        i = jnp.minimum((u * nn).astype(jnp.int32), nn - 1)
+        deg_i = jnp.take_along_axis(deg, i[:, None], axis=1)[:, 0]
+        v = jax.random.uniform(kj, (B,))
+        jidx = jnp.minimum((v * deg_i).astype(jnp.int32),
+                           jnp.maximum(deg_i - 1, 0))
+        pos = start[jnp.arange(B), i] + jidx
+        hops = hop_flat[pos]
+        if loss_p is None:
+            fwd_ok = rep_ok = jnp.ones((B,), bool)
+            cost = 2 * hops
+        else:
+            p = jnp.asarray(loss_p, jnp.float32)
+            fwd_ok, fh = truncated_failure_hops(
+                jax.random.uniform(kf, (B,)), p, hops)
+            rep_ok, rh = truncated_failure_hops(
+                jax.random.uniform(kr, (B,)), p, hops)
+            cost = fh + jnp.where(fwd_ok, rh, 0)
+        return ExchangeSchedule(
+            i=i, jidx=jidx, j=nbr[pos], valid=deg_i > 0, fwd_ok=fwd_ok,
+            rep_ok=rep_ok, cost=cost, pos=pos, hops=hops)
+
+    return jax.vmap(tick)(ts)
+
+
+@pytest.mark.parametrize("loss_p", [None, 0.7], ids=["lossless", "loss"])
+@pytest.mark.parametrize("case", list(_LOOKUP_CASES))
+def test_select_lookups_match_gather(case, loss_p):
+    """The schedule's lookups — by one-hot selects over the drawn
+    graph's slots, or by the flat gather past `ROW_SELECT_MAX` — give
+    the gathers' integers bit for bit, garbage partners of invalid
+    draws included; the select path lowers with no gather at all."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import dense_to_csr
+    from repro.core.schedule import ROW_SELECT_MAX
+
+    B, C, D, hops = _LOOKUP_CASES[case]
+    path = "select" if C * max(D, 1) <= ROW_SELECT_MAX else "gather"
+    dense = _dense_batch(B, C, D, hops, seed=B * C + D)
+    adj_np = dense_to_csr(*dense)
+    uniform = hops == "ones"
+    assert adj_np.lookup == {"path": path,
+                             "hops": "const" if uniform else "table"}
+    assert adj_np.nbr.shape == ((C * max(D, 1), B) if path == "select"
+                                else (int(dense[1].sum()) + 1,))
+    adj = jax.tree.map(jnp.asarray, adj_np)
+    key = jax.random.PRNGKey(B + C + D)
+    ts = jnp.arange(7, 7 + 96)
+    got = jax.jit(sample_schedule, static_argnums=3)(ts, key, adj, loss_p)
+    want = _gather_schedule(ts, key, *dense, loss_p)
+    for field in want._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, field)), np.asarray(getattr(want, field)),
+            err_msg=field)
+    assert not np.asarray(got.valid)[:, -1].any()   # the edgeless graph
+    np.testing.assert_array_equal(np.asarray(got.pos)[:, -1],
+                                  adj.num_entries - 1)
+    hlo = jax.jit(sample_schedule, static_argnums=3).lower(
+        ts, key, adj, loss_p).as_text()
+    assert ("gather" in hlo) == (path == "gather")
 
 
 def test_compose_schedule_is_stochastic_and_matches_ref():

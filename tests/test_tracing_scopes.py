@@ -2,7 +2,8 @@
 HLO carries an ``op_name`` for sits under one ``level_<i>`` (or
 ``final``) scope and one of `LAYER_SCOPES`; a profiler trace of
 `execute_plan` holds its host spans; a cache miss records one lowering
-and one compile event, a hit none."""
+and one compile event and one schedule-lookup event per level, a hit
+none."""
 import collections
 import glob
 import os
@@ -12,7 +13,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import ExecOptions, build_plan, execute_plan
+from repro.core import ExecOptions, build_plan, execute_plan, setup_plan
 from repro.core import random_geometric_graph
 from repro.core.gossip import LAYER_SCOPES
 
@@ -135,4 +136,31 @@ def test_a_cache_miss_records_one_lower_and_one_compile_event(plan300, x300):
     assert [e for e, _ in miss] == ["/repro/core/executor_lower",
                                     "/repro/core/executor_compile"]
     assert all(secs > 0 for _, secs in miss)
+    assert events == miss          # the hit recorded nothing
+
+
+def test_a_build_records_each_levels_schedule_lookup():
+    """The paper's deployment (n=2000, five levels): every level reads
+    partners by the select; hop counts are uniform on the cells and the
+    first overlay."""
+    plan, _ = setup_plan(n=2000, c=3.0, graph_seed=100, a=2 / 3,
+                         cell_max=8.0, seed=0, rep_mode="random",
+                         use_cache=False)
+    x0 = np.random.default_rng(1).standard_normal(2000).astype(np.float32)
+    events = []
+
+    def listen(event, **kw):
+        if event == "/repro/core/schedule_lookup":
+            events.append(kw)
+
+    jax.monitoring.register_event_listener(listen)
+    try:
+        execute_plan(plan, x0, eps=1e-2, seeds=(1,))
+        miss = list(events)
+        execute_plan(plan, x0, eps=1e-2, seeds=(2,))
+    finally:
+        jax.monitoring.unregister_event_listener(listen)
+    hops = ["const", "const", "table", "table", "table"]
+    assert miss == [{"level": li, "path": "select", "hops": h}
+                    for li, h in enumerate(hops)]
     assert events == miss          # the hit recorded nothing
