@@ -13,6 +13,7 @@ from .baselines import (
     standard_gossip,
 )
 from .engine import EngineResult, execute_plan
+from .events import event_totals
 from .failures import handshake_cost
 from .gossip import (
     GOSSIP_BACKENDS,
@@ -111,6 +112,7 @@ __all__ = [
     "build_plan",
     "connectivity_radius",
     "dense_to_csr",
+    "event_totals",
     "execute_plan",
     "expected_retransmissions",
     "flat_usage_to_dense",

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .events import record_duration, record_event
 from .gossip import GOSSIP_BACKENDS, gossip_core
 from .medium import (
     CostModel,
@@ -313,8 +314,13 @@ def execute_plan(
     the dispatch.  A cache miss also records the lowering and compile
     seconds as the `jax.monitoring` duration events
     ``/repro/core/executor_lower`` and ``/repro/core/executor_compile``,
-    and one ``/repro/core/schedule_lookup`` event per level with how its
-    schedule reads partners and hops (`CsrGraphs.lookup`).
+    and per level the events ``/repro/core/schedule_lookup``, with how
+    its schedule reads partners and hops (`CsrGraphs.lookup`),
+    ``/repro/core/executor_consts``, with the ``bytes`` of the level's
+    plan arrays baked into the executor as constants, and in
+    fixed-iterations mode ``/repro/core/fixed_ticks``, with the level's
+    tick budget (``ticks``, rounded up to the check cadence ``check``);
+    `core.events.event_totals` keeps their running totals.
     On the device every executor op sits under a ``level_<i>`` (or
     ``final``) scope and one of `core.gossip.LAYER_SCOPES`.
     """
@@ -626,13 +632,16 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
                 opts = _CPU_COMPILER_OPTS if platform == "cpu" else None
                 fn = lowered.compile(compiler_options=opts)
             t2 = time.perf_counter()
-        jax.monitoring.record_event_duration_secs(
-            "/repro/core/executor_lower", t1 - t0)
-        jax.monitoring.record_event_duration_secs(
-            "/repro/core/executor_compile", t2 - t1)
+        record_duration("/repro/core/executor_lower", t1 - t0)
+        record_duration("/repro/core/executor_compile", t2 - t1)
         for li, c in enumerate(consts):
-            jax.monitoring.record_event("/repro/core/schedule_lookup",
-                                        level=li, **c["adj"].lookup)
+            record_event("/repro/core/schedule_lookup", level=li,
+                         **c["adj"].lookup)
+            record_event("/repro/core/executor_consts", level=li,
+                         bytes=sum(a.nbytes for a in jax.tree.leaves(c)))
+            if fixed_ticks_scale > 0:
+                record_event("/repro/core/fixed_ticks", level=li,
+                             ticks=maxt_levels[li], check=chk_levels[li])
         plan.exec_cache[cache_key] = fn
     return fn, args
 

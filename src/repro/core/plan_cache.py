@@ -34,8 +34,10 @@ import tempfile
 import time
 from typing import Any, Optional
 
+import jax
 import numpy as np
 
+from .events import record_duration
 from .plan import HierarchyPlan, build_plan
 from .rgg import Graph, random_geometric_graph
 
@@ -53,6 +55,8 @@ __all__ = [
 # bump on any change to plan layout or builder semantics; stale entries
 # then miss by construction
 PLAN_CACHE_VERSION = 1
+
+_span = jax.profiler.TraceAnnotation
 
 
 def default_cache_dir() -> str:
@@ -197,46 +201,68 @@ def setup_plan(
     plan_build_s, load_s | store_s, setup_s}.  `refresh=True` forces a
     rebuild (and re-store) even if an entry exists — the benchmark's
     cold path.
+
+    A profiler trace holds the span ``repro.setup_plan`` with children
+    ``.load`` (a cache lookup), ``.graph``, ``.plan`` and ``.store`` (the
+    steps of a miss).  A hit records the duration event
+    ``/repro/core/plan_load``; a miss records ``/repro/core/graph_build``
+    (seeded graphs only), ``/repro/core/plan_build`` and, with the cache
+    on, ``/repro/core/plan_store`` (`core.events`).
     """
     if (n is None) == (g is None):
         raise ValueError("pass exactly one of n= or g=")
-    t_all = time.perf_counter()
-    if g is None:
-        gspec = graph_spec(n, c=c, seed=graph_seed, radius=radius)
-    else:
-        gspec = graph_digest_spec(g)
-    key = plan_key(
-        gspec, k=k, a=a, cell_max=cell_max, seed=seed, rep_mode=rep_mode
-    )
-    info: dict[str, Any] = {"key": key, "graph_gen_s": 0.0}
-    if use_cache and not refresh:
-        t0 = time.perf_counter()
-        plan = load_plan(key, cache_dir=cache_dir)
-        if plan is not None:
-            info.update(
-                cache="hit",
-                load_s=round(time.perf_counter() - t0, 6),
-                plan_build_s=dict(plan.build_seconds or {}),
-                setup_s=round(time.perf_counter() - t_all, 6),
-            )
-            return plan, info
-    if g is None:
-        t0 = time.perf_counter()
-        g = random_geometric_graph(
-            n, c=c, seed=graph_seed, radius=radius, method=graph_method
+    with _span("repro.setup_plan"):
+        t_all = time.perf_counter()
+        if g is None:
+            gspec = graph_spec(n, c=c, seed=graph_seed, radius=radius)
+        else:
+            gspec = graph_digest_spec(g)
+        key = plan_key(
+            gspec, k=k, a=a, cell_max=cell_max, seed=seed,
+            rep_mode=rep_mode,
         )
-        info["graph_gen_s"] = round(time.perf_counter() - t0, 6)
-    plan = build_plan(
-        g, k=k, a=a, cell_max=cell_max, seed=seed, rep_mode=rep_mode,
-        workers=workers,
-    )
-    info["plan_build_s"] = dict(plan.build_seconds or {})
-    if use_cache:
+        info: dict[str, Any] = {"key": key, "graph_gen_s": 0.0}
+        if use_cache and not refresh:
+            t0 = time.perf_counter()
+            with _span("repro.setup_plan.load"):
+                plan = load_plan(key, cache_dir=cache_dir)
+            if plan is not None:
+                load_s = time.perf_counter() - t0
+                record_duration("/repro/core/plan_load", load_s)
+                info.update(
+                    cache="hit",
+                    load_s=round(load_s, 6),
+                    plan_build_s=dict(plan.build_seconds or {}),
+                    setup_s=round(time.perf_counter() - t_all, 6),
+                )
+                return plan, info
+        if g is None:
+            t0 = time.perf_counter()
+            with _span("repro.setup_plan.graph"):
+                g = random_geometric_graph(
+                    n, c=c, seed=graph_seed, radius=radius,
+                    method=graph_method,
+                )
+            graph_s = time.perf_counter() - t0
+            record_duration("/repro/core/graph_build", graph_s)
+            info["graph_gen_s"] = round(graph_s, 6)
         t0 = time.perf_counter()
-        store_plan(key, plan, cache_dir=cache_dir)
-        info["store_s"] = round(time.perf_counter() - t0, 6)
-        info["cache"] = "miss"
-    else:
-        info["cache"] = "off"
-    info["setup_s"] = round(time.perf_counter() - t_all, 6)
-    return plan, info
+        with _span("repro.setup_plan.plan"):
+            plan = build_plan(
+                g, k=k, a=a, cell_max=cell_max, seed=seed,
+                rep_mode=rep_mode, workers=workers,
+            )
+        record_duration("/repro/core/plan_build", time.perf_counter() - t0)
+        info["plan_build_s"] = dict(plan.build_seconds or {})
+        if use_cache:
+            t0 = time.perf_counter()
+            with _span("repro.setup_plan.store"):
+                store_plan(key, plan, cache_dir=cache_dir)
+            store_s = time.perf_counter() - t0
+            record_duration("/repro/core/plan_store", store_s)
+            info["store_s"] = round(store_s, 6)
+            info["cache"] = "miss"
+        else:
+            info["cache"] = "off"
+        info["setup_s"] = round(time.perf_counter() - t_all, 6)
+        return plan, info
