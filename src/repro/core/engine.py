@@ -316,6 +316,10 @@ def execute_plan(
     ``/repro/core/executor_lower`` and ``/repro/core/executor_compile``,
     and per level the events ``/repro/core/schedule_lookup``, with how
     its schedule reads partners and hops (`CsrGraphs.lookup`),
+    on the lax backend's presampled value pass
+    ``/repro/core/value_pass_lookup``, with how `pair_apply_ref` reads
+    a tick's endpoints in one node block (``path`` select or gather,
+    `kernels.pair_apply.ref.value_read_path`),
     ``/repro/core/executor_consts``, with the ``bytes`` of the level's
     plan arrays baked into the executor as constants, and in
     fixed-iterations mode ``/repro/core/fixed_ticks``, with the level's
@@ -637,6 +641,12 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
         for li, c in enumerate(consts):
             record_event("/repro/core/schedule_lookup", level=li,
                          **c["adj"].lookup)
+            if backend == "lax" and schedule == "presampled":
+                from repro.kernels.pair_apply.ref import value_read_path
+
+                B, C = plan.levels[li].node_mask.shape
+                record_event("/repro/core/value_pass_lookup", level=li,
+                             path=value_read_path(-(-B // nd), C))
             record_event("/repro/core/executor_consts", level=li,
                          bytes=sum(a.nbytes for a in jax.tree.leaves(c)))
             if fixed_ticks_scale > 0:

@@ -28,8 +28,9 @@ over the presampled arrays), then applies the pair list with the chosen
 value backend:
 
 * ``backend="lax"`` — `kernels.pair_apply.pair_apply_ref`: a scan whose
-  body is just two gathers, one average, and two conditional writes
-  (the legacy tick with all sampling hoisted out);
+  body is just two endpoint reads (one-hot selects over a cell's slots,
+  or gathers, by the level's shape), one average, and two conditional
+  writes (the legacy tick with all sampling hoisted out);
 * ``backend="pallas"`` — the `kernels.pair_apply` TPU kernel walks the
   schedule with cell state streamed through VMEM in blocks (no HBM
   round-trips within a block); its f32 op sequence matches the oracle
@@ -221,7 +222,12 @@ def gossip_core(
                 "failure scenarios / cost pricing are not supported on "
                 "the (trials, nodes) mesh")
     with _scope("convergence_check"):
-        live = node_mask.astype(x0.dtype)[..., None]  # (B, C, 1)
+        # the barrier keeps the float mask one value: with a plan's mask
+        # a constant, the TPU compiler otherwise folded it into a float
+        # constant and copied that once more for the chunk loop of a
+        # level whose value pass selects (17.5 MB more at n=10^6)
+        live = jax.lax.optimization_barrier(
+            node_mask.astype(x0.dtype))[..., None]    # (B, C, 1)
         denom = jnp.maximum(live.sum(1), 1.0)
         mean = (x0 * live).sum(1) / denom             # (B, V)
         x0_norm = jnp.sqrt(((x0 * live) ** 2).sum((1, 2)))

@@ -2,9 +2,9 @@
 pair-average recursion over a presampled exchange schedule.
 
 This is the value half of the legacy per-tick gossip scan with the
-sampling stripped out — same gathers, same 0.5 * (xi + xj), same
-conditional writes in the same order — so it is bitwise-identical to
-the historical path and serves as both the lax-backend hot loop and
+sampling stripped out — the same endpoint values, same 0.5 * (xi + xj),
+same conditional writes in the same order — so it is bitwise-identical
+to the historical path and serves as both the lax-backend hot loop and
 the Pallas kernel's parity oracle.
 """
 from __future__ import annotations
@@ -12,7 +12,41 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["pair_apply_ref"]
+__all__ = ["pair_apply_ref", "value_read_path"]
+
+
+# The endpoints x[i], x[j] are read by a one-hot select over a cell's
+# slots where there are at least VALUE_SELECT_MIN_B cells and a cell
+# has at most VALUE_SELECT_MAX_C slots; elsewhere by XLA gathers.  A
+# gather's output puts V on the TPU's lanes and drags the loop carry out
+# of its cells-on-lanes layout; a select reads all C slots of an
+# endpoint but keeps the cells on the lanes.  On a TPU v5e (64-tick
+# calls, V=2) the select won at every B >= 16 and C <= 100 swept: about
+# even at B=16, 1.2-2x at B of 64-256, 3-40x from B=2,500 on (18 ms
+# against 341 ms at 337,504 cells of 13).  It tied at B=4 and lost at
+# B=1 from C=8 on (5x slower at C=100).  On the CPU a select costs C
+# reads an endpoint, so cells wider than the sweep keep the gather.
+VALUE_SELECT_MIN_B = 16
+VALUE_SELECT_MAX_C = 100
+
+
+def value_read_path(B: int, C: int) -> str:
+    """How `pair_apply_ref` reads the endpoints of B cells of C slots:
+    ``"select"`` or ``"gather"``."""
+    if B >= VALUE_SELECT_MIN_B and C <= VALUE_SELECT_MAX_C:
+        return "select"
+    return "gather"
+
+
+def _select_slot(x, k):
+    """``x[b, k[b]]`` by a chain of selects over the C slots: exact, bit
+    for bit and -0.0 included (a masked sum would turn -0.0 into +0.0).
+    A k outside [0, C) reads slot 0, where a gather would clamp or wrap;
+    the schedule writes no row at such a k, so the state is the same."""
+    out = x[:, 0]
+    for c in range(1, x.shape[1]):
+        out = jnp.where((k == c)[:, None], x[:, c], out)
+    return out
 
 
 def pair_apply_ref(x, i, j, upd_i, upd_j):
@@ -24,16 +58,22 @@ def pair_apply_ref(x, i, j, upd_i, upd_j):
       upd_i, upd_j: (T, B) bool — whether the initiator / partner row
         actually updates at that tick (schedule validity, per-chunk
         done freeze, and per-hop loss outcomes already folded in).
-    Returns (B, C, V) state after the T ticks, in order.
+    Returns (B, C, V) state after the T ticks, in order.  The endpoints
+    are read as `value_read_path` says; both paths give the same bits.
     """
     B, C, V = x.shape
     bidx = jnp.arange(B)
     slots = jnp.arange(C)[None, :]
+    if value_read_path(B, C) == "select":
+        read = _select_slot
+    else:
+        def read(x, k):
+            return x[bidx, k]
 
     def tick(x, sched):
         it, jt, ui, uj = sched
-        xi = x[bidx, it]
-        xj = x[bidx, jt]
+        xi = read(x, it)
+        xj = read(x, jt)
         avg = 0.5 * (xi + xj)
         # row writes as one-hot masked selects, not scatters: the written
         # value is the identical float either way (no arithmetic on the

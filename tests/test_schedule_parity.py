@@ -339,6 +339,56 @@ def test_pair_apply_noop_when_masked():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
 
 
+# (B, C, V, T): both sides of `value_read_path`'s rule, a one-slot cell
+# among them; every case has ticks with i == j, all-masked ticks and
+# -0.0 among the values
+_VALUE_READ_CASES = [
+    (875, 8, 2, 48), (337, 13, 1, 24), (128, 32, 2, 16), (300, 1, 2, 8),
+    (16, 100, 2, 16), (3, 13, 2, 64), (4, 49, 2, 32), (1, 100, 2, 64),
+    (7, 5, 1, 32), (16, 101, 1, 8),
+]
+
+
+@pytest.mark.parametrize("B,C,V,T", _VALUE_READ_CASES)
+def test_value_read_paths_give_the_same_bits(B, C, V, T, monkeypatch):
+    """`pair_apply_ref` reads x[i], x[j] by a select over a cell's slots
+    or by gathers, as `value_read_path` picks from the shape: either
+    path, forced, gives the rule's result bit for bit, signs of zero
+    included."""
+    import jax.numpy as jnp
+
+    from repro.kernels.pair_apply import ref
+
+    rng = np.random.default_rng(B * C + V * T)
+    x = rng.normal(size=(B, C, V)).astype(np.float32)
+    x[rng.uniform(size=x.shape) < 0.1] = -0.0
+    x[B // 2, :, 0] = -0.0     # averages of -0.0 stay -0.0, a sum's do not
+    i = rng.integers(0, C, (T, B)).astype(np.int32)
+    j = rng.integers(0, C, (T, B)).astype(np.int32)
+    j[::3] = i[::3]                                  # i == j
+    ui = rng.uniform(size=(T, B)) < 0.8
+    uj = rng.uniform(size=(T, B)) < 0.9
+    ui[1::5] = uj[1::5] = False                      # all-masked ticks
+    args = [jnp.asarray(a) for a in (x, i, j, ui, uj)]
+    want = np.asarray(pair_apply_ref(*args)).view(np.uint32)
+    for path in ("select", "gather"):
+        monkeypatch.setattr(ref, "value_read_path", lambda B, C: path)
+        got = np.asarray(pair_apply_ref(*args)).view(np.uint32)
+        np.testing.assert_array_equal(got, want, err_msg=path)
+    assert (want == np.float32(-0.0).view(np.uint32)).any()
+
+
+@pytest.mark.parametrize("B,C,path", [
+    (337_504, 13, "select"), (16, 100, "select"),
+    (1, 100, "gather"), (15, 8, "gather"), (4, 49, "gather"),
+    (16, 101, "gather"),
+])
+def test_value_read_path_rule(B, C, path):
+    from repro.kernels.pair_apply.ref import value_read_path
+
+    assert value_read_path(B, C) == path
+
+
 # --------------------------- engine parity -----------------------------
 
 

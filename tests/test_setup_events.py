@@ -172,6 +172,29 @@ def test_event_totals_sum_durations_and_bytes(plan300, x300, tmp_path):
         2 * len(plan300.levels)
 
 
+def test_a_build_records_each_levels_value_pass_lookup():
+    """The paper's deployment (n=2000, five levels: 875 cells of 8
+    slots, then 256, 64 and 16 cells of 4 and one of 16): one event per
+    level on a build, none on a hit, with the path `value_read_path`
+    picks for the level's shape."""
+    from repro.kernels.pair_apply.ref import value_read_path
+
+    plan, _ = setup_plan(n=2000, c=3.0, graph_seed=100, a=2 / 3,
+                         cell_max=8.0, seed=0, rep_mode="random",
+                         use_cache=False)
+    x0 = np.random.default_rng(1).standard_normal(2000).astype(np.float32)
+    with _Events() as rec:
+        execute_plan(plan, x0, eps=1e-2, seeds=(1, 2))
+        miss = rec.named("/repro/core/value_pass_lookup")
+        execute_plan(plan, x0, eps=1e-2, seeds=(3, 4))
+    shapes = [lp.node_mask.shape for lp in plan.levels]
+    assert shapes == [(875, 8), (256, 4), (64, 4), (16, 4), (1, 16)]
+    paths = ["select", "select", "select", "select", "gather"]
+    assert [value_read_path(*bc) for bc in shapes] == paths
+    assert miss == [{"level": li, "path": p} for li, p in enumerate(paths)]
+    assert rec.named("/repro/core/value_pass_lookup") == miss   # a hit
+
+
 def _digest(a: np.ndarray) -> str:
     return hashlib.sha256(np.ascontiguousarray(a).tobytes()).hexdigest()[:16]
 

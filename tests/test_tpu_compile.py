@@ -128,3 +128,83 @@ def test_sample_schedule_compiles_for_v5e(one_chip, trials, B, C, D, hops):
     assert ("gather" in compiled.as_text()) == (path == "gather")
     scratch = compiled.memory_analysis().temp_size_in_bytes
     assert scratch <= 4 * T * trials * B * 4
+
+
+# (trials, B, C): the n=10^6 cells, the paper's cells under its
+# ten-trial batch, the n=10^6 top level and wide cells of a k=2 plan
+@pytest.mark.parametrize("trials,B,C", [
+    (1, 337_504, 13), (10, 875, 8), (1, 1, 100), (1, 4, 49),
+])
+def test_value_pass_compiles_for_v5e(one_chip, trials, B, C):
+    """The lax value pass lowers to the read `value_read_path` picks: a
+    select level keeps no gather after compiling and its select chain
+    stays fused (scratch memory no more than one copy of the state, and
+    1 MiB besides for the gather's buffers at a few cells)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.pair_apply import pair_apply_ref
+    from repro.kernels.pair_apply.ref import value_read_path
+
+    T, V = 64, 2
+    path = value_read_path(B, C)
+    x = jax.ShapeDtypeStruct((trials, B, C, V), jnp.float32,
+                             sharding=one_chip)
+    ij = jax.ShapeDtypeStruct((trials, T, B), jnp.int32, sharding=one_chip)
+    upd = jax.ShapeDtypeStruct((trials, T, B), jnp.bool_, sharding=one_chip)
+    fn = jax.vmap(pair_apply_ref)
+    if trials == 1:
+        def fn(x, i, j, ui, uj):
+            return pair_apply_ref(x[0], i[0], j[0], ui[0], uj[0])[None]
+    lowered = jax.jit(fn).lower(x, ij, ij, upd, upd)
+    assert ("gather" in lowered.as_text()) == (path == "gather")
+    compiled = lowered.compile()
+    if path == "select":
+        assert " gather(" not in compiled.as_text()
+    scratch = compiled.memory_analysis().temp_size_in_bytes
+    assert scratch <= trials * B * C * V * 4 + 2**20
+
+
+
+def test_the_executor_holds_each_mask_as_floats_once(one_chip, monkeypatch):
+    """The paper's deployment (n=2000, one trial) compiled as
+    `execute_plan` builds it, every plan array a constant: each level's
+    node mask is in the program as floats at most once.  The compiler
+    had copied that constant once more for the chunk loop of each level
+    whose value pass selects (17.5 MB more device memory at n=10^6)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import engine, setup_plan
+
+    plan, _ = setup_plan(n=2000, c=3.0, graph_seed=100, a=2 / 3,
+                         cell_max=8.0, seed=0, rep_mode="random",
+                         use_cache=False)
+    built = {}
+
+    class Built(Exception):
+        pass
+
+    def over_trials(*args, **kwargs):
+        built["run"] = over_trials.orig(*args, **kwargs)
+        raise Built
+
+    over_trials.orig = engine._over_trials
+    monkeypatch.setattr(engine, "_over_trials", over_trials)
+    x0 = np.zeros(2000, np.float32)
+    with pytest.raises(Built):
+        engine.execute_plan(plan, x0, eps=1e-4, seeds=(1,), weighted=True)
+    L = len(plan.levels)
+    text = _compile_text(
+        built["run"],
+        jax.ShapeDtypeStruct((2000,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1, 2), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((L,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((L,), jnp.int32, sharding=one_chip),
+    )
+    for lp in plan.levels:
+        B, C = lp.node_mask.shape
+        assert len(re.findall(rf"f32\[{B},{C}\]\S* constant\(", text)) <= 1
