@@ -8,8 +8,7 @@ span >= 1 decade of n.
 
 Trial-vmapping note: the multiscale benchmarks run all trials of one
 configuration in a single compiled vmapped call (`multiscale_gossip(...,
-trials=T, backend=...)`); artifacts record `wall_clock_s` per algorithm
-plus the `backend` used so perf regressions are visible in CI diffs.
+trials=T)`); artifacts record `wall_clock_s` per algorithm.
 """
 from __future__ import annotations
 
@@ -23,19 +22,6 @@ from repro.launch.compile_cache import enable_compile_cache
 
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "artifacts")
 
-ENGINE_BACKENDS = ("lax", "pallas", "matmul")
-SCHEDULES = ("presampled", "per_tick")
-
-
-def exec_options(backend: str = "lax", schedule: str = "presampled", **kw):
-    """The figure benchmarks' uniform `ExecOptions` constructor: every
-    `run()` takes the same (backend, schedule) pair and threads it to
-    the engine through here instead of the deprecated flat kwargs."""
-    from repro.core import ExecOptions
-
-    return ExecOptions(backend=backend, schedule=schedule, **kw)
-
-
 def _tuple_arg(elem):
     def parse(s):
         return tuple(elem(x) for x in s.split(","))
@@ -46,8 +32,8 @@ def bench_cli(run_fn, argv=None) -> None:
     """Uniform standalone CLI for `python -m benchmarks.figX`.
 
     Builds argparse flags from `run_fn`'s keyword defaults, so every
-    figure benchmark exposes the same surface (--trials, --backend,
-    --schedule, --artifact, plus its own numeric knobs) without each
+    figure benchmark exposes the same surface (--trials, --artifact,
+    plus its own numeric knobs) without each
     module hand-rolling a parser.  Tuple defaults parse as
     comma-separated lists (e.g. ``--sizes 500,1000``).
     """
@@ -60,11 +46,7 @@ def bench_cli(run_fn, argv=None) -> None:
         if d is inspect.Parameter.empty or d is None:
             continue
         flag = f"--{name.replace('_', '-')}"
-        if name == "backend":
-            ap.add_argument(flag, default=d, choices=ENGINE_BACKENDS)
-        elif name == "schedule":
-            ap.add_argument(flag, default=d, choices=SCHEDULES)
-        elif isinstance(d, bool):
+        if isinstance(d, bool):
             ap.add_argument(flag, action=argparse.BooleanOptionalAction,
                             default=d)
         elif isinstance(d, tuple):

@@ -8,13 +8,11 @@ import numpy as np
 
 from repro.core import build_plan, multiscale_gossip, random_geometric_graph
 
-from .common import csv_line, exec_options, save_artifact, timed
+from .common import csv_line, save_artifact, timed
 
 
 def run(n: int = 2000, trials: int = 3, eps: float = 1e-4,
-        max_k: int = 6, backend: str = "lax", schedule: str = "presampled",
-        artifact: str = "fig2_levels") -> list[str]:
-    opts = exec_options(backend, schedule)
+        max_k: int = 6, artifact: str = "fig2_levels") -> list[str]:
     rows = {}
     plan_build_s: dict = {}
     graph_gen: list[float] = []
@@ -31,7 +29,6 @@ def run(n: int = 2000, trials: int = 3, eps: float = 1e-4,
             builds.append(plan.build_seconds or {})
             r = multiscale_gossip(
                 g, x0, eps=eps, k=k, seed=t, weighted=True, plan=plan,
-                options=opts,
             )
             msgs.append(r.messages)
             errs.append(r.error(x0))
@@ -46,8 +43,7 @@ def run(n: int = 2000, trials: int = 3, eps: float = 1e-4,
         }
     save_artifact(
         artifact,
-        {"n": n, "eps": eps, "backend": backend, "schedule": schedule,
-         "rows": rows, "plan_build_s": plan_build_s,
+        {"n": n, "eps": eps, "rows": rows, "plan_build_s": plan_build_s,
          "graph_gen_s": float(np.mean(graph_gen))},
     )
     total_us = (time.time() - t0) * 1e6

@@ -7,11 +7,11 @@ transmissions than path averaging, near-linear growth in n.
 
 Multiscale variants run through the plan/execute core: one
 `HierarchyPlan` per (n, partition config), all trials vmapped into a
-single compiled call.  Wall-clock per algorithm and the engine backend
-are recorded in the artifact.
+single compiled call.  Wall-clock per algorithm is recorded in the
+artifact.
 
 Standalone:  python -m benchmarks.fig3_vs_path_averaging \
-                 [--sizes 500,1000] [--trials 3] [--backend lax|pallas]
+                 [--sizes 500,1000] [--trials 3]
 """
 from __future__ import annotations
 
@@ -21,10 +21,10 @@ from repro.core import (
     build_plan, multiscale_gossip, path_averaging, random_geometric_graph,
 )
 
-from .common import csv_line, exec_options, save_artifact, timed
+from .common import csv_line, save_artifact, timed
 
 
-def _warm_jit(opts) -> float:
+def _warm_jit() -> float:
     """Absorb one-time XLA/LLVM process-init cost before the timed rows.
 
     Compiles a throwaway executor on a tiny unrelated graph: none of the
@@ -39,23 +39,22 @@ def _warm_jit(opts) -> float:
         # (allocator, lowering-rule caches)
         for n in (24, 40):
             g = random_geometric_graph(n, seed=9)
-            multiscale_gossip(g, np.zeros(n), eps=1e-2, seed=0, options=opts)
+            multiscale_gossip(g, np.zeros(n), eps=1e-2, seed=0)
 
     _, dt = timed(warm)
     return dt
 
 
 def run(sizes=(500, 1000, 2000, 4000, 8000), trials: int = 3,
-        eps: float = 1e-4, backend: str = "lax", schedule: str = "presampled",
+        eps: float = 1e-4,
         artifact: str = "fig3_vs_path_averaging") -> list[str]:
-    opts = exec_options(backend, schedule)
     algo_names = ["multiscale", "multiscale_fi", "multiscale_2level",
                   "path_averaging"]
     table: dict = {a: {} for a in algo_names}
     timing: dict = {a: 0.0 for a in algo_names}
     plan_build_s: dict = {}
     graph_gen_s: dict = {}
-    warmup_s = _warm_jit(opts)
+    warmup_s = _warm_jit()
 
     def record(name, n, res, x0, dt):
         timing[name] += dt
@@ -85,7 +84,7 @@ def run(sizes=(500, 1000, 2000, 4000, 8000), trials: int = 3,
         def run_ms(name):
             r, dt = timed(
                 multiscale_gossip, g, x0 if trials > 1 else x0[0], eps=eps,
-                seed=0, weighted=True, trials=trials, options=opts,
+                seed=0, weighted=True, trials=trials,
                 **ms_variants[name],
             )
             return name, r, dt
@@ -133,8 +132,6 @@ def run(sizes=(500, 1000, 2000, 4000, 8000), trials: int = 3,
         {
             "eps": eps,
             "trials": trials,
-            "backend": backend,
-            "schedule": schedule,
             # trials share one deployment per n (graph seed 1000+n, the
             # vmapped plan/execute design): messages variance is gossip
             # noise only, NOT across-graph variance as in the paper's

@@ -4,8 +4,8 @@ Expected (paper): the busiest multiscale node transmits less than
 ~22% of path-averaging nodes do — load is spread, no hot relays.
 
 Multiscale trials run vmapped through the plan/execute engine; the CDF
-aggregates node sends over all trials.  Wall-clock per algorithm and the
-backend are recorded in the artifact.
+aggregates node sends over all trials.  Wall-clock per algorithm is
+recorded in the artifact.
 """
 from __future__ import annotations
 
@@ -13,17 +13,16 @@ import numpy as np
 
 from repro.core import multiscale_gossip, path_averaging, random_geometric_graph
 
-from .common import csv_line, exec_options, save_artifact, timed
+from .common import csv_line, save_artifact, timed
 
 
 def run(n: int = 2000, eps: float = 1e-4, seed: int = 0, trials: int = 3,
-        backend: str = "lax", schedule: str = "presampled",
         artifact: str = "fig4_cdf") -> list[str]:
     g = random_geometric_graph(n, seed=42)
     x0 = np.random.default_rng(7).normal(0, 1, n)
     ms, t_ms = timed(
         multiscale_gossip, g, x0, eps=eps, seed=seed, weighted=True,
-        trials=trials, options=exec_options(backend, schedule),
+        trials=trials,
     )
     pa_runs, t_pa = timed(lambda: [
         path_averaging(g, x0, eps=eps, seed=seed + t) for t in range(trials)
@@ -49,8 +48,6 @@ def run(n: int = 2000, eps: float = 1e-4, seed: int = 0, trials: int = 3,
     payload = {
         "n": n,
         "trials": trials,
-        "backend": backend,
-        "schedule": schedule,
         "trial_mode": "vmapped",
         "wall_clock_s": {"multiscale": t_ms, "path_averaging": t_pa},
         "ms_max_trial_mean": ms_max,

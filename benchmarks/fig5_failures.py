@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import (
     CostModel,
+    ExecOptions,
     FailureModel,
     build_plan,
     multiscale_gossip,
@@ -37,24 +38,22 @@ from repro.core import (
     scenario_matrix,
 )
 
-from .common import csv_line, exec_options, save_artifact, timed
+from .common import csv_line, save_artifact, timed
 
 
 def run(n: int = 2000, eps: float = 1e-4,
         ps=(0.5, 0.6, 0.7, 0.8, 0.9, 1.0), trials: int = 3,
-        backend: str = "lax", schedule: str = "presampled",
         scenario_trials: int = 0, scenario_scale: float = 0.25,
         scenario_retransmit_p: float = 0.9,
         artifact: str = "fig5_failures") -> list[str]:
     """`scenario_trials > 0` appends the failure-scenario matrix (at the
     same n, fixed-iterations mode) to the artifact and CSV."""
-    opts = exec_options(backend, schedule)
     g = random_geometric_graph(n, seed=21)
     x0 = np.random.default_rng(3).normal(0, 1, n)
     timing = {}
     ms, timing["multiscale"] = timed(
         multiscale_gossip, g, x0, eps=eps, seed=0, weighted=True,
-        trials=trials, options=opts,
+        trials=trials,
     )
     pa_runs, timing["path_averaging"] = timed(lambda: [
         path_averaging(g, x0, eps=eps, seed=t) for t in range(trials)
@@ -83,7 +82,7 @@ def run(n: int = 2000, eps: float = 1e-4,
     # message-loss model (changes the trajectory): bounded budgets, the
     # same `trials` seeds as the reliable runs (multiscale vmapped)
     loss_p = 0.9
-    loss_opts = exec_options(backend, schedule, max_ticks_per_level=60_000)
+    loss_opts = ExecOptions(max_ticks_per_level=60_000)
     ms_loss, timing["multiscale_loss"] = timed(
         multiscale_gossip, g, x0, eps=eps, seed=0, weighted=True,
         trials=trials, options=loss_opts, failures=FailureModel(loss_p=loss_p),
@@ -123,7 +122,7 @@ def run(n: int = 2000, eps: float = 1e-4,
         sc_res, timing["scenario_matrix"] = timed(
             run_scenario_matrix, g, x0, scenario_matrix(),
             eps=eps, trials=scenario_trials, seed=0, weighted=True,
-            fixed_ticks_scale=scenario_scale, options=opts, cost=sc_cost,
+            fixed_ticks_scale=scenario_scale, cost=sc_cost,
             plan=plan,
         )
         scenarios = {
@@ -145,8 +144,6 @@ def run(n: int = 2000, eps: float = 1e-4,
         "n": n,
         "eps": eps,
         "trials": trials,
-        "backend": backend,
-        "schedule": schedule,
         "trial_mode": "vmapped",
         "wall_clock_s": {k: float(v) for k, v in timing.items()},
         "handshake": handshake,

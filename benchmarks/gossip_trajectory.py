@@ -1,9 +1,8 @@
 """BENCH_gossip.json — the standardized gossip perf-trajectory artifact.
 
 Every entry snapshots the simulation hot path's measured performance at
-one commit: the fig3 smoke wall-clocks per engine backend (from the
-backend-suffixed smoke artifacts `fig3_smoke_lax` / `fig3_smoke_pallas`)
-plus the pair-apply kernel microbenchmark sweep.  The file lives at the
+one commit: the fig3 smoke wall-clocks (from the smoke artifact
+`fig3_smoke_lax`) plus the value-pass microbenchmark sweep.  The file lives at the
 repo root and is append-only (one entry per (commit, label);
 re-running replaces that entry), so future PRs diff their numbers
 against a measured baseline instead of an empty trajectory.
@@ -31,7 +30,7 @@ TRAJECTORY = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "BENCH_gossip.json",
 )
-SMOKE_ARTIFACTS = {"lax": "fig3_smoke_lax", "pallas": "fig3_smoke_pallas"}
+SMOKE_ARTIFACTS = {"lax": "fig3_smoke_lax"}
 
 
 def _git_commit() -> str:
@@ -71,12 +70,12 @@ def validate_entry(entry: dict) -> None:
     ut = entry.get("unix_time")
     if not isinstance(ut, int) or ut <= 0:
         raise ValueError(f"gossip_trajectory entry missing unix_time: {ut!r}")
-    for backend, rec in entry.get("fig3_smoke", {}).items():
+    for smoke, rec in entry.get("fig3_smoke", {}).items():
         if "missing" in rec:
             continue
         if not isinstance(rec.get("jit_warmup_s"), (int, float)):
             raise ValueError(
-                f"gossip_trajectory entry fig3_smoke[{backend!r}] lacks "
+                f"gossip_trajectory entry fig3_smoke[{smoke!r}] lacks "
                 f"jit_warmup_s — regenerate the smoke artifact "
                 f"(REPRO_BENCH_SMOKE=1 tools/ci.sh) before recording"
             )
@@ -105,15 +104,15 @@ def build_entry(label: str = "", kernels: bool = True) -> dict:
         "label": label,
         "fig3_smoke": {},
     }
-    for backend, name in SMOKE_ARTIFACTS.items():
+    for smoke, name in SMOKE_ARTIFACTS.items():
         art = load_artifact(name)
         if art is None:
-            entry["fig3_smoke"][backend] = {
+            entry["fig3_smoke"][smoke] = {
                 "missing": f"benchmarks/artifacts/{name}.json — run "
                            "REPRO_BENCH_SMOKE=1 tools/ci.sh first"
             }
             continue
-        entry["fig3_smoke"][backend] = {
+        entry["fig3_smoke"][smoke] = {
             "n": sorted(int(n) for a in art["summary"].values() for n in a)[0],
             "trials": art["trials"],
             "jit_warmup_s": art.get("jit_warmup_s"),
@@ -133,7 +132,6 @@ def build_entry(label: str = "", kernels: bool = True) -> dict:
         large[name] = {
             "n": art["n"],
             "trials": art["trials"],
-            "backend": art["backend"],
             "fixed_ticks_scale": art["fixed_ticks_scale"],
             "messages": art["messages"],
             "err": art["err"],
@@ -158,14 +156,14 @@ def run(label: str = "", kernels: bool = True) -> list[str]:
     entry = build_entry(label=label, kernels=kernels)
     record_entry(entry)
     lines = []
-    for backend, rec in entry["fig3_smoke"].items():
+    for smoke, rec in entry["fig3_smoke"].items():
         if "missing" in rec:
-            lines.append(csv_line(f"gossip/fig3_smoke_{backend}", 0.0,
+            lines.append(csv_line(f"gossip/fig3_smoke_{smoke}", 0.0,
                                   rec["missing"]))
             continue
         ms = rec["wall_clock_s"].get("multiscale", 0.0)
         lines.append(csv_line(
-            f"gossip/fig3_smoke_{backend}", ms * 1e6,
+            f"gossip/fig3_smoke_{smoke}", ms * 1e6,
             f"n={rec['n']} multiscale_wall={ms:.2f}s "
             f"msgs={rec['messages_mean'].get('multiscale', 0):.0f}",
         ))

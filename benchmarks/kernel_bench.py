@@ -25,10 +25,8 @@ def pair_apply_bench(
     as_rows: bool = True,
 ):
     """Presampled-schedule value-pass sweep over (schedule length T,
-    cell size C): the lax scan oracle, the Pallas pair-apply kernel in
-    interpret mode (kernel-validation path — NOT TPU performance), and
-    the associative-scan matmul composition (compose + cell-mixing
-    apply, the MXU-facing backend's XLA oracle path).
+    cell size C): `core.value_pass.pair_apply`, jitted (key suffix
+    ``lax``, as in the trajectory's earlier entries).
 
     Returns CSV rows (`as_rows=True`) or a {key: us_per_call} dict for
     the BENCH_gossip.json trajectory.
@@ -37,9 +35,7 @@ def pair_apply_bench(
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core.schedule import compose_schedule
-    from repro.kernels.cell_mixing import cell_mixing
-    from repro.kernels.pair_apply import pair_apply, pair_apply_ref
+    from repro.core.value_pass import pair_apply
     from .common import csv_line
 
     rng = np.random.default_rng(11)
@@ -51,27 +47,14 @@ def pair_apply_bench(
         ui = jnp.asarray(rng.uniform(size=(T, B)) < 0.9)
         uj = jnp.asarray(rng.uniform(size=(T, B)) < 0.95)
 
-        lax_f = jax.jit(pair_apply_ref)
-        matmul_f = jax.jit(lambda x, i, j, ui, uj: cell_mixing(
-            compose_schedule(C, i, j, ui, uj), x, rounds=1, use_pallas=False
+        apply_f = jax.jit(pair_apply)
+        us = _time(lambda: apply_f(x, i, j, ui, uj))
+        key = f"T{T}_C{C}_lax"
+        flat[key] = us
+        rows.append(csv_line(
+            f"kernel/pair_apply_{key}", us,
+            f"B={B} V={V} ticks_per_us={T/max(us,1e-9):.2f}",
         ))
-        variants = {
-            "lax": lambda: lax_f(x, i, j, ui, uj),
-            "pallas_interp": lambda: pair_apply(
-                x, i, j, ui, uj, use_pallas=True, interpret=True
-            ),
-            "matmul": lambda: matmul_f(x, i, j, ui, uj),
-        }
-        for name, fn in variants.items():
-            us = _time(fn, reps=3 if name == "pallas_interp" else 5)
-            key = f"T{T}_C{C}_{name}"
-            flat[key] = us
-            rows.append(csv_line(
-                f"kernel/pair_apply_{key}", us,
-                f"B={B} V={V} ticks_per_us={T/max(us,1e-9):.2f}"
-                + (" (interpreter, not TPU perf)"
-                   if name == "pallas_interp" else ""),
-            ))
     return rows if as_rows else flat
 
 
@@ -105,7 +88,7 @@ def run() -> list[str]:
         f"cells={len(subs)} ticks={ticks} ticks_per_sec={ticks/dt:.0f}",
     ))
 
-    # presampled-schedule value pass (lax vs pallas vs associative-scan)
+    # presampled-schedule value pass
     lines.extend(pair_apply_bench())
 
     # synchronous cell mixing (jnp oracle = production XLA path)

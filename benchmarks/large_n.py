@@ -56,19 +56,16 @@ from tools.membuf_probe import memory_report  # noqa: E402
 OVERLAP_TOLERANCE = 0.15
 
 
-def _execute_stats(plan, x0, *, eps, fixed_ticks_scale, seeds, backend):
-    from repro.core import ExecOptions
-
+def _execute_stats(plan, x0, *, eps, fixed_ticks_scale, seeds):
     res, dt = timed(
         execute_plan, plan, x0, eps=eps, seeds=seeds, weighted=True,
         fixed_ticks_scale=fixed_ticks_scale,
-        options=ExecOptions(backend=backend),
     )
     return res, dt
 
 
 def overlap_check(overlap_n: int, *, eps: float, fixed_ticks_scale: float,
-                  backend: str, seed: int = 0) -> dict:
+                  seed: int = 0) -> dict:
     """Execute reference-built vs vectorized-built plans at a size both
     can afford; return the message-count comparison."""
     g = random_geometric_graph(overlap_n, seed=1000 + overlap_n)
@@ -78,7 +75,7 @@ def overlap_check(overlap_n: int, *, eps: float, fixed_ticks_scale: float,
         plan = build_plan(g, seed=seed, method=method)
         res, _ = _execute_stats(
             plan, x0, eps=eps, fixed_ticks_scale=fixed_ticks_scale,
-            seeds=(seed,), backend=backend,
+            seeds=(seed,),
         )
         msgs[method] = int(res.messages[0])
     ratio = msgs["vectorized"] / max(msgs["reference"], 1)
@@ -100,14 +97,13 @@ def default_cache_dir() -> str:
 
 def run(n: int = 100_000, overlap_n: int = 2000, trials: int = 1,
         eps: float = 1e-3, fixed_ticks_scale: float = 0.2,
-        backend: str = "lax", seed: int = 0, workers: int = 0,
+        seed: int = 0, workers: int = 0,
         cache_dir: str | None = None,
         artifact: str | None = None) -> list[str]:
     artifact = artifact or f"large_n_{n}"
     cache_dir = cache_dir or default_cache_dir()
     overlap = overlap_check(
-        overlap_n, eps=eps, fixed_ticks_scale=fixed_ticks_scale,
-        backend=backend, seed=seed,
+        overlap_n, eps=eps, fixed_ticks_scale=fixed_ticks_scale, seed=seed,
     ) if overlap_n else None
 
     # cold setup: streamed graph gen + plan build, forced fresh (the
@@ -131,16 +127,15 @@ def run(n: int = 100_000, overlap_n: int = 2000, trials: int = 1,
     seeds = tuple(seed + t for t in range(trials))
     res, cold_s = _execute_stats(
         plan, x0, eps=eps, fixed_ticks_scale=fixed_ticks_scale,
-        seeds=seeds, backend=backend,
+        seeds=seeds,
     )
     _, warm_s = _execute_stats(
         plan, x0, eps=eps, fixed_ticks_scale=fixed_ticks_scale,
-        seeds=seeds, backend=backend,
+        seeds=seeds,
     )
     payload = {
         "n": int(n),
         "trials": trials,
-        "backend": backend,
         "mode": "fixed_iterations",
         "eps": eps,
         "fixed_ticks_scale": fixed_ticks_scale,
@@ -212,7 +207,6 @@ if __name__ == "__main__":
     ap.add_argument("--eps", type=float, default=1e-3)
     ap.add_argument("--scale", type=float, default=0.2,
                     help="fixed_ticks_scale (FI tick budget)")
-    ap.add_argument("--backend", default="lax")
     ap.add_argument("--workers", type=int, default=0,
                     help="fork-pool width for plan construction "
                          "(bitwise-identical to serial; wall-clock only)")
@@ -228,7 +222,7 @@ if __name__ == "__main__":
     enable_compile_cache()
     for line in run(
         n=args.n, overlap_n=args.overlap_n, trials=args.trials,
-        eps=args.eps, fixed_ticks_scale=args.scale, backend=args.backend,
+        eps=args.eps, fixed_ticks_scale=args.scale,
         workers=args.workers, cache_dir=args.cache_dir,
         artifact=args.artifact,
     ):

@@ -14,74 +14,12 @@ import os
 from .common import ARTIFACTS, csv_line
 
 
-# v5e single-core peaks used by the analytic pair-apply cells below
-_V5E_F32_FLOPS = 9.85e13   # MXU f32 (half the 197 TF bf16 figure)
-_V5E_HBM_BPS = 8.19e11
-
-
-def pair_apply_roofline(
-    sweep=((64, 16), (64, 64), (256, 16), (256, 64)),
-    B: int = 256, V: int = 2,
-) -> list[str]:
-    """Analytic roofline cells for the presampled-schedule value pass
-    (schedule length T x cell size C): modeled HBM traffic, flops, and
-    arithmetic intensity for the three backends.
-
-    * lax (XLA scan): the (B, C, V) state round-trips memory every tick
-      (the select-based row update materializes the full state), so
-      bytes grow with T while flops stay tiny — deep in the
-      memory-bound regime;
-    * pallas pair_apply: one state load + one store per chunk plus the
-      SMEM schedule — traffic is T-independent, which is the whole
-      point of walking the schedule in VMEM;
-    * matmul composition: log2(T) batched (C, C) GEMMs trade extra
-      flops for MXU-shaped work (intensity grows with C).
-    """
-    rows = []
-    out = {}
-    for T, C in sweep:
-        state_b = B * C * V * 4
-        sched_b = 4 * T * B * 4
-        flops_apply = T * B * 2 * V
-        cells = {
-            "lax": (2 * T * state_b, flops_apply),
-            "pallas": (2 * state_b + sched_b, flops_apply),
-            "matmul": (
-                T * B * C * C * 4 + 2 * state_b,
-                (T - 1) * B * 2 * C**3 + B * 2 * C * C * V,
-            ),
-        }
-        for name, (bytes_, flops) in cells.items():
-            ai = flops / bytes_
-            t_mem = bytes_ / _V5E_HBM_BPS
-            t_cmp = flops / _V5E_F32_FLOPS
-            bound = "compute" if t_cmp > t_mem else "memory"
-            out[f"T{T}_C{C}_{name}"] = {
-                "bytes": bytes_, "flops": flops, "intensity": ai,
-                "bound": bound, "modeled_us": max(t_mem, t_cmp) * 1e6,
-            }
-            rows.append(csv_line(
-                f"roofline/pair_apply_T{T}_C{C}_{name}",
-                max(t_mem, t_cmp) * 1e6,
-                f"B={B} bytes={bytes_/1e6:.2f}MB flops={flops/1e6:.2f}MF "
-                f"AI={ai:.3f} bound={bound}",
-            ))
-    os.makedirs(ARTIFACTS, exist_ok=True)
-    with open(os.path.join(ARTIFACTS, "pair_apply_roofline.json"), "w") as f:
-        json.dump({"B": B, "V": V, "cells": out}, f, indent=1)
-    rows.append(csv_line(
-        "roofline/pair_apply_table", 0.0,
-        f"cells={len(out)} -> benchmarks/artifacts/pair_apply_roofline.json",
-    ))
-    return rows
-
-
 def run() -> list[str]:
-    lines = pair_apply_roofline()
+    lines = []
     paths = sorted(glob.glob(os.path.join(ARTIFACTS, "dryrun", "*.json")))
     if not paths:
-        return lines + [csv_line("roofline/missing", 0.0,
-                        "run `python -m repro.launch.dryrun --all` first")]
+        return [csv_line("roofline/missing", 0.0,
+                         "run `python -m repro.launch.dryrun --all` first")]
     rows = []
     for p in paths:
         r = json.load(open(p))

@@ -5,10 +5,8 @@ Prints ``name,us_per_call,derived`` CSV.  Profiles:
   --full:  the paper's trial counts / sizes (longer).
 
 Every figure benchmark exposes the same `run()` surface — `trials`,
-`backend`, `schedule`, `artifact` plus its own size knobs — so the
-harness dispatches them from one profile table instead of
-special-casing each module; `--backend` / `--schedule` apply to all of
-them at once.
+`artifact` plus its own size knobs — so the harness dispatches them
+from one profile table instead of special-casing each module.
 
 The dry-run roofline cells are produced separately
 (`python -m repro.launch.dryrun --all`, hours of XLA compile time) and
@@ -29,10 +27,6 @@ def main() -> None:
                     help="paper-scale trials (slow)")
     ap.add_argument("--only", default=None,
                     help="comma-separated subset, e.g. fig3,roofline")
-    ap.add_argument("--backend", default="lax",
-                    help="engine backend for every figure benchmark")
-    ap.add_argument("--schedule", default="presampled",
-                    help="engine schedule mode for every figure benchmark")
     args = ap.parse_args()
     keep = set(args.only.split(",")) if args.only else None
 
@@ -69,9 +63,7 @@ def main() -> None:
         kwargs = dict(base)
         if args.full:
             kwargs.update(full)
-        return lambda: mod.run(
-            backend=args.backend, schedule=args.schedule, **kwargs
-        )
+        return lambda: mod.run(**kwargs)
 
     suites = {name: fig_suite(*spec) for name, spec in figures.items()}
     suites.update({

@@ -13,18 +13,16 @@ import numpy as np
 
 from repro.core import multiscale_gossip, random_geometric_graph
 
-from .common import csv_line, exec_options, save_artifact
+from .common import csv_line, save_artifact
 
 
 def run(n: int = 2000, eps: float = 1e-4, k: int = 5, seed: int = 0,
-        trials: int = 1, backend: str = "lax", schedule: str = "presampled",
-        artifact: str = "table1_node_utilization") -> list[str]:
+        trials: int = 1, artifact: str = "table1_node_utilization") -> list[str]:
     t0 = time.time()
     g = random_geometric_graph(n, seed=11)
     x0 = np.random.default_rng(1).normal(0, 1, n)
     r = multiscale_gossip(g, x0, eps=eps, k=k, seed=seed, rep_mode="random",
-                          weighted=True, trials=trials,
-                          options=exec_options(backend, schedule))
+                          weighted=True, trials=trials)
     # trial-mean per-node sends (a single trial keeps the historical
     # numbers bit-for-bit; the election — rep_counts — is plan-shared)
     node_sends = np.atleast_2d(r.node_sends).mean(axis=0)
@@ -38,8 +36,7 @@ def run(n: int = 2000, eps: float = 1e-4, k: int = 5, seed: int = 0,
         }
     avg_degree = float(g.degrees.mean())
     payload = {
-        "n": n, "k": k, "trials": trials, "backend": backend,
-        "schedule": schedule, "rows": rows,
+        "n": n, "k": k, "trials": trials, "rows": rows,
         "all_mean": float(node_sends.mean()),
         "all_std": float(node_sends.std()),
         "avg_degree": avg_degree,

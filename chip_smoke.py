@@ -1,35 +1,25 @@
 #!/usr/bin/env python3
-"""Smoke run of the multiscale-gossip core on a TPU.
+"""Smoke run of the multiscale-gossip core across four TPU chips.
 
-    python chip_smoke.py              # phases A and B on one chip
-    python chip_smoke.py --chips 4    # the mesh and training-sync phases
+    python chip_smoke.py --chips 4
 
-It drives the paper's main path, `build_plan` -> `execute_plan`, once
-with the Pallas value pass (`backend="pallas"`) and once with the `lax`
-reference, both on the chip, and checks that they agree bit for bit:
+It runs only what exists across chips, with default `ExecOptions`:
 
-* phase A, the paper's deployment: an RGG of n=2000 nodes, eps=1e-4,
-  the mass-weighted variant, 10 trials in one vmapped call.  Every
-  trial's error against the exact float64 mean is within the Theorem 2
-  bound sqrt(6)*n*eps;
-* phase B, a million-node deployment (the large-n configuration of
-  `benchmarks/large_n.py`): n=10^6, fixed iterations at
-  `fixed_ticks_scale=0.2`, error <= 1e-3.
+* phase mesh: a million-node deployment (the large-n configuration of
+  `benchmarks/large_n.py`: n=10^6, fixed iterations at
+  `fixed_ticks_scale=0.2`) on a (trials=1, nodes=4) mesh against the
+  same plan on one chip, bit for bit (x_final, messages, node sends,
+  per-level ticks);
+* phase sync: the training sync `execute_sync_sharded` against
+  `execute_sync` for 4 replicas, one per chip.
 
-In both, `x_final` is bitwise equal between the backends, messages,
-node sends and per-level ticks are equal, and the compiled pallas
-executor holds the kernel (`tpu_custom_call`), so the kernel ran and
-not an oracle.
-
-With `--chips 4` it runs only what exists across chips: phase B's plan
-on a (trials=1, nodes=4) mesh against the same plan on one chip
-(bitwise), and the training sync `execute_sync_sharded` against
-`execute_sync` for 4 replicas, one per chip.
+What one chip runs is measured by the benchmark's cells
+(`python3 -m bench.run`), each checked against a float64 replay.
 
 Times printed on the way are one smoke run's wall clock, not metrics.
 The last line of standard output is one JSON object naming the device,
-printed only when every check passed.  Without a TPU it exits non-zero
-before running anything.
+printed only when every check passed.  Without four TPU chips it exits
+non-zero before running anything.
 """
 from __future__ import annotations
 
@@ -46,17 +36,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 import jax  # noqa: E402
 
-from repro.core import (  # noqa: E402
-    ExecOptions,
-    build_plan,
-    execute_plan,
-    random_geometric_graph,
-    setup_plan,
-    theorem2_bound,
-)
+from repro.core import ExecOptions, execute_plan, setup_plan  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
-BACKENDS = ("pallas", "lax")
 LARGE_N = 10**6
 
 
@@ -77,11 +59,6 @@ def tpu_devices(chips: int) -> list:
     if len(devs) < chips:
         sys.exit(f"chip_smoke: {chips} chips asked for, {len(devs)} found")
     return devs
-
-
-def peak_bytes(dev) -> int | None:
-    stats = dev.memory_stats() or {}
-    return stats.get("peak_bytes_in_use")
 
 
 def log_levels(plan) -> None:
@@ -117,54 +94,6 @@ def require_same(a, b, what: str) -> None:
             f"{what}: level ticks {a.level_ticks} != {b.level_ticks}")
 
 
-def require_kernel(plan) -> None:
-    """The compiled pallas executor must hold the Mosaic kernel."""
-    texts = [fn.as_text() for key, fn in plan.exec_cache.items()
-             if "pallas" in key]
-    require(bool(texts), "no pallas executor was compiled")
-    require(all("tpu_custom_call" in t for t in texts),
-            "the pallas executor holds no tpu_custom_call")
-
-
-def pallas_vs_lax(plan, x0, **kw) -> dict:
-    out = {}
-    for backend in BACKENDS:
-        res, cold, warm = timed_execute(
-            plan, x0, ExecOptions(backend=backend), **kw)
-        log(f"  {backend}: execute cold {cold:.3f} s (compile included), "
-            f"warm {warm:.3f} s; messages {res.messages.tolist()}")
-        out[backend] = res
-    return out
-
-
-def phase_a(dev, n: int = 2000, eps: float = 1e-4, trials: int = 10) -> None:
-    """The paper's deployment: RGG(n) at the connectivity radius."""
-    log(f"phase A: RGG n={n}, eps={eps}, weighted, {trials} trials")
-    t0 = time.perf_counter()
-    g = random_geometric_graph(n, seed=100)
-    graph_s = time.perf_counter() - t0
-    require(g.is_connected(), "phase A graph is not connected")
-    t0 = time.perf_counter()
-    plan = build_plan(g, seed=0)
-    log(f"  set-up: graph {graph_s:.3f} s, plan "
-        f"{time.perf_counter() - t0:.3f} s")
-    log_levels(plan)
-    x0 = np.random.default_rng(0).normal(0.0, 1.0, n)
-    res = pallas_vs_lax(plan, x0, eps=eps, seeds=tuple(range(trials)),
-                        weighted=True)
-    bound = theorem2_bound(n, eps)
-    for backend, r in res.items():
-        err = r.error(x0)
-        log(f"  {backend}: error max {err.max():.3e} "
-            f"(Theorem 2 bound {bound:.3e})")
-        require(bool(np.all(err <= bound)),
-                f"phase A {backend}: error {err} above {bound}")
-    require_same(res["pallas"], res["lax"], "phase A pallas vs lax")
-    require_kernel(plan)
-    log(f"  peak_bytes_in_use {peak_bytes(dev)}")
-    log("phase A: pass")
-
-
 def large_plan(n: int):
     t0 = time.perf_counter()
     plan, info = setup_plan(n=n, graph_seed=1000 + n, seed=0,
@@ -180,39 +109,22 @@ def large_plan(n: int):
 LARGE_KW = dict(eps=1e-3, fixed_ticks_scale=0.2, weighted=True, seeds=(0,))
 
 
-def phase_b(dev, n: int = LARGE_N, max_err: float = 1e-3) -> None:
-    """The large-n configuration of record, fixed iterations."""
-    log(f"phase B: RGG n={n}, fixed iterations (scale 0.2), weighted")
-    plan, x0 = large_plan(n)
-    res = pallas_vs_lax(plan, x0, **LARGE_KW)
-    for backend, r in res.items():
-        err = float(r.error(x0)[0])
-        log(f"  {backend}: error {err:.3e} (limit {max_err:g}), "
-            f"level ticks {r.level_ticks[0].tolist()}")
-        require(err <= max_err, f"phase B {backend}: error {err}")
-    require_same(res["pallas"], res["lax"], "phase B pallas vs lax")
-    require_kernel(plan)
-    log(f"  peak_bytes_in_use {peak_bytes(dev)}")
-    log("phase B: pass")
-
-
 def phase_mesh(devs, n: int = LARGE_N) -> None:
-    """Phase B's plan on a (trials=1, nodes=4) mesh vs one chip."""
+    """The large-n plan on a (trials=1, nodes=4) mesh vs one chip."""
     from jax.sharding import Mesh
 
     log(f"phase mesh: n={n} on a (trials=1, nodes=4) mesh vs one chip")
     plan, x0 = large_plan(n)
     mesh = Mesh(np.array(devs[:4]).reshape(1, 4), ("trials", "nodes"))
     sharded, cold, warm = timed_execute(
-        plan, x0, ExecOptions(backend="pallas", mesh=mesh), **LARGE_KW)
+        plan, x0, ExecOptions(mesh=mesh), **LARGE_KW)
     log(f"  mesh: execute cold {cold:.3f} s, warm {warm:.3f} s")
     single, cold, warm = timed_execute(
-        plan, x0, ExecOptions(backend="pallas"), **LARGE_KW)
+        plan, x0, ExecOptions(), **LARGE_KW)
     log(f"  one chip: execute cold {cold:.3f} s, warm {warm:.3f} s")
     log(f"  error {float(sharded.error(x0)[0]):.3e}, "
         f"messages {sharded.messages.tolist()}")
     require_same(sharded, single, "mesh vs one chip")
-    require_kernel(plan)
     log("phase mesh: pass")
 
 
@@ -257,18 +169,14 @@ def phase_sync(devs, R: int = 4) -> None:
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run only the phases across four chips")
+    ap.add_argument("--chips", type=int, choices=(4,), default=4,
+                    help="chips to run across (four, the only mode)")
     args = ap.parse_args()
     devs = tpu_devices(args.chips)
     log(f"device: {devs[0].device_kind} x{len(devs)}; compile cache "
         f"{enable_compile_cache()}")
-    if args.chips == 4:
-        phase_mesh(devs)
-        phase_sync(devs)
-    else:
-        phase_a(devs[0])
-        phase_b(devs[0])
+    phase_mesh(devs)
+    phase_sync(devs)
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform,
         "kind": devs[0].device_kind,

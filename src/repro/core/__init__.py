@@ -16,7 +16,6 @@ from .engine import EngineResult, execute_plan
 from .events import event_totals
 from .failures import handshake_cost
 from .gossip import (
-    GOSSIP_BACKENDS,
     GossipResult,
     batched_graphs,
     gossip_core,
@@ -59,7 +58,6 @@ from .rgg import (
 from .schedule import (
     CsrGraphs,
     ExchangeSchedule,
-    compose_schedule,
     dense_to_csr,
     flat_usage_to_dense,
     sample_schedule,

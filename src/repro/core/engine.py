@@ -26,14 +26,11 @@ compiled call.  `mesh=` shards that computation over real hardware:
   between graphs exactly there) plus the final assembly — the gossip
   inner loops themselves run shard-local.
 
-Backends: ``backend="lax"`` is the reference inner kernel;
-``backend="pallas"`` walks each chunk's presampled schedule with the
-`kernels.pair_apply` TPU kernel, streaming cell state through VMEM in
-cell blocks (bitwise-identical to lax; off the TPU the kernel runs in
-the Pallas interpreter); ``backend="matmul"`` composes each chunk's
-mixing matrix with a log2 tree of batched MXU matmuls (values agree up
-to f32 rounding).  ``schedule="per_tick"`` keeps the legacy sequential scan as
-the parity reference (see `core.gossip`).
+Every level runs the one value pass of `core.gossip`: the chunk's
+presampled schedule walked by `core.value_pass.pair_apply`, whose
+endpoint read (select or gather) follows from the level's shape.  A
+choice of value pass would be a fork of the hot path for every change
+to prove against; the shape already decides what a caller could.
 """
 from __future__ import annotations
 
@@ -46,8 +43,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import value_pass
 from .events import record_duration, record_event
-from .gossip import GOSSIP_BACKENDS, gossip_core
+from .gossip import gossip_core
 from .medium import (
     CostModel,
     FailureCtx,
@@ -69,7 +67,7 @@ _span = jax.profiler.TraceAnnotation
 # CPU: these are small scatter/gather loops where full optimization buys
 # nothing measurable at runtime but more than doubles compile time.  The
 # LLVM expensive-pass cut matters most: the executor's scatter bodies
-# spend their compile budget in LLVM, not in HLO passes.  Other backends
+# spend their compile budget in LLVM, not in HLO passes.  Other platforms
 # compile with their defaults.
 _CPU_COMPILER_OPTS = {
     "xla_backend_optimization_level": 0,
@@ -114,7 +112,6 @@ class EngineResult:
     #                              level's CSR layout (collect_usage=True
     #                              only; LevelPlan.dense_usage restores the
     #                              historical (B, C, D) view)
-    backend: str
     cost: Optional[MediumCost] = None  # priced medium cost (CostModel runs)
 
     @property
@@ -276,8 +273,8 @@ def execute_plan(
     seed drives one trial's exchange randomness; the plan (partition,
     election, routes) is shared, so trials differ only in gossip noise.
 
-    `options` (an `ExecOptions`) selects backend / schedule / mesh /
-    check cadence / tick budget (the historical flat kwargs were
+    `options` (an `ExecOptions`) selects mesh / check cadence / tick
+    budget / usage read-out (the historical flat kwargs were
     removed after their deprecation window — a stale call now raises
     `TypeError`).  `failures` (a `FailureModel`) carries the paper's
     `loss_p` message-loss model plus the scenario fields (churn,
@@ -295,11 +292,10 @@ def execute_plan(
     mesh with axes named ``("trials", "nodes")`` also blocks every
     level's graph batch over the "nodes" axis, with psum halos only at
     promotion boundaries — per-trial results are bitwise-independent of
-    the sharding either way.  The node-sharded path requires
-    ``schedule="presampled"`` and supports neither `collect_usage`
-    (the flat usage buffer is deliberately never assembled globally)
-    nor `failures` scenarios / `cost` pricing (their reductions are
-    batch-global).
+    the sharding either way.  The node-sharded path supports neither
+    `collect_usage` (the flat usage buffer is deliberately never
+    assembled globally) nor `failures` scenarios / `cost` pricing (their
+    reductions are batch-global).
 
     `options.collect_usage` additionally returns the raw per-level flat
     exchange counters (for attribution audits); leave it off on the hot
@@ -316,10 +312,9 @@ def execute_plan(
     ``/repro/core/executor_lower`` and ``/repro/core/executor_compile``,
     and per level the events ``/repro/core/schedule_lookup``, with how
     its schedule reads partners and hops (`CsrGraphs.lookup`),
-    on the lax backend's presampled value pass
-    ``/repro/core/value_pass_lookup``, with how `pair_apply_ref` reads
-    a tick's endpoints in one node block (``path`` select or gather,
-    `kernels.pair_apply.ref.value_read_path`),
+    ``/repro/core/value_pass_lookup``, with how `pair_apply` reads a
+    tick's endpoints in one node block (``path`` select or gather,
+    `core.value_pass.value_read_path`),
     ``/repro/core/executor_consts``, with the ``bytes`` of the level's
     plan arrays baked into the executor as constants, and in
     fixed-iterations mode ``/repro/core/fixed_ticks``, with the level's
@@ -337,15 +332,14 @@ def execute_plan(
                 failures=failures, cost=cost)
             out = fn(*args)
         with _span("repro.execute_plan.readback"):
-            return _readback(plan, out, len(seeds), options.backend, cost)
+            return _readback(plan, out, len(seeds), cost)
 
 
 def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
               options, failures, cost):
     """The compiled executor for this call (from `plan.exec_cache`, or
     lowered and compiled on a miss) and its device arguments."""
-    backend, schedule, mesh = options.backend, options.schedule, options.mesh
-    interpret, collect_usage = options.interpret, options.collect_usage
+    mesh, collect_usage = options.mesh, options.collect_usage
     check_every = options.check_every
     max_ticks_per_level = options.max_ticks_per_level
     if failures is not None and failures.heterogeneous:
@@ -360,11 +354,6 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
             "price_edge_messages")
     loss_p = failures.loss_p if failures is not None else None
     scenario = failures is not None and failures.has_scenario
-    if backend not in GOSSIP_BACKENDS:
-        raise ValueError(f"unknown backend {backend!r}")
-    if (scenario or cost is not None) and schedule != "presampled":
-        raise ValueError(
-            "failure scenarios / cost pricing require schedule='presampled'")
     if scenario and fixed_ticks_scale <= 0:
         raise ValueError(
             "failure scenarios require fixed_ticks_scale > 0: scenario "
@@ -372,8 +361,6 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
             "which the eps-oracle mode leaves unbounded")
     platform = (mesh.devices.flat[0].platform if mesh is not None
                 else jax.default_backend())
-    if interpret is None:
-        interpret = platform != "tpu"
     n = plan.graph.n
     x0 = np.asarray(x0, np.float32)
     T = len(seeds)
@@ -386,10 +373,6 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
             "trials", "nodes",
         ):
             node_mesh = True
-            if schedule != "presampled":
-                raise ValueError(
-                    "the (trials, nodes) mesh requires schedule='presampled'"
-                )
             if collect_usage:
                 raise ValueError(
                     "collect_usage is not supported on the (trials, nodes) "
@@ -490,7 +473,6 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
                     xb_loc, c["adj"], mask,
                     eps_l, level_key,
                     max_ticks=maxt_l, check_every=chk, loss_p=loss_p,
-                    backend=backend, schedule=schedule, interpret=interpret,
                     node_shard=shard,
                     failure_ctx=fail_ctxs[li] if scenario else None,
                     cost_model=cost, hop_cap=max(1, int(lp.max_hops)),
@@ -610,8 +592,8 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
         jnp.asarray(maxt_levels, jnp.int32),
     )
     cache_key = (
-        platform, T, per_trial_x0, weighted, failures, cost, backend,
-        schedule, mesh, interpret, tuple(chk_levels), collect_usage,
+        platform, T, per_trial_x0, weighted, failures, cost, mesh,
+        tuple(chk_levels), collect_usage,
         # scenario event ticks are baked into the trace as constants
         # derived from maxt_levels (see _failure_consts), so executors
         # traced for different tick budgets must not collide
@@ -641,12 +623,9 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
         for li, c in enumerate(consts):
             record_event("/repro/core/schedule_lookup", level=li,
                          **c["adj"].lookup)
-            if backend == "lax" and schedule == "presampled":
-                from repro.kernels.pair_apply.ref import value_read_path
-
-                B, C = plan.levels[li].node_mask.shape
-                record_event("/repro/core/value_pass_lookup", level=li,
-                             path=value_read_path(-(-B // nd), C))
+            B, C = plan.levels[li].node_mask.shape
+            record_event("/repro/core/value_pass_lookup", level=li,
+                         path=value_pass.value_read_path(-(-B // nd), C))
             record_event("/repro/core/executor_consts", level=li,
                          bytes=sum(a.nbytes for a in jax.tree.leaves(c)))
             if fixed_ticks_scale > 0:
@@ -660,7 +639,7 @@ def _over_trials(run, T, per_trial_x0, mesh, node_mesh, num_levels):
     """`run` (one trial) over the call's T trials: vmapped, and
     shard_mapped over `mesh` when there is one."""
     if T == 1 and mesh is None:
-        # single-trial fast path: the batching interpreter roughly
+        # single-trial fast path: vmap's batching trace roughly
         # doubles trace time and XLA pays for size-1 batch dims on
         # every op — run the trial unbatched and re-add the trial
         # axis on the way out (per-trial results are independent of
@@ -695,7 +674,7 @@ def _over_trials(run, T, per_trial_x0, mesh, node_mesh, num_levels):
     )
 
 
-def _readback(plan, out, T, backend, cost) -> EngineResult:
+def _readback(plan, out, T, cost) -> EngineResult:
     """The executor's device outputs as host arrays: padding trials
     dropped, per-graph int32 counters reduced in int64, cost priced."""
     n = plan.graph.n
@@ -725,7 +704,6 @@ def _readback(plan, out, T, backend, cost) -> EngineResult:
         level_ticks=np.asarray(lt, np.int64),
         level_converged=np.asarray(lc, np.float64),
         edge_usage=[np.asarray(u) for u in usages],
-        backend=backend,
         cost=_price_levels(
             cost, plan, n, level_messages, messages, lretx, lcong),
     )

@@ -19,34 +19,15 @@ transmission of an exchange succeeds w.p. `loss_p`; a lost request
 aborts the exchange, a lost reply leaves only the contacted node
 updated (mass distortion — exactly the failure the paper analyzes).
 
-Schedule / value split (`schedule="presampled"`, the default): every
-exchange decision depends only on ``(key, t)``, never on the values, so
-each `check_every` chunk first presamples its full ``(T, B)`` exchange
-schedule in one batched RNG pass (`core.schedule.sample_schedule` —
-usage and message accounting become one scatter-add / one reduction
-over the presampled arrays), then applies the pair list with the chosen
-value backend:
-
-* ``backend="lax"`` — `kernels.pair_apply.pair_apply_ref`: a scan whose
-  body is just two endpoint reads (one-hot selects over a cell's slots,
-  or gathers, by the level's shape), one average, and two conditional
-  writes (the legacy tick with all sampling hoisted out);
-* ``backend="pallas"`` — the `kernels.pair_apply` TPU kernel walks the
-  schedule with cell state streamed through VMEM in blocks (no HBM
-  round-trips within a block); its f32 op sequence matches the oracle
-  exactly, so results are bitwise-identical to the lax backend (off the
-  TPU it runs in the Pallas interpreter, ``interpret=True``);
-* ``backend="matmul"`` — `core.schedule.compose_schedule` folds the
-  chunk's elementary pair-average matrices with a log2(T) tree of
-  batched matmuls and applies the result via `kernels.cell_mixing`
-  (MXU work; values agree up to f32 rounding because matrix
-  composition reassociates the sums — integer accounting is still
-  exact).
-
-``schedule="per_tick"`` keeps the legacy sequential scan (sampling
-interleaved with value updates) as the bitwise-parity reference path;
-it supports the lax backend and the historical pallas
-eye-rebuild-then-scan branch.
+Schedule / value split: every exchange decision depends only on
+``(key, t)``, never on the values, so each `check_every` chunk first
+presamples its full ``(T, B)`` exchange schedule in one batched RNG pass
+(`core.schedule.sample_schedule` — usage and message accounting become
+one scatter-add / one reduction over the presampled arrays), then
+applies the pair list with `core.value_pass.pair_apply`: a scan whose
+body is just two endpoint reads (one-hot selects over a cell's slots,
+or gathers, by the level's shape), one average, and two conditional
+writes.  Only the values are sequential, so only they pay for a scan.
 
 Adjacency is CSR-addressed (`core.schedule.CsrGraphs`): one flat entry
 per directed edge instead of ``(B, C, D)`` dense padding, with usage
@@ -66,7 +47,7 @@ while-loop trip counts without affecting any output.
 
 Layer scopes: every op `gossip_core` emits sits under one of the
 `jax.named_scope`s in `LAYER_SCOPES` — ``schedule`` (sampling and its
-perturbation), ``value_pass`` (the pair-average backend),
+perturbation), ``value_pass`` (the pair walk),
 ``accounting`` (usage, messages, ticks, cost counters) and
 ``convergence_check`` (the tolerance, the per-chunk ``err <= tol`` and
 the chunk loop's own control); `core.engine` adds ``promote`` for the
@@ -95,17 +76,15 @@ import numpy as np
 from .medium import _TAG_RETX, _TAG_STRAGGLER, CostModel, FailureCtx
 from .schedule import (
     CsrGraphs,
-    compose_schedule,
     dense_to_csr,
     flat_usage_to_dense,
     sample_schedule,
-    sample_tick,
 )
+from .value_pass import pair_apply
 
 __all__ = ["GossipResult", "gossip_core", "gossip_until", "batched_graphs",
-           "GOSSIP_BACKENDS", "LAYER_SCOPES"]
+           "LAYER_SCOPES"]
 
-GOSSIP_BACKENDS = ("lax", "pallas", "matmul")
 LAYER_SCOPES = ("schedule", "value_pass", "accounting", "convergence_check",
                 "promote")
 
@@ -132,30 +111,6 @@ class GossipResult:
         return self.x[..., 0] / np.maximum(self.x[..., 1], 1e-30)
 
 
-def _one_tick(state, t, adj, key, loss_p):
-    """Legacy tick: sample-and-apply interleaved (the parity reference).
-    Sampling is shared with the presampled path (`schedule.sample_tick`)
-    so the two stay draw-for-draw identical by construction."""
-    x, usage, msgs, done = state
-    B = adj.n_nodes.shape[0]
-    with _scope("schedule"):
-        s = sample_tick(t, key, adj, loss_p, x.dtype)
-        active = (~done) & s.valid
-    with _scope("value_pass"):
-        bidx = jnp.arange(B)
-        xi = x[bidx, s.i]
-        xj = x[bidx, s.j]
-        avg = 0.5 * (xi + xj)
-        upd_j = (active & s.fwd_ok)[:, None]  # j updates iff request arrived
-        upd_i = (active & s.fwd_ok & s.rep_ok)[:, None]  # i iff reply arrived
-        x = x.at[bidx, s.j].set(jnp.where(upd_j, avg, xj))
-        x = x.at[bidx, s.i].set(jnp.where(upd_i, avg, xi))
-    with _scope("accounting"):
-        usage = usage.at[s.pos].add(active.astype(jnp.int32))
-        msgs = msgs + jnp.where(active, s.cost, 0)
-    return (x, usage, msgs, done), None
-
-
 def gossip_core(
     x0,
     adj: CsrGraphs,
@@ -166,9 +121,6 @@ def gossip_core(
     max_ticks: int,
     check_every: int,
     loss_p: Optional[float],
-    backend: str = "lax",
-    schedule: str = "presampled",
-    interpret: bool = False,
     node_shard=None,
     failure_ctx: Optional[FailureCtx] = None,
     cost_model: Optional[CostModel] = None,
@@ -182,21 +134,16 @@ def gossip_core(
     (retransmissions, congestion_pairs) — priced from the presampled
     schedule with RNG streams disjoint from the exchange streams, so
     x/usage/msgs/done/ticks are bitwise-independent of the cost model.
-    `backend` selects the inner pairwise-average kernel and `schedule`
-    the presampled vs legacy per-tick execution (see module docstring);
-    the random exchange sequence, usage, and message counts are
-    backend- and schedule-independent.  `eps` and `max_ticks` may be
-    traced scalars (the plan/execute engine passes them at runtime so
-    eps-oracle and fixed-iteration runs share one compilation);
-    `check_every` must be static (scan length).
+    `eps` and `max_ticks` may be traced scalars (the plan/execute engine
+    passes them at runtime so eps-oracle and fixed-iteration runs share
+    one compilation); `check_every` must be static (scan length).
 
     `failure_ctx` (a `medium.FailureCtx`) perturbs the presampled
     schedule — churned/regional nodes' exchanges vanish (a live
     initiator contacting a down partner wastes the forward leg),
     straggler exchanges fail w.p. 1 - straggler_success at full cost,
     Byzantine slots never apply updates.  This DOES change trajectory
-    and accounting (that is the point); requires
-    ``schedule="presampled"``.
+    and accounting (that is the point).
 
     `node_shard=(cols, ok)` runs only the given global batch columns:
     `x0`/`node_mask` are the local ``(Bs, C, …)`` slices, sampling stays
@@ -204,23 +151,11 @@ def gossip_core(
     are local while usage stays global-flat (adds land only at the
     shard's own edges).
     """
-    if backend not in GOSSIP_BACKENDS:
-        raise ValueError(f"unknown backend {backend!r}")
-    if schedule not in ("presampled", "per_tick"):
-        raise ValueError(f"unknown schedule mode {schedule!r}")
-    if schedule == "per_tick" and backend == "matmul":
-        raise ValueError("backend='matmul' requires schedule='presampled'")
-    if node_shard is not None and schedule != "presampled":
-        raise ValueError("node_shard requires schedule='presampled'")
-    if (failure_ctx is not None or cost_model is not None):
-        if schedule != "presampled":
-            raise ValueError(
-                "failure scenarios / cost pricing require "
-                "schedule='presampled'")
-        if node_shard is not None:
-            raise ValueError(
-                "failure scenarios / cost pricing are not supported on "
-                "the (trials, nodes) mesh")
+    if node_shard is not None and (
+            failure_ctx is not None or cost_model is not None):
+        raise ValueError(
+            "failure scenarios / cost pricing are not supported on "
+            "the (trials, nodes) mesh")
     with _scope("convergence_check"):
         # the barrier keeps the float mask one value: with a plan's mask
         # a constant, the TPU compiler otherwise folded it into a float
@@ -237,15 +172,10 @@ def gossip_core(
         d = (x - mean[:, None, :]) * live
         return jnp.sqrt((d**2).sum((1, 2)))
 
-    if schedule == "per_tick":
-        chunk = _per_tick_chunk(
-            adj, key, loss_p, check_every, backend, interpret, err, tol,
-        )
-    else:
-        chunk = _presampled_chunk(
-            adj, key, loss_p, check_every, backend, interpret, err, tol,
-            node_shard, failure_ctx, cost_model, hop_cap,
-        )
+    chunk = _presampled_chunk(
+        adj, key, loss_p, check_every, err, tol,
+        node_shard, failure_ctx, cost_model, hop_cap,
+    )
 
     def cond(carry):
         with _scope("convergence_check"):
@@ -271,9 +201,9 @@ def gossip_core(
     return out[:-1]  # drop the tick counter t0
 
 
-def _presampled_chunk(adj, key, loss_p, check_every, backend, interpret,
-                      err, tol, node_shard=None, failure_ctx=None,
-                      cost_model=None, hop_cap=1):
+def _presampled_chunk(adj, key, loss_p, check_every, err, tol,
+                      node_shard=None, failure_ctx=None, cost_model=None,
+                      hop_cap=1):
     """Chunk body for the schedule/value split: one batched RNG pass for
     the whole chunk, accounting as a single scatter-add + reduction,
     then the value pass over the presampled pair list.
@@ -284,8 +214,6 @@ def _presampled_chunk(adj, key, loss_p, check_every, backend, interpret,
     folded from tags disjoint from every tick index, so the exchange
     draws — and therefore x/usage/msgs — are untouched.
     """
-    from repro.kernels.pair_apply import pair_apply, pair_apply_ref
-
     cost_on = cost_model is not None
     sample_retx = (cost_on and cost_model.sample
                    and cost_model.retransmit_p < 1.0)
@@ -296,7 +224,6 @@ def _presampled_chunk(adj, key, loss_p, check_every, backend, interpret,
             x, usage, msgs, done, ticks, retx, congp, t0 = carry
         else:
             x, usage, msgs, done, ticks, t0 = carry
-        C = x.shape[1]
         with _scope("schedule"):
             ts = t0 + jnp.arange(check_every)
             s = sample_schedule(ts, key, adj, loss_p, x.dtype)
@@ -357,15 +284,7 @@ def _presampled_chunk(adj, key, loss_p, check_every, backend, interpret,
                     attempt * jnp.maximum(conc - 1, 0)[:, None]
                 ).sum(0).astype(jnp.float32)
         with _scope("value_pass"):
-            if backend == "lax":
-                x = pair_apply_ref(x, s.i, s.j, upd_i, upd_j)
-            elif backend == "pallas":
-                x = pair_apply(x, s.i, s.j, upd_i, upd_j, interpret=interpret)
-            else:  # matmul: associative composition, applied on the MXU
-                from repro.kernels.cell_mixing import cell_mixing
-
-                m = compose_schedule(C, s.i, s.j, upd_i, upd_j, x.dtype)
-                x = cell_mixing(m, x, rounds=1, interpret=interpret)
+            x = pair_apply(x, s.i, s.j, upd_i, upd_j)
         with _scope("accounting"):
             ticks = ticks + jnp.where(done, 0, check_every)
         with _scope("convergence_check"):
@@ -379,55 +298,9 @@ def _presampled_chunk(adj, key, loss_p, check_every, backend, interpret,
     return chunk
 
 
-def _per_tick_chunk(adj, key, loss_p, check_every, backend, interpret,
-                    err, tol):
-    """Legacy chunk body: the sequential sample-and-apply scan."""
-    C, B = adj.degrees.shape
-
-    def tick(s, t):
-        return _one_tick(s, t, adj, key, loss_p)
-
-    # historical pallas branch: the chunk's pair averages accumulate into
-    # a mixing matrix (identity + row averages — _one_tick applied to
-    # rows of I) applied with the Pallas batched matmul kernel.  The
-    # identity seed is built once here, not per while-loop iteration.
-    eye = None
-    if backend == "pallas":
-        with _scope("value_pass"):
-            eye = jnp.broadcast_to(jnp.eye(C, dtype=jnp.float32), (B, C, C))
-
-    def chunk(carry):
-        x, usage, msgs, done, ticks, t0 = carry
-        with _scope("schedule"):
-            ts = t0 + jnp.arange(check_every)
-        # the tick scan interleaves the three layers; its body scopes
-        # each of them (`_one_tick`), the loop itself is the value pass
-        with _scope("value_pass"):
-            if backend == "lax":
-                (x, usage, msgs, done), _ = jax.lax.scan(
-                    tick, (x, usage, msgs, done), ts
-                )
-            else:
-                from repro.kernels.cell_mixing import cell_mixing
-
-                (m, usage, msgs, done), _ = jax.lax.scan(
-                    tick, (eye.astype(x.dtype), usage, msgs, done), ts
-                )
-                x = cell_mixing(m, x, rounds=1, interpret=interpret)
-        with _scope("accounting"):
-            ticks = ticks + jnp.where(done, 0, check_every)
-        with _scope("convergence_check"):
-            done = done | (err(x) <= tol)
-            t1 = t0 + check_every
-        return (x, usage, msgs, done, ticks, t1)
-
-    return chunk
-
-
 @partial(
     jax.jit,
-    static_argnames=("max_ticks", "check_every", "loss_p", "backend",
-                     "schedule", "interpret"),
+    static_argnames=("max_ticks", "check_every", "loss_p"),
 )
 def _gossip_loop(
     x0,
@@ -438,14 +311,10 @@ def _gossip_loop(
     max_ticks: int,
     check_every: int,
     loss_p: Optional[float],
-    backend: str = "lax",
-    schedule: str = "presampled",
-    interpret: bool = False,
 ):
     return gossip_core(
         x0, adj, node_mask, eps, key,
         max_ticks=max_ticks, check_every=check_every, loss_p=loss_p,
-        backend=backend, schedule=schedule, interpret=interpret,
     )
 
 
@@ -463,9 +332,6 @@ def gossip_until(
     check_every: int = 64,
     fixed_ticks: Optional[int] = None,
     loss_p: Optional[float] = None,
-    backend: str = "lax",
-    schedule: str = "presampled",
-    interpret: bool = False,
 ) -> GossipResult:
     """Run batched randomized gossip to eps-accuracy (or `fixed_ticks`).
 
@@ -474,8 +340,6 @@ def gossip_until(
     convergence oracle.  Convergence is re-checked every `check_every`
     ticks, so up to that many extra exchanges can occur after the true
     crossing (convergence detection is not free in reality either).
-    `backend`/`schedule`/`interpret` select the inner pairwise-average
-    kernel and execution mode (see module docstring).
 
     The host API stays dense — ``(B, C, D)`` padded neighbors in, dense
     `edge_usage` out; the CSR packing is internal.
@@ -505,9 +369,6 @@ def gossip_until(
         max_ticks=max_t,
         check_every=check,
         loss_p=loss_p,
-        backend=backend,
-        schedule=schedule,
-        interpret=interpret,
     )
     return GossipResult(
         x=np.asarray(x),

@@ -100,7 +100,6 @@ class MultiscaleTrials:
     rep_counts: np.ndarray    # (n,) — shared: election is part of the plan
     disconnected_cells: int
     partition: Partition
-    backend: str
     cost: Optional[MediumCost] = None  # per-trial priced cost (CostModel runs)
 
     @property
@@ -160,12 +159,12 @@ def multiscale_gossip(
     `cell_max`, `rep_mode` are taken from the plan and `seed` only
     drives the gossip randomness).
 
-    `options` (`ExecOptions`) selects backend / schedule / mesh / check
-    cadence / tick budget; `failures` (`FailureModel`) carries the
+    `options` (`ExecOptions`) selects mesh / check cadence / tick
+    budget / usage read-out; `failures` (`FailureModel`) carries the
     paper's loss model plus churn / straggler / regional / Byzantine
     scenarios; `cost` (`CostModel`) prices the run onto the wireless
     medium into `.cost` without perturbing the exchange trajectory.
-    The historical flat kwargs (``backend=``, ``loss_p=``, ...) were
+    The historical flat kwargs (``mesh=``, ``loss_p=``, ...) were
     removed after their deprecation window — a stale call now raises
     `TypeError`.
     """
@@ -203,6 +202,5 @@ def multiscale_gossip(
         rep_counts=plan.rep_counts.copy(),
         disconnected_cells=plan.disconnected_cells,
         partition=plan.partition,
-        backend=options.backend,
         cost=res.cost,
     )

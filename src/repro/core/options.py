@@ -1,18 +1,19 @@
 """Execution options for the plan/execute simulation core.
 
 `ExecOptions` is the single static (hashable) config surface for HOW a
-plan is executed — backend, schedule mode, sharding mesh, convergence
-check cadence, tick budget — mirroring the dist layer's `SyncConfig` →
+plan is executed — sharding mesh, convergence check cadence, tick
+budget, usage read-out — mirroring the dist layer's `SyncConfig` →
 `SyncPlan` pattern.  WHAT is simulated stays in positional/semantic
 arguments (`eps`, `seeds`, `weighted`, `fixed_ticks_scale`) and the two
 sibling dataclasses `FailureModel` / `CostModel` (`core.medium`).
 
-The historical flat kwargs (``backend=``, ``schedule=``, ``mesh=``,
-``interpret=``, ``check_every=``, ``max_ticks_per_level=``,
-``collect_usage=``, ``loss_p=``) have been REMOVED after their
-one-release deprecation window: `execute_plan` / `multiscale_gossip`
-now take `options=ExecOptions(...)` and `failures=FailureModel(...)`
-only, and a stale flat-kwarg call fails loudly with `TypeError`.
+The value pass is not an option: the executor has one, the presampled
+schedule walked by `core.value_pass.pair_apply`, which picks its own
+endpoint read from each level's shape, so there is nothing left for a
+caller to choose.  An unknown keyword fails with `TypeError`, as do the
+flat execute kwargs (``mesh=``, ``loss_p=``, ...): `execute_plan` /
+`multiscale_gossip` take `options=ExecOptions(...)` and
+`failures=FailureModel(...)` only.
 """
 from __future__ import annotations
 
@@ -21,45 +22,24 @@ from typing import Any, Optional
 
 __all__ = ["ExecOptions"]
 
-_ENGINE_BACKENDS = ("lax", "pallas", "matmul")
-_SCHEDULES = ("presampled", "per_tick")
-
 
 @dataclasses.dataclass(frozen=True)
 class ExecOptions:
     """Static (hashable) description of how to execute a plan.
 
-    backend: inner pairwise-average kernel — "lax" (reference scan),
-        "pallas" (TPU pair_apply kernel), "matmul" (log2(T) MXU
-        composition).
-    schedule: "presampled" (schedule/value split, the default) or
-        "per_tick" (legacy sequential scan, the parity reference).
     mesh: optional `jax.sharding.Mesh` — 1-axis shards the trial axis;
         a 2-axis ``("trials", "nodes")`` mesh also blocks node batches.
-    interpret: run Pallas kernels in interpret mode; None = auto
-        (interpret off only on real TPUs).
     check_every: convergence-oracle cadence (static scan length).
     max_ticks_per_level: per-level tick budget in eps-oracle mode.
     collect_usage: also return the raw per-level flat exchange
         counters (attribution audits; off on the hot path).
     """
 
-    backend: str = "lax"
-    schedule: str = "presampled"
     mesh: Optional[Any] = None
-    interpret: Optional[bool] = None
     check_every: int = 64
     max_ticks_per_level: int = 2_000_000
     collect_usage: bool = False
 
     def __post_init__(self):
-        if self.backend not in _ENGINE_BACKENDS:
-            raise ValueError(
-                f"unknown backend {self.backend!r}; "
-                f"expected one of {_ENGINE_BACKENDS}")
-        if self.schedule not in _SCHEDULES:
-            raise ValueError(
-                f"unknown schedule mode {self.schedule!r}; "
-                f"expected one of {_SCHEDULES}")
         if self.check_every < 1:
             raise ValueError("check_every must be >= 1")

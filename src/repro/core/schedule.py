@@ -8,22 +8,15 @@ sequential.  (Boyd et al. [2] and the paper's §VII fixed-iterations
 analysis both treat the exchange sequence as an i.i.d. schedule for
 exactly this reason.)  This module exploits that split:
 
-* `sample_tick` is the sampling half of one legacy gossip tick — the
-  exact draws, in the exact order, of the historical per-tick scan body,
-  so its schedule is bitwise-reproducible against the legacy path;
+* `sample_tick` is the sampling half of one gossip tick — the draws
+  of one tick, in order, so a per-tick scan that samples as it goes
+  (the tests' oracle) sees the same exchanges bit for bit;
 * `sample_schedule` vmaps it over a whole `check_every` chunk of tick
   indices: one batched RNG pass produces the full ``(T, B)`` schedule
   (waking node, neighbor slot, partner, per-hop loss outcomes, hop
   cost) at once.  `jax.vmap` does not change threefry's per-key
   streams, so the presampled schedule is bit-identical to T sequential
-  `sample_tick` calls;
-* `compose_schedule` turns a presampled pair list into the chunk's
-  ``(B, C, C)`` mixing matrix with a log2(T) tree of batched matmuls
-  (MXU-friendly), replacing the historical eye-rebuild-then-scan.
-  Matrix composition reassociates the f32 sums, so values produced
-  through it agree with the sequential recursion only up to f32
-  rounding — integer accounting (usage, cost) is schedule-only and
-  stays exact.
+  `sample_tick` calls.
 
 Adjacency is CSR-addressed (`CsrGraphs`): the ``(B, C, D)`` dense
 padded arrays of the historical path wasted O(B*C*D) memory on the
@@ -36,8 +29,7 @@ are compare-and-selects over the drawn graph's slots, not gathers, but
 for the partner of a level with wide rows (see `CsrGraphs`).
 
 The value half — applying the presampled pair list to ``(B, C, V)``
-cell state — lives in `repro.kernels.pair_apply` (jnp oracle + Pallas
-TPU kernel that streams the schedule through SMEM in cell blocks).
+cell state — lives in `core.value_pass`.
 """
 from __future__ import annotations
 
@@ -56,7 +48,6 @@ __all__ = [
     "flat_usage_to_dense",
     "sample_tick",
     "sample_schedule",
-    "compose_schedule",
 ]
 
 
@@ -245,11 +236,10 @@ def sample_tick(
 ) -> ExchangeSchedule:
     """Draw one tick's exchange decisions for all B graphs.
 
-    This is the sampling half of the legacy per-tick scan body — its
-    draws and RNG consumption order are kept identical so the presampled
-    and per-tick paths are bitwise-interchangeable; each draw's degree,
-    row start, partner and hops are read within its own graph (see
-    `CsrGraphs`).  Draws are over the
+    This is the sampling half of one tick: a per-tick scan that calls
+    it as it goes draws exactly the presampled schedule, bit for bit;
+    each draw's degree, row start, partner and hops are read within its
+    own graph (see `CsrGraphs`).  Draws are over the
     global batch: a node-sharded caller samples the full ``(B,)``
     schedule and slices its columns, which keeps every shard's draws
     bit-identical to the unsharded run (threefry streams have no prefix
@@ -319,39 +309,3 @@ def sample_schedule(
         return sample_tick(t, key, adj, loss_p, dtype)
 
     return jax.vmap(one)(ts)
-
-
-def compose_schedule(num_slots: int, i, j, upd_i, upd_j, dtype=jnp.float32):
-    """Compose a presampled pair list into one (B, C, C) mixing matrix.
-
-    Tick t's elementary matrix E_t is the identity with rows i_t / j_t
-    replaced by the pair average 0.5 (e_i + e_j) where the respective
-    update fires (the same conditional row updates the per-tick scan
-    applies to x).  The chunk matrix E_T @ … @ E_1 is folded with a
-    log2(T) tree of batched matmuls — each round one (T/2, B, C, C)
-    batched GEMM, MXU work instead of T sequential row scatters.
-
-    Memory: materializes (T, B, C, C); intended for the small per-cell
-    matrices of the simulation hierarchy (C up to a few dozen).
-    """
-    T, B = i.shape
-    C = num_slots
-    eye = jnp.eye(C, dtype=dtype)
-    e_i = eye[i]                       # (T, B, C) one-hot rows
-    e_j = eye[j]
-    avg = 0.5 * (e_i + e_j)
-    rows_i = jnp.where(upd_i[..., None], avg, e_i)
-    rows_j = jnp.where(upd_j[..., None], avg, e_j)
-    tidx = jnp.arange(T)[:, None]
-    bidx = jnp.arange(B)[None, :]
-    E = jnp.broadcast_to(eye, (T, B, C, C))
-    # same write order as the scan: partner row, then initiator row
-    E = E.at[tidx, bidx, j].set(rows_j)
-    E = E.at[tidx, bidx, i].set(rows_i)
-    P = 1 << max(T - 1, 0).bit_length()
-    if P != T:
-        E = jnp.concatenate([E, jnp.broadcast_to(eye, (P - T, B, C, C))], 0)
-    while E.shape[0] > 1:
-        # fold adjacent pairs: later-tick matrix multiplies from the left
-        E = jnp.einsum("tbij,tbjk->tbik", E[1::2], E[0::2])
-    return E[0]

@@ -1,37 +1,39 @@
-"""Pure-jnp oracle for the pair-apply kernel: the sequential
-pair-average recursion over a presampled exchange schedule.
+"""The value pass: a chunk's presampled pair list applied to cell state.
 
-This is the value half of the legacy per-tick gossip scan with the
-sampling stripped out — the same endpoint values, same 0.5 * (xi + xj),
-same conditional writes in the same order — so it is bitwise-identical
-to the historical path and serves as both the lax-backend hot loop and
-the Pallas kernel's parity oracle.
+The schedule decides every exchange from ``(key, t)`` alone, so the
+values are the only sequential part of gossip: tick by tick, read the
+two endpoints, average them, and write the partner row, then the
+initiator row, where the exchange updates them.  `pair_apply` is that
+recursion as one `lax.scan` over the chunk's ticks, with nothing else
+in its body.
+
+The endpoints x[i], x[j] are read by a one-hot select over a cell's
+slots where there are at least VALUE_SELECT_MIN_B cells and a cell has
+at most VALUE_SELECT_MAX_C slots; elsewhere by XLA gathers.  A gather's
+output puts V on the TPU's lanes and drags the loop carry out of its
+cells-on-lanes layout; a select reads all C slots of an endpoint but
+keeps the cells on the lanes.  On a TPU v5e (64-tick calls, V=2) the
+select won at every B >= 16 and C <= 100 swept: about even at B=16,
+1.2-2x at B of 64-256, 3-40x from B=2,500 on (18 ms against 341 ms at
+337,504 cells of 13).  It tied at B=4 and lost at B=1 from C=8 on (5x
+slower at C=100).  On the CPU a select costs C reads an endpoint, so
+cells wider than the sweep keep the gather.  Both reads give the same
+bits.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["pair_apply_ref", "value_read_path"]
+__all__ = ["pair_apply", "value_read_path"]
 
 
-# The endpoints x[i], x[j] are read by a one-hot select over a cell's
-# slots where there are at least VALUE_SELECT_MIN_B cells and a cell
-# has at most VALUE_SELECT_MAX_C slots; elsewhere by XLA gathers.  A
-# gather's output puts V on the TPU's lanes and drags the loop carry out
-# of its cells-on-lanes layout; a select reads all C slots of an
-# endpoint but keeps the cells on the lanes.  On a TPU v5e (64-tick
-# calls, V=2) the select won at every B >= 16 and C <= 100 swept: about
-# even at B=16, 1.2-2x at B of 64-256, 3-40x from B=2,500 on (18 ms
-# against 341 ms at 337,504 cells of 13).  It tied at B=4 and lost at
-# B=1 from C=8 on (5x slower at C=100).  On the CPU a select costs C
-# reads an endpoint, so cells wider than the sweep keep the gather.
 VALUE_SELECT_MIN_B = 16
 VALUE_SELECT_MAX_C = 100
 
 
 def value_read_path(B: int, C: int) -> str:
-    """How `pair_apply_ref` reads the endpoints of B cells of C slots:
+    """How `pair_apply` reads the endpoints of B cells of C slots:
     ``"select"`` or ``"gather"``."""
     if B >= VALUE_SELECT_MIN_B and C <= VALUE_SELECT_MAX_C:
         return "select"
@@ -49,7 +51,7 @@ def _select_slot(x, k):
     return out
 
 
-def pair_apply_ref(x, i, j, upd_i, upd_j):
+def pair_apply(x, i, j, upd_i, upd_j):
     """Apply a presampled pair list to batched cell state.
 
     Args:
@@ -79,7 +81,7 @@ def pair_apply_ref(x, i, j, upd_i, upd_j):
         # value is the identical float either way (no arithmetic on the
         # pass-through lanes), but XLA compiles a select orders of
         # magnitude faster than a scatter and vectorizes it better on
-        # CPU.  Partner row first, then initiator (the legacy order).
+        # CPU.  Partner row first, then initiator.
         oh_j = (slots == jt[:, None]) & uj[:, None]
         oh_i = (slots == it[:, None]) & ui[:, None]
         x = jnp.where(oh_j[..., None], avg[:, None, :], x)

@@ -33,7 +33,6 @@ from typing import Optional
 import numpy as np
 
 from repro.core import (
-    ExecOptions,
     build_plan,
     execute_plan,
     random_geometric_graph,
@@ -69,7 +68,7 @@ class ControlPlane:
 
     def __init__(self, R: int, *, full_view: bool = True, seed: int = 0,
                  eps: float = 1e-4, bytes_per_value: int = 4,
-                 fixed_ticks_scale: float = 1.0, backend: str = "lax"):
+                 fixed_ticks_scale: float = 1.0):
         if R < 2:
             raise ValueError(f"control plane needs >= 2 replicas, got {R}")
         self.R = R
@@ -83,7 +82,6 @@ class ControlPlane:
             # exchange byte accounting would be wrong
             raise ValueError("control plane requires fixed_ticks_scale > 0")
         self.fixed_ticks_scale = float(fixed_ticks_scale)
-        self.backend = backend
         self.levels = suggest_levels(R)
 
         # replica deployment: a connected RGG over the unit square
@@ -149,7 +147,6 @@ class ControlPlane:
         res = execute_plan(
             self.plan, x0, eps=self.eps, seeds=[seed] * T,
             fixed_ticks_scale=self.fixed_ticks_scale, weighted=True,
-            options=ExecOptions(backend=self.backend),
         )
         messages = int(res.messages[0])
         assert int(res.messages.min()) == int(res.messages.max()), (

@@ -30,8 +30,6 @@ settings.load_profile("ci")
 SLOW_FILES = {"test_dist_multidevice.py"}
 SLOW_TESTS = {
     "test_trials_vmap_matches_sequential",
-    "test_pallas_backend_matches_lax",
-    "test_engine_matmul_backend",
     "test_engine_single_device_mesh_matches_unsharded",
     "test_plan_methods_execute_identically",
 }

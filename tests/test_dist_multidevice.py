@@ -357,14 +357,8 @@ def test_node_mesh_2d_matches_trial_mesh():
                 node.level_messages, other.level_messages)
         print("NODE MESH OK", kw["eps"])
 
-    # guardrails: the node-sharded path is presampled-only and cannot
-    # collect per-edge usage (counters live sharded)
-    try:
-        execute_plan(plan, x0, seeds=(0,),
-                     options=ExecOptions(mesh=mesh2d, schedule="per_tick"))
-        raise AssertionError("per_tick + node mesh must be rejected")
-    except ValueError:
-        pass
+    # guardrail: the node-sharded path cannot collect per-edge usage
+    # (counters live sharded)
     try:
         execute_plan(plan, x0, seeds=(0,),
                      options=ExecOptions(mesh=mesh2d, collect_usage=True))

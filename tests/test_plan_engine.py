@@ -1,6 +1,6 @@
 """Plan/execute core: batched-router parity with the scalar router,
 CSR attribution parity with the legacy dict-based crawl, trial vmapping
-consistency, per-trial mass conservation, and backend agreement."""
+consistency, per-trial mass conservation, and the removed options."""
 import numpy as np
 import pytest
 
@@ -11,6 +11,7 @@ from repro.core import (
     batched_routes_to_nodes,
     build_plan,
     execute_plan,
+    gossip_until,
     greedy_route,
     multiscale_gossip,
     random_geometric_graph,
@@ -167,34 +168,21 @@ def test_trials_accounting_per_trial(rgg500, x0_500):
         assert res.node_sends[t].sum() == res.messages[t]
 
 
-# ----------------------------- backends --------------------------------
+# ------------------------- removed options ------------------------------
 
 
-def test_pallas_backend_matches_lax():
-    g = random_geometric_graph(120, seed=5)
-    x0 = np.random.default_rng(2).normal(0, 1, 120)
-    plan = build_plan(g, seed=0)
-    a = multiscale_gossip(
-        g, x0, eps=1e-4, seed=0, weighted=True, plan=plan,
-        options=ExecOptions(backend="lax"),
-    )
-    b = multiscale_gossip(
-        g, x0, eps=1e-4, seed=0, weighted=True, plan=plan,
-        options=ExecOptions(backend="pallas"),
-    )
-    # identical exchange sequence => identical message/send accounting;
-    # the kernel (interpreted off the TPU) keeps the oracle's f32 op
-    # sequence, so values are bitwise equal too
-    assert a.messages == b.messages
-    np.testing.assert_array_equal(a.node_sends, b.node_sends)
-    np.testing.assert_array_equal(a.x_final, b.x_final)
-
-
-def test_unknown_backend_rejected(rgg500, x0_500):
-    with pytest.raises(ValueError):
-        multiscale_gossip(
-            rgg500, x0_500, options=ExecOptions(backend="cuda")
-        )
+@pytest.mark.parametrize("name,value", [
+    ("backend", "pallas"), ("schedule", "per_tick"), ("interpret", True),
+], ids=["backend", "schedule", "interpret"])
+def test_removed_exec_options_fail_loudly(name, value):
+    """The executor has one value pass: a stale choice of it is a
+    TypeError, in the options and in the host gossip API alike."""
+    with pytest.raises(TypeError):
+        ExecOptions(**{name: value})
+    with pytest.raises(TypeError):
+        gossip_until(np.zeros((1, 2)), np.zeros((1, 2, 1), np.int32),
+                     np.ones((1, 2), np.int32), np.array([2]), eps=1e-3,
+                     **{name: value})
 
 
 def test_single_level_plan_counts_reps():

@@ -4,13 +4,13 @@
 per-group loop construction; `method="vectorized"` (the default) is the
 large-n rewrite.  The two must be BITWISE-interchangeable: every level's
 CSR arrays, routes, and election outcomes identical — and therefore the
-executed simulation (messages, usage counters, x) identical too, for
-both the lax backend and the pallas kernel in interpret mode.
+executed simulation (messages, usage counters, x) identical too,
+whichever endpoint read the value pass takes.
 """
 import numpy as np
 import pytest
 
-from repro.core import ExecOptions, build_plan, execute_plan
+from repro.core import ExecOptions, build_plan, execute_plan, value_pass
 from repro.core.plan import PLAN_METHODS
 
 _LP_ARRAY_FIELDS = (
@@ -54,17 +54,20 @@ def test_plan_methods_bitwise_identical(rgg500):
         }
 
 
-@pytest.mark.parametrize("backend", ["lax", "pallas"])
-def test_plan_methods_execute_identically(rgg500, x0_500, backend):
+@pytest.mark.parametrize("read", ["lax", "gather"])
+def test_plan_methods_execute_identically(rgg500, x0_500, read, monkeypatch):
     """fig3-sized end-to-end: messages, flat usage counters, and x are
-    identical between the two builders under the presampled engine."""
+    identical between the two builders under the presampled engine,
+    with each level's endpoint read as its shape picks (``lax``) or
+    forced to the gather."""
+    if read == "gather":
+        monkeypatch.setattr(value_pass, "value_read_path",
+                            lambda B, C: "gather")
     plans = _plans(rgg500)
     results = {
         m: execute_plan(
             p, x0_500, eps=1e-4, seeds=(0,), weighted=True,
-            options=ExecOptions(
-                backend=backend, interpret=True, collect_usage=True,
-            ),
+            options=ExecOptions(collect_usage=True),
         )
         for m, p in plans.items()
     }

@@ -1,28 +1,39 @@
-"""Presampled-schedule parity vs the legacy per-tick scan.
+"""The library's chunk against the per-tick oracle (`per_tick_oracle`).
 
-The schedule/value split must be invisible to the simulation: the lax
-and pallas backends are BITWISE-identical to the legacy sequential scan
-(x, edge_usage, messages, ticks — including the `loss_p` failure path),
-and the matmul backend keeps the integer accounting bitwise while its
-values agree up to f32 rounding (matrix composition reassociates the
-pair-average sums; same caveat the historical pallas branch carried).
+The schedule/value split must be invisible to the simulation: gossip
+with the presampled schedule and the pair walk of `core.value_pass` is
+BITWISE-identical to the sequential sample-and-apply scan (x,
+edge_usage, messages, ticks — including the `loss_p` failure path),
+whichever endpoint read the value pass takes.
 """
 import numpy as np
 import pytest
 
+import per_tick_oracle as oracle
 from repro.core import (
     ExecOptions,
+    FailureModel,
     batched_graphs,
     build_plan,
-    compose_schedule,
     execute_plan,
+    gossip,
     gossip_until,
-    multiscale_gossip,
     random_geometric_graph,
     sample_schedule,
     sample_tick,
+    value_pass,
 )
-from repro.kernels.pair_apply import pair_apply, pair_apply_ref
+from repro.core.value_pass import pair_apply
+
+
+@pytest.fixture(params=["select", "gather"])
+def forced_read(request, monkeypatch):
+    """Every value pass reads its endpoints by one path, whatever the
+    shape; `gossip_until` traces its loop anew, so the force holds."""
+    path = request.param
+    monkeypatch.setattr(value_pass, "value_read_path", lambda B, C: path)
+    monkeypatch.setattr(gossip, "_gossip_loop", oracle.fresh_gossip_loop())
+    return path
 
 
 def _ring(n):
@@ -55,71 +66,36 @@ def _assert_int_parity(a, b):
 # ------------------------ gossip-loop parity ---------------------------
 
 
-@pytest.mark.parametrize("backend", ["lax", "pallas"])
-def test_presampled_bitwise_eps_oracle(backend):
+def test_presampled_bitwise_eps_oracle(forced_read):
     args = _gossip_args(seed=1)
-    legacy = gossip_until(*args, eps=1e-4, seed=3, schedule="per_tick")
-    new = gossip_until(
-        *args, eps=1e-4, seed=3, schedule="presampled", backend=backend,
-        interpret=True,
-    )
+    legacy = oracle.gossip_until_per_tick(*args, eps=1e-4, seed=3)
+    new = gossip_until(*args, eps=1e-4, seed=3)
     np.testing.assert_array_equal(legacy.x, new.x)
     _assert_int_parity(legacy, new)
 
 
-@pytest.mark.parametrize("backend", ["lax", "pallas", "matmul"])
-def test_presampled_parity_fixed_ticks_loss(backend):
-    """The paper's failure path: fixed budget, per-hop loss.  All
-    accounting is schedule-only, so it is bitwise for every backend;
-    values are bitwise for lax/pallas and allclose for matmul."""
+def test_presampled_parity_fixed_ticks_loss(forced_read):
+    """The paper's failure path: fixed budget, per-hop loss."""
     args = _gossip_args(seed=2)
     kw = dict(eps=-1.0, seed=7, fixed_ticks=384, loss_p=0.8)
-    legacy = gossip_until(*args, schedule="per_tick", **kw)
-    new = gossip_until(
-        *args, schedule="presampled", backend=backend, interpret=True, **kw
-    )
+    legacy = oracle.gossip_until_per_tick(*args, **kw)
+    new = gossip_until(*args, **kw)
     _assert_int_parity(legacy, new)
-    if backend == "matmul":
-        np.testing.assert_allclose(legacy.x, new.x, rtol=2e-5, atol=2e-6)
-    else:
-        np.testing.assert_array_equal(legacy.x, new.x)
+    np.testing.assert_array_equal(legacy.x, new.x)
 
 
-def test_presampled_parity_batched_weighted():
+def test_presampled_parity_batched_weighted(forced_read):
     gs = [_ring(8), _ring(24), _ring(40)]
     neighbors, degrees, n_nodes, mask = batched_graphs(gs)
     rng = np.random.default_rng(5)
     x = np.where(mask, rng.normal(0, 1, mask.shape), 0.0)
     w = np.where(mask, rng.uniform(0.5, 2.0, mask.shape), 0.0)
     x0 = np.stack([x * w, w], axis=-1).astype(np.float32)
-    legacy = gossip_until(
-        x0, neighbors, degrees, n_nodes, eps=1e-4, seed=9,
-        schedule="per_tick",
-    )
+    legacy = oracle.gossip_until_per_tick(
+        x0, neighbors, degrees, n_nodes, eps=1e-4, seed=9)
     new = gossip_until(x0, neighbors, degrees, n_nodes, eps=1e-4, seed=9)
     np.testing.assert_array_equal(legacy.x, new.x)
     _assert_int_parity(legacy, new)
-
-
-def test_per_tick_pallas_matches_lax_accounting():
-    """The kept legacy pallas branch (eye hoisted out of the chunk
-    body) must still produce the identical exchange sequence."""
-    args = _gossip_args(seed=3)
-    a = gossip_until(*args, eps=1e-3, seed=11, schedule="per_tick")
-    b = gossip_until(
-        *args, eps=1e-3, seed=11, schedule="per_tick", backend="pallas",
-        interpret=True,
-    )
-    _assert_int_parity(a, b)
-    np.testing.assert_allclose(a.x, b.x, rtol=1e-4, atol=1e-5)
-
-
-def test_schedule_mode_validation():
-    args = _gossip_args()
-    with pytest.raises(ValueError):
-        gossip_until(*args, eps=1e-3, schedule="clairvoyant")
-    with pytest.raises(ValueError):
-        gossip_until(*args, eps=1e-3, schedule="per_tick", backend="matmul")
 
 
 # -------------------------- schedule pass ------------------------------
@@ -268,77 +244,6 @@ def test_select_lookups_match_gather(case, loss_p):
     assert ("gather" in hlo) == (path == "gather")
 
 
-def test_compose_schedule_is_stochastic_and_matches_ref():
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(6)
-    T, B, C, V = 48, 3, 12, 2
-    i = jnp.asarray(rng.integers(0, C, (T, B)), jnp.int32)
-    j = jnp.asarray((rng.integers(1, C, (T, B)) + np.asarray(i)) % C,
-                    jnp.int32)
-    ui = jnp.asarray(rng.uniform(size=(T, B)) < 0.8)
-    uj = jnp.asarray(rng.uniform(size=(T, B)) < 0.9)
-    m = compose_schedule(C, i, j, ui, uj)
-    # each elementary matrix is row-stochastic, so the composition is too
-    np.testing.assert_allclose(np.asarray(m).sum(-1), 1.0, atol=1e-5)
-    x = jnp.asarray(rng.normal(size=(B, C, V)), jnp.float32)
-    want = pair_apply_ref(x, i, j, ui, uj)
-    got = jnp.einsum("bij,bjv->biv", m, x)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-6)
-
-
-# ------------------------- pair_apply kernel ---------------------------
-
-
-@pytest.mark.parametrize("B,C,V,T", [(1, 8, 1, 16), (3, 13, 2, 64)])
-def test_pair_apply_kernel_bitwise_vs_oracle(B, C, V, T):
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(B * T)
-    x = jnp.asarray(rng.normal(size=(B, C, V)), jnp.float32)
-    i = jnp.asarray(rng.integers(0, C, (T, B)), jnp.int32)
-    j = jnp.asarray(rng.integers(0, C, (T, B)), jnp.int32)
-    ui = jnp.asarray(rng.uniform(size=(T, B)) < 0.8)
-    uj = jnp.asarray(rng.uniform(size=(T, B)) < 0.9)
-    want = pair_apply_ref(x, i, j, ui, uj)
-    got = pair_apply(x, i, j, ui, uj, use_pallas=True, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-@pytest.mark.parametrize("block_b", [1, 2, 3, 4])
-@pytest.mark.parametrize("B,C,V,T", [(7, 5, 1, 32), (16, 9, 2, 48)])
-def test_pair_apply_tiled_bitwise_any_block(B, C, V, T, block_b):
-    """Tiling must be invisible: every block size — including blocks
-    smaller than the batch and batches that are NOT a block multiple
-    (ops pads with all-masked pass-through schedules) — reproduces the
-    oracle bitwise, because cells never interact."""
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(B * T + block_b)
-    x = jnp.asarray(rng.normal(size=(B, C, V)), jnp.float32)
-    i = jnp.asarray(rng.integers(0, C, (T, B)), jnp.int32)
-    j = jnp.asarray(rng.integers(0, C, (T, B)), jnp.int32)
-    ui = jnp.asarray(rng.uniform(size=(T, B)) < 0.8)
-    uj = jnp.asarray(rng.uniform(size=(T, B)) < 0.9)
-    want = pair_apply_ref(x, i, j, ui, uj)
-    got = pair_apply(
-        x, i, j, ui, uj, use_pallas=True, interpret=True, block_b=block_b
-    )
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_pair_apply_noop_when_masked():
-    import jax.numpy as jnp
-
-    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 9, 1)),
-                    jnp.float32)
-    i = jnp.zeros((12, 2), jnp.int32)
-    off = jnp.zeros((12, 2), bool)
-    got = pair_apply_ref(x, i, i, off, off)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
-
-
 # (B, C, V, T): both sides of `value_read_path`'s rule, a one-slot cell
 # among them; every case has ticks with i == j, all-masked ticks and
 # -0.0 among the values
@@ -349,15 +254,9 @@ _VALUE_READ_CASES = [
 ]
 
 
-@pytest.mark.parametrize("B,C,V,T", _VALUE_READ_CASES)
-def test_value_read_paths_give_the_same_bits(B, C, V, T, monkeypatch):
-    """`pair_apply_ref` reads x[i], x[j] by a select over a cell's slots
-    or by gathers, as `value_read_path` picks from the shape: either
-    path, forced, gives the rule's result bit for bit, signs of zero
-    included."""
+def _value_case(B, C, V, T):
+    """x, i, j, upd_i, upd_j for one of `_VALUE_READ_CASES`."""
     import jax.numpy as jnp
-
-    from repro.kernels.pair_apply import ref
 
     rng = np.random.default_rng(B * C + V * T)
     x = rng.normal(size=(B, C, V)).astype(np.float32)
@@ -369,13 +268,44 @@ def test_value_read_paths_give_the_same_bits(B, C, V, T, monkeypatch):
     ui = rng.uniform(size=(T, B)) < 0.8
     uj = rng.uniform(size=(T, B)) < 0.9
     ui[1::5] = uj[1::5] = False                      # all-masked ticks
-    args = [jnp.asarray(a) for a in (x, i, j, ui, uj)]
-    want = np.asarray(pair_apply_ref(*args)).view(np.uint32)
+    return [jnp.asarray(a) for a in (x, i, j, ui, uj)]
+
+
+@pytest.mark.parametrize("B,C,V,T", _VALUE_READ_CASES)
+def test_pair_apply_matches_sequential_updates(B, C, V, T):
+    """The value pass gives the bits of the same pair list applied tick
+    by tick with scatters, signs of zero included."""
+    args = _value_case(B, C, V, T)
+    want = np.asarray(oracle.sequential_pair_apply(*args)).view(np.uint32)
+    got = np.asarray(pair_apply(*args)).view(np.uint32)
+    np.testing.assert_array_equal(got, want)
+    assert (want == np.float32(-0.0).view(np.uint32)).any()
+
+
+@pytest.mark.parametrize("B,C,V,T", _VALUE_READ_CASES)
+def test_value_read_paths_give_the_same_bits(B, C, V, T, monkeypatch):
+    """`pair_apply` reads x[i], x[j] by a select over a cell's slots or
+    by gathers, as `value_read_path` picks from the shape: either path,
+    forced, gives the rule's result bit for bit, signs of zero
+    included."""
+    args = _value_case(B, C, V, T)
+    want = np.asarray(pair_apply(*args)).view(np.uint32)
     for path in ("select", "gather"):
-        monkeypatch.setattr(ref, "value_read_path", lambda B, C: path)
-        got = np.asarray(pair_apply_ref(*args)).view(np.uint32)
+        monkeypatch.setattr(value_pass, "value_read_path", lambda B, C: path)
+        got = np.asarray(pair_apply(*args)).view(np.uint32)
         np.testing.assert_array_equal(got, want, err_msg=path)
     assert (want == np.float32(-0.0).view(np.uint32)).any()
+
+
+def test_pair_apply_noop_when_masked(forced_read):
+    import jax.numpy as jnp
+
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 9, 1)),
+                    jnp.float32)
+    i = jnp.zeros((12, 2), jnp.int32)
+    off = jnp.zeros((12, 2), bool)
+    got = pair_apply(x, i, i, off, off)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
 
 
 @pytest.mark.parametrize("B,C,path", [
@@ -384,44 +314,36 @@ def test_value_read_paths_give_the_same_bits(B, C, V, T, monkeypatch):
     (16, 101, "gather"),
 ])
 def test_value_read_path_rule(B, C, path):
-    from repro.kernels.pair_apply.ref import value_read_path
-
-    assert value_read_path(B, C) == path
+    assert value_pass.value_read_path(B, C) == path
 
 
 # --------------------------- engine parity -----------------------------
 
 
-def test_engine_presampled_matches_per_tick():
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["plain", "weighted"])
+@pytest.mark.parametrize("mode", ["eps", "fi-loss"])
+def test_engine_presampled_matches_per_tick(mode, weighted, monkeypatch):
+    """The whole executor, every level, with the per-tick oracle swapped
+    in for the chunk body: the same bits and counts.  Each run builds
+    its own plan, so no executor traced before the swap is reused."""
     g = random_geometric_graph(120, seed=5)
     x0 = np.random.default_rng(2).normal(0, 1, 120)
-    plan = build_plan(g, seed=0)
-    legacy = execute_plan(
-        plan, x0, eps=1e-4, seeds=(0,), weighted=True,
-        options=ExecOptions(schedule="per_tick"),
-    )
-    new = execute_plan(plan, x0, eps=1e-4, seeds=(0,), weighted=True)
+    kw = dict(eps=1e-4, seeds=(0, 1), weighted=weighted,
+              options=ExecOptions(collect_usage=True))
+    if mode == "fi-loss":
+        kw.update(eps=1e-3, fixed_ticks_scale=0.3,
+                  failures=FailureModel(loss_p=0.8))
+    new = execute_plan(build_plan(g, seed=0), x0, **kw)
+    monkeypatch.setattr(gossip, "_presampled_chunk", oracle.per_tick_chunk)
+    legacy = execute_plan(build_plan(g, seed=0), x0, **kw)
     np.testing.assert_array_equal(legacy.x_final, new.x_final)
     np.testing.assert_array_equal(legacy.messages, new.messages)
     np.testing.assert_array_equal(legacy.node_sends, new.node_sends)
     np.testing.assert_array_equal(legacy.level_ticks, new.level_ticks)
-
-
-def test_engine_matmul_backend():
-    g = random_geometric_graph(100, seed=6)
-    x0 = np.random.default_rng(3).normal(0, 1, 100)
-    plan = build_plan(g, seed=0)
-    a = multiscale_gossip(
-        g, x0, eps=1e-4, seed=0, weighted=True, plan=plan,
-        options=ExecOptions(backend="lax"),
-    )
-    b = multiscale_gossip(
-        g, x0, eps=1e-4, seed=0, weighted=True, plan=plan,
-        options=ExecOptions(backend="matmul"),
-    )
-    assert a.messages == b.messages
-    np.testing.assert_array_equal(a.node_sends, b.node_sends)
-    np.testing.assert_allclose(a.x_final, b.x_final, atol=2e-4, rtol=1e-4)
+    np.testing.assert_array_equal(legacy.level_messages, new.level_messages)
+    for a, b in zip(legacy.edge_usage, new.edge_usage, strict=True):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_engine_single_device_mesh_matches_unsharded():

@@ -177,7 +177,7 @@ def test_a_build_records_each_levels_value_pass_lookup():
     slots, then 256, 64 and 16 cells of 4 and one of 16): one event per
     level on a build, none on a hit, with the path `value_read_path`
     picks for the level's shape."""
-    from repro.kernels.pair_apply.ref import value_read_path
+    from repro.core.value_pass import value_read_path
 
     plan, _ = setup_plan(n=2000, c=3.0, graph_seed=100, a=2 / 3,
                          cell_max=8.0, seed=0, rep_mode="random",

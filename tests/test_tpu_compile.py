@@ -1,16 +1,16 @@
-"""Compile-only rehearsals of the value-pass kernels and the schedule's
-lookups for a TPU v5e.
+"""Compile-only rehearsals of the value pass, the cell-mixing kernel and
+the schedule's lookups for a TPU v5e.
 
 The TPU compiler is installed with JAX and compiles for a chip that is
-described, not attached, so these tests catch what the Pallas
-interpreter cannot: block shapes that break the (8, 128) tiling rule,
-primitives Mosaic cannot lower, VMEM/SMEM budgets.  Nothing runs.
+described, not attached, so these tests catch what the CPU and the
+Pallas interpreter cannot: block shapes that break the (8, 128) tiling
+rule, primitives Mosaic cannot lower, gathers left in a program meant
+to have none, scratch memory.  Nothing runs.
 
-Shapes come from `setup_plan(n=10**6, graph_seed=1001000)`: the finest
-cells (B=337,504 graphs of C=13 nodes), the B=100, C=25 overlay and the
+Shapes come from `setup_plan(n=10**6, graph_seed=1001000)`: its six
+levels, from the finest cells (B=337,504 graphs of C=13 nodes) to the
 single top overlay of C=100 nodes, with V=2 (the weighted variant) and
-T=64 ticks per chunk; the schedule's lookups compile at all six of its
-levels.
+T=64 ticks per chunk.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library.
@@ -39,25 +39,6 @@ def _compile_text(fn, *shapes):
     import jax
 
     return jax.jit(fn).lower(*shapes).compile().as_text()
-
-
-@pytest.mark.parametrize("B,C", [(337_504, 13), (100, 25), (1, 100)])
-def test_pair_apply_compiles_for_v5e(one_chip, B, C):
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels.pair_apply import pair_apply
-
-    T, V = 64, 2
-    x = jax.ShapeDtypeStruct((B, C, V), jnp.float32, sharding=one_chip)
-    ij = jax.ShapeDtypeStruct((T, B), jnp.int32, sharding=one_chip)
-    upd = jax.ShapeDtypeStruct((T, B), jnp.bool_, sharding=one_chip)
-    text = _compile_text(
-        lambda x, i, j, ui, uj: pair_apply(
-            x, i, j, ui, uj, use_pallas=True, interpret=False),
-        x, ij, ij, upd, upd,
-    )
-    assert "tpu_custom_call" in text
 
 
 def test_cell_mixing_compiles_for_v5e(one_chip):
@@ -130,21 +111,21 @@ def test_sample_schedule_compiles_for_v5e(one_chip, trials, B, C, D, hops):
     assert scratch <= 4 * T * trials * B * 4
 
 
-# (trials, B, C): the n=10^6 cells, the paper's cells under its
-# ten-trial batch, the n=10^6 top level and wide cells of a k=2 plan
+# (trials, B, C): the six levels at n=10^6, the paper's cells under its
+# ten-trial batch and wide cells of a k=2 plan
 @pytest.mark.parametrize("trials,B,C", [
-    (1, 337_504, 13), (10, 875, 8), (1, 1, 100), (1, 4, 49),
+    (1, 337_504, 13), (1, 89_996, 4), (1, 22_500, 4), (1, 2_500, 9),
+    (1, 100, 25), (1, 1, 100), (10, 875, 8), (1, 4, 49),
 ])
 def test_value_pass_compiles_for_v5e(one_chip, trials, B, C):
-    """The lax value pass lowers to the read `value_read_path` picks: a
+    """The value pass lowers to the read `value_read_path` picks: a
     select level keeps no gather after compiling and its select chain
     stays fused (scratch memory no more than one copy of the state, and
     1 MiB besides for the gather's buffers at a few cells)."""
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.pair_apply import pair_apply_ref
-    from repro.kernels.pair_apply.ref import value_read_path
+    from repro.core.value_pass import pair_apply, value_read_path
 
     T, V = 64, 2
     path = value_read_path(B, C)
@@ -152,10 +133,10 @@ def test_value_pass_compiles_for_v5e(one_chip, trials, B, C):
                              sharding=one_chip)
     ij = jax.ShapeDtypeStruct((trials, T, B), jnp.int32, sharding=one_chip)
     upd = jax.ShapeDtypeStruct((trials, T, B), jnp.bool_, sharding=one_chip)
-    fn = jax.vmap(pair_apply_ref)
+    fn = jax.vmap(pair_apply)
     if trials == 1:
         def fn(x, i, j, ui, uj):
-            return pair_apply_ref(x[0], i[0], j[0], ui[0], uj[0])[None]
+            return pair_apply(x[0], i[0], j[0], ui[0], uj[0])[None]
     lowered = jax.jit(fn).lower(x, ij, ij, upd, upd)
     assert ("gather" in lowered.as_text()) == (path == "gather")
     compiled = lowered.compile()

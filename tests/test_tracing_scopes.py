@@ -13,7 +13,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import ExecOptions, build_plan, execute_plan, setup_plan
+from repro.core import build_plan, execute_plan, setup_plan, value_pass
 from repro.core import random_geometric_graph
 from repro.core.gossip import LAYER_SCOPES
 
@@ -51,12 +51,18 @@ def test_scopes_read_through_transform_wrappers():
 
 @pytest.mark.parametrize("weighted", [False, True], ids=["plain", "weighted"])
 @pytest.mark.parametrize("trials", [1, 3], ids=["T1", "T3"])
-@pytest.mark.parametrize("backend", ["lax", "pallas"])
-def test_every_executor_op_carries_one_layer_scope(plan300, x300, backend,
-                                                   trials, weighted):
+@pytest.mark.parametrize("read", ["lax", "gather"])
+def test_every_executor_op_carries_one_layer_scope(plan300, x300, read,
+                                                   trials, weighted,
+                                                   monkeypatch):
+    """Each level's endpoint read as its shape picks (``lax``), or every
+    level's forced to the gather."""
+    if read == "gather":
+        monkeypatch.setattr(value_pass, "value_read_path",
+                            lambda B, C: "gather")
     plan300.exec_cache.clear()
     execute_plan(plan300, x300, eps=1e-3, seeds=tuple(range(trials)),
-                 weighted=weighted, options=ExecOptions(backend=backend))
+                 weighted=weighted)
     (fn,) = plan300.exec_cache.values()
     per_layer, unscoped, traced = collections.Counter(), [], 0
     for line in fn.as_text().splitlines():

@@ -5,10 +5,9 @@ Re-runs the fig2 smoke (same n / eps / seeds as the committed run —
 the benchmark is deterministic, so honest drift comes from algorithm
 changes, not noise) and compares per-level-count message means against
 `benchmarks/artifacts/fig2_levels.json` within a relative tolerance.
-The same gate then covers the fig3 smoke: each backend-suffixed
-committed artifact (`fig3_smoke_lax`, `fig3_smoke_pallas`) is re-run at
-its recorded n / eps / trials and the per-algorithm message means are
-compared within the same tolerance (wall-clocks are machine-dependent
+The same gate then covers the fig3 smoke: the committed artifact
+`fig3_smoke_lax` is re-run at its recorded n / eps / trials and the
+per-algorithm message means are compared within the same tolerance (wall-clocks are machine-dependent
 and NOT gated).  Artifact drift then fails CI loudly instead of being
 silently committed the next time someone regenerates the artifacts.
 
@@ -36,7 +35,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 COMMITTED = "fig2_levels"
 SCRATCH = "fig2_levels_check"
-FIG3_BACKENDS = ("lax", "pallas")
+FIG3 = "fig3_smoke_lax"
 LARGE_N = "large_n_smoke"
 FIG5 = "fig5_smoke"
 ROBUST_TRAIN = "robust_train_smoke"
@@ -46,52 +45,47 @@ GRAPH_GEN_FLOOR_S = 0.5
 
 
 def check_fig3(tolerance: float) -> list[str]:
-    """Gate the backend-suffixed fig3 smoke message counts."""
+    """Gate the fig3 smoke message counts."""
     from benchmarks import fig3_vs_path_averaging
     from benchmarks.common import load_artifact
 
-    failures = []
-    for backend in FIG3_BACKENDS:
-        name = f"fig3_smoke_{backend}"
-        committed = load_artifact(name)
-        if committed is None:
-            failures.append(
-                f"  {name}: committed artifact benchmarks/artifacts/"
+    name = FIG3
+    committed = load_artifact(name)
+    if committed is None:
+        return [f"  {name}: committed artifact benchmarks/artifacts/"
                 f"{name}.json is missing; run REPRO_BENCH_SMOKE=1 "
-                f"tools/ci.sh and commit the result")
-            continue
-        sizes = tuple(sorted({
-            int(n) for rows in committed["summary"].values() for n in rows
-        }))
-        print(f"check_artifacts: re-running fig3 smoke (backend={backend}, "
-              f"sizes={sizes}, trials={committed['trials']}, "
-              f"eps={committed['eps']}) against {name} "
-              f"(tolerance ±{tolerance:.0%})")
-        fig3_vs_path_averaging.run(
-            sizes=sizes, trials=int(committed["trials"]),
-            eps=float(committed["eps"]), backend=backend,
-            artifact=f"{name}_check",
-        )
-        fresh = load_artifact(f"{name}_check")
-        for algo, rows in committed["summary"].items():
-            for n, rec in rows.items():
-                want = float(rec["messages_mean"])
-                got_rec = fresh["summary"].get(algo, {}).get(
-                    n, fresh["summary"].get(algo, {}).get(str(n)))
-                if got_rec is None:
-                    failures.append(
-                        f"  {name} {algo}@n{n}: missing from the fresh run")
-                    continue
-                got = float(got_rec["messages_mean"])
-                rel = abs(got - want) / max(want, 1.0)
-                status = "ok" if rel <= tolerance else "DRIFT"
-                print(f"  {backend}/{algo}@n{n}: committed={want:.0f} "
-                      f"fresh={got:.0f} rel={rel:+.1%} [{status}]")
-                if rel > tolerance:
-                    failures.append(
-                        f"  {name} {algo}@n{n}: messages_mean drifted "
-                        f"{rel:.1%} (committed {want:.0f} -> fresh {got:.0f},"
-                        f" tolerance {tolerance:.0%})")
+                f"tools/ci.sh and commit the result"]
+    sizes = tuple(sorted({
+        int(n) for rows in committed["summary"].values() for n in rows
+    }))
+    print(f"check_artifacts: re-running fig3 smoke (sizes={sizes}, "
+          f"trials={committed['trials']}, eps={committed['eps']}) against "
+          f"{name} (tolerance ±{tolerance:.0%})")
+    fig3_vs_path_averaging.run(
+        sizes=sizes, trials=int(committed["trials"]),
+        eps=float(committed["eps"]), artifact=f"{name}_check",
+    )
+    fresh = load_artifact(f"{name}_check")
+    failures = []
+    for algo, rows in committed["summary"].items():
+        for n, rec in rows.items():
+            want = float(rec["messages_mean"])
+            got_rec = fresh["summary"].get(algo, {}).get(
+                n, fresh["summary"].get(algo, {}).get(str(n)))
+            if got_rec is None:
+                failures.append(
+                    f"  {name} {algo}@n{n}: missing from the fresh run")
+                continue
+            got = float(got_rec["messages_mean"])
+            rel = abs(got - want) / max(want, 1.0)
+            status = "ok" if rel <= tolerance else "DRIFT"
+            print(f"  {algo}@n{n}: committed={want:.0f} "
+                  f"fresh={got:.0f} rel={rel:+.1%} [{status}]")
+            if rel > tolerance:
+                failures.append(
+                    f"  {name} {algo}@n{n}: messages_mean drifted "
+                    f"{rel:.1%} (committed {want:.0f} -> fresh {got:.0f},"
+                    f" tolerance {tolerance:.0%})")
     return failures
 
 
@@ -130,8 +124,7 @@ def check_fig5(tolerance: float) -> list[str]:
           f"against {FIG5} (tolerance ±{tolerance:.0%})")
     fig5_failures.run(
         n=int(committed["n"]), eps=float(committed["eps"]), ps=ps,
-        trials=int(committed["trials"]), backend=committed["backend"],
-        schedule=committed.get("schedule", "presampled"),
+        trials=int(committed["trials"]),
         scenario_trials=int(sm["trials"]),
         scenario_scale=float(sm["fixed_ticks_scale"]),
         scenario_retransmit_p=float(sm["retransmit_p"]),
@@ -304,14 +297,14 @@ def check_large_n(tolerance: float) -> list[str]:
     overlap_n = int((committed.get("overlap") or {}).get("n", 2000))
     print(f"check_artifacts: re-running large-n smoke "
           f"(n={committed['n']}, scale={committed['fixed_ticks_scale']}, "
-          f"backend={committed['backend']}, overlap_n={overlap_n}) against "
+          f"overlap_n={overlap_n}) against "
           f"{LARGE_N} (tolerance ±{tolerance:.0%})")
     try:
         large_n.run(
             n=int(committed["n"]), overlap_n=overlap_n,
             trials=int(committed["trials"]), eps=float(committed["eps"]),
             fixed_ticks_scale=float(committed["fixed_ticks_scale"]),
-            backend=committed["backend"], artifact=f"{LARGE_N}_check",
+            artifact=f"{LARGE_N}_check",
         )
     except SystemExit as e:  # overlap-parity failure inside the benchmark
         return [f"  {LARGE_N}: {e}"]

@@ -20,11 +20,8 @@
 #                                   #   the reference-vs-vectorized
 #                                   #   plan-builder overlap parity)
 #                                   # + fig3 device-resident smoke
-#                                   #   (n=500, trials=1, both engine
-#                                   #   backends — backend-suffixed
-#                                   #   artifacts so the pallas run does
-#                                   #   not clobber the lax run's
-#                                   #   wall-clock/backend record), then
+#                                   #   (n=500, trials=1, its own
+#                                   #   fig3_smoke_lax artifact), then
 #                                   #   an entry appended to the
 #                                   #   BENCH_gossip.json perf trajectory
 #                                   # + compressed decentralized-train smoke
@@ -43,7 +40,7 @@
 #                                   #   consensus error / loss / degradation
 #                                   #   metrics ±15%)
 #
-# The slow tier (multi-device subprocess + vmap-/backend-parity tests) is
+# The slow tier (multi-device subprocess + vmap-parity tests) is
 # NOT run here — .github/workflows/ci.yml's second job runs `-m slow`.
 # A bare `python -m pytest -x -q` still runs both tiers.
 set -euo pipefail
@@ -66,14 +63,11 @@ if [[ "${1:-}" != "--no-bench" ]]; then
 fi
 
 if [[ "${REPRO_BENCH_SMOKE:-0}" == "1" ]]; then
-    # scratch artifact names: the smoke must not clobber the full-run
-    # artifact, and each backend writes its own record
-    echo "== benchmark smoke (fig3 n=500 trials=1, backend=lax) =="
+    # a scratch artifact name: the smoke must not clobber the full-run
+    # artifact
+    echo "== benchmark smoke (fig3 n=500 trials=1) =="
     python -m benchmarks.fig3_vs_path_averaging --sizes 500 --trials 1 \
-        --backend lax --artifact fig3_smoke_lax
-    echo "== benchmark smoke (fig3 n=500 trials=1, backend=pallas) =="
-    python -m benchmarks.fig3_vs_path_averaging --sizes 500 --trials 1 \
-        --backend pallas --artifact fig3_smoke_pallas
+        --artifact fig3_smoke_lax
     echo "== large-n smoke gate (n=20k FI, CSR path, ±15% vs committed) =="
     python tools/check_artifacts.py --large-n-only
     echo "== gossip perf trajectory (BENCH_gossip.json) =="

@@ -68,19 +68,16 @@ def gossip_memory_report(
     eps: float = 1e-3,
     fixed_ticks_scale: float = 0.2,
     trials: int = 1,
-    backend: str = "lax",
     method: str = "vectorized",
 ) -> dict:
     """Build and execute a multiscale plan at size `n`, reporting the
     peak host RSS and live device-buffer bytes alongside the
     `build_seconds` breakdown.  Defaults mirror the large-n benchmark
-    profile (fixed-iterations mode, lax backend, one trial).
+    profile (fixed-iterations mode, one trial).
     """
     import numpy as np
 
-    from repro.core import (
-        ExecOptions, build_plan, execute_plan, random_geometric_graph,
-    )
+    from repro.core import build_plan, execute_plan, random_geometric_graph
 
     g = random_geometric_graph(n, seed=1000 + n)
     x0 = np.random.default_rng(n).normal(0, 1, n)
@@ -88,7 +85,6 @@ def gossip_memory_report(
     res = execute_plan(
         plan, x0, eps=eps, seeds=tuple(seed + t for t in range(trials)),
         weighted=True, fixed_ticks_scale=fixed_ticks_scale,
-        options=ExecOptions(backend=backend),
     )
     report = memory_report()
     report.update(
