@@ -5,11 +5,13 @@ One call runs all K levels of multiscale gossip end-to-end in a single
 compiled JAX function: per-level batched gossip (`gossip_core`),
 representative election (static, from the plan), Alg.-1 line-16
 reweighting and value promotion as gathers/scatters, send attribution as
-gathers through the plan's route-incidence CSR plus one scatter-add, and
-the dissemination down-pass as a gather — no host round-trips between
-levels.  Adjacency and usage counters are CSR end-to-end (flat
-per-directed-edge arrays from `LevelPlan`), so device memory scales with
-edge count, not with ``B*C*max_deg`` padding.
+one scatter-add per level (a cell level's per-slot endpoint counts
+through the slot's node id; an overlay level's flat per-edge counters
+through the plan's route-incidence CSR), and the dissemination
+down-pass as a gather — no host round-trips between levels.  Adjacency
+is CSR end-to-end (flat per-directed-edge arrays from `LevelPlan`), so
+device memory scales with edge count, not with ``B*C*max_deg``
+padding.
 
 The executor is `vmap`-ped over trial seeds, so `execute_plan(plan, x0,
 seeds=[s0..sT])` simulates T independent Monte-Carlo trials in one
@@ -123,7 +125,18 @@ class EngineResult:
         return trials_error(self.x_final, x0)
 
 
-def _level_consts(lp):
+def _usage_count(lp, collect_usage: bool) -> str:
+    """How `gossip_core` counts a level's usage.  A cell level's sends
+    go to the exchange's two endpoints alone, so unless the caller reads
+    the flat per-edge counters each slot's endpoint count is all the
+    attribution needs (``"slots"``); an overlay level's routes credit
+    relay nodes per edge (``"edges"``)."""
+    if lp.kind == "cells" and not collect_usage:
+        return "slots"
+    return "edges"
+
+
+def _level_consts(lp, usage_count: str):
     c = {
         "adj": jax.tree.map(jnp.asarray, CsrGraphs.from_flat(
             lp.nbr_start, lp.nbr_flat, lp.hop_flat, lp.degrees, lp.n_nodes)),
@@ -131,9 +144,11 @@ def _level_consts(lp):
         "slot_node": jnp.asarray(lp.slot_node, jnp.int32),
     }
     if lp.kind == "cells":
-        # per-flat-entry owner/partner global ids (sentinel = trash slot n)
-        c["row_node"] = jnp.asarray(lp.row_node, jnp.int32)
-        c["partner_flat"] = jnp.asarray(lp.partner_flat, jnp.int32)
+        if usage_count == "edges":
+            # per-flat-entry owner/partner global ids (sentinel = trash
+            # slot n); a slot count attributes through slot_node
+            c["row_node"] = jnp.asarray(lp.row_node, jnp.int32)
+            c["partner_flat"] = jnp.asarray(lp.partner_flat, jnp.int32)
     else:
         for name in ("edge_pos_i", "edge_pos_j",
                      "inc_node", "inc_edge", "inc_count"):
@@ -314,7 +329,11 @@ def execute_plan(
     its schedule reads partners and hops (`CsrGraphs.lookup`),
     ``/repro/core/value_pass_lookup``, with how `pair_apply` reads a
     tick's endpoints in one node block (``path`` select or gather,
-    `core.value_pass.value_read_path`),
+    `core.value_pass.value_read_path`), ``/repro/core/usage_count``,
+    with how the level counts usage (``path`` slots, each slot's
+    endpoint sends by one-hot adds, on a cell level unless
+    `collect_usage` asks for the flat counters; else edges, the flat
+    per-edge scatter),
     ``/repro/core/executor_consts``, with the ``bytes`` of the level's
     plan arrays baked into the executor as constants, and in
     fixed-iterations mode ``/repro/core/fixed_ticks``, with the level's
@@ -418,6 +437,7 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
             eps_levels.append(float(eps))
             maxt_levels.append(int(max_ticks_per_level))
             chk_levels.append(int(check_every))
+    counts = [_usage_count(lp, collect_usage) for lp in plan.levels]
     # filled only when the executor must be (re)traced: a cache hit never
     # touches the plan's big constant arrays again.  fail_ctxs holds the
     # per-level scenario flags (slot-mapped failure sets + static event
@@ -442,7 +462,8 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
         lvl_retx, lvl_cong = [], []
         xb = None
         frozen_vals = None
-        for li, (lp, c, chk) in enumerate(zip(plan.levels, consts, chk_levels)):
+        for li, (lp, c, chk, count) in enumerate(
+                zip(plan.levels, consts, chk_levels, counts)):
             with _scope(f"level_{li}"):
                 B = lp.num_graphs
                 with _scope("schedule"):
@@ -476,6 +497,7 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
                     node_shard=shard,
                     failure_ctx=fail_ctxs[li] if scenario else None,
                     cost_model=cost, hop_cap=max(1, int(lp.max_hops)),
+                    usage_count=count,
                 )
                 if cost is not None:
                     x, usage, msgs, done, ticks, retx_l, cong_l = out
@@ -507,13 +529,21 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
                         e0 = (x[..., 0] if V == 1
                               else x[..., 0] / jnp.maximum(x[..., 1], 1e-30))
                         frozen_vals = e0[fz["graph0"], fz["slot0"]]
-                # attribution: gathers through the plan CSR + one scatter-add
-                # per level.  Under node sharding `usage` is the shard's
-                # partial flat counter (both directed entries of an overlay
-                # edge live in one graph, hence one shard), so the partial
+                # attribution: one scatter-add per level, of the slot
+                # counts through the node ids promotion read x0 by, or of
+                # the flat counter through the plan CSR.  A padding slot
+                # is never an endpoint (i < n_nodes, j a neighbour), so
+                # clipping sends its zero count to node 0; reusing the
+                # clipped ids keeps one folded constant where a second
+                # index array would add another (17.55 MB at n=10^6).
+                # Under node sharding `usage` is the shard's partial
+                # counter (both directed entries of an overlay edge live
+                # in one graph, hence one shard), so the partial
                 # node_sends just psum at the end.
                 with _scope("accounting"):
-                    if lp.kind == "cells":
+                    if count == "slots":
+                        node_sends = node_sends.at[slots].add(usage.T)
+                    elif lp.kind == "cells":
                         node_sends = node_sends.at[c["row_node"]].add(usage)
                         node_sends = node_sends.at[
                             c["partner_flat"]].add(usage)
@@ -604,7 +634,8 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
         with _span("repro.execute_plan.build"):
             t0 = time.perf_counter()
             with _span("repro.execute_plan.build.lower"):
-                consts.extend(_level_consts(lp) for lp in plan.levels)
+                consts.extend(_level_consts(lp, count)
+                              for lp, count in zip(plan.levels, counts))
                 if scenario:
                     ctxs, freeze = _failure_consts(
                         plan, failures, maxt_levels, n)
@@ -626,6 +657,8 @@ def _executor(plan, x0, *, eps, seeds, weighted, fixed_ticks_scale,
             B, C = plan.levels[li].node_mask.shape
             record_event("/repro/core/value_pass_lookup", level=li,
                          path=value_pass.value_read_path(-(-B // nd), C))
+            record_event("/repro/core/usage_count", level=li,
+                         path=counts[li])
             record_event("/repro/core/executor_consts", level=li,
                          bytes=sum(a.nbytes for a in jax.tree.leaves(c)))
             if fixed_ticks_scale > 0:

@@ -23,17 +23,22 @@ Schedule / value split: every exchange decision depends only on
 ``(key, t)``, never on the values, so each `check_every` chunk first
 presamples its full ``(T, B)`` exchange schedule in one batched RNG pass
 (`core.schedule.sample_schedule` — usage and message accounting become
-one scatter-add / one reduction over the presampled arrays), then
+reductions over the presampled arrays), then
 applies the pair list with `core.value_pass.pair_apply`: a scan whose
 body is just two endpoint reads (one-hot selects over a cell's slots,
 or gathers, by the level's shape), one average, and two conditional
 writes.  Only the values are sequential, so only they pay for a scan.
 
 Adjacency is CSR-addressed (`core.schedule.CsrGraphs`): one flat entry
-per directed edge instead of ``(B, C, D)`` dense padding, with usage
-counted in a flat ``(nnz+1,)`` buffer via a 1-D scatter on the sampled
-`pos` field.  `gossip_until` keeps the historical dense host API — it
-packs dense inputs with `dense_to_csr` and scatters flat usage back to
+per directed edge instead of ``(B, C, D)`` dense padding.  Usage is
+counted one of two ways, the caller's static choice (`usage_count`):
+per directed edge, in a flat ``(nnz+1,)`` buffer via a 1-D scatter on
+the sampled `pos` field (``"edges"``), or per slot, in a ``(C, B)``
+table of how many exchanges each slot was an endpoint of, by one-hot
+adds over the chunk's ticks (``"slots"``, `slot_sends`): no scatter,
+and all a caller needs that attributes sends to the endpoints alone.
+`gossip_until` keeps the historical dense host API — it packs dense
+inputs with `dense_to_csr` and scatters flat usage back to
 ``(B, C, D)`` for `GossipResult`.
 
 Node sharding (`node_shard=(cols, ok)`): a shard owns columns `cols` of
@@ -83,7 +88,7 @@ from .schedule import (
 from .value_pass import pair_apply
 
 __all__ = ["GossipResult", "gossip_core", "gossip_until", "batched_graphs",
-           "LAYER_SCOPES"]
+           "slot_sends", "LAYER_SCOPES"]
 
 LAYER_SCOPES = ("schedule", "value_pass", "accounting", "convergence_check",
                 "promote")
@@ -125,11 +130,14 @@ def gossip_core(
     failure_ctx: Optional[FailureCtx] = None,
     cost_model: Optional[CostModel] = None,
     hop_cap: int = 1,
+    usage_count: str = "edges",
 ):
     """Pure-JAX batched gossip loop; composable under jit and vmap.
 
-    Returns (x, usage, msgs, done, ticks) where usage is the flat
-    ``(nnz+1,)`` per-directed-edge counter aligned with `adj`; with
+    Returns (x, usage, msgs, done, ticks) where usage is, by the static
+    `usage_count`, the flat ``(nnz+1,)`` per-directed-edge counter
+    aligned with `adj` (``"edges"``) or the ``(C, B)`` int32 table of
+    each slot's endpoint sends (``"slots"``, see `slot_sends`); with
     `cost_model` set, two extra per-graph arrays are appended —
     (retransmissions, congestion_pairs) — priced from the presampled
     schedule with RNG streams disjoint from the exchange streams, so
@@ -149,7 +157,8 @@ def gossip_core(
     `x0`/`node_mask` are the local ``(Bs, C, …)`` slices, sampling stays
     global (see module docstring), and the returned x/msgs/done/ticks
     are local while usage stays global-flat (adds land only at the
-    shard's own edges).
+    shard's own edges), or, counted by slots, covers the shard's own
+    columns.
     """
     if node_shard is not None and (
             failure_ctx is not None or cost_model is not None):
@@ -174,7 +183,7 @@ def gossip_core(
 
     chunk = _presampled_chunk(
         adj, key, loss_p, check_every, err, tol,
-        node_shard, failure_ctx, cost_model, hop_cap,
+        node_shard, failure_ctx, cost_model, hop_cap, usage_count,
     )
 
     def cond(carry):
@@ -182,7 +191,10 @@ def gossip_core(
             return (~jnp.all(carry[3])) & (carry[-1] < max_ticks)
 
     with _scope("accounting"):
-        usage0 = jnp.zeros((adj.num_entries,), jnp.int32)
+        if usage_count == "slots":
+            usage0 = jnp.zeros(x0.shape[1::-1], jnp.int32)   # (C, B)
+        else:
+            usage0 = jnp.zeros((adj.num_entries,), jnp.int32)
         msgs0 = jnp.zeros(x0.shape[:1], jnp.int32)
         ticks0 = jnp.zeros(x0.shape[:1], jnp.int32)
         if cost_model is not None:
@@ -203,10 +215,11 @@ def gossip_core(
 
 def _presampled_chunk(adj, key, loss_p, check_every, err, tol,
                       node_shard=None, failure_ctx=None, cost_model=None,
-                      hop_cap=1):
+                      hop_cap=1, usage_count="edges"):
     """Chunk body for the schedule/value split: one batched RNG pass for
-    the whole chunk, accounting as a single scatter-add + reduction,
-    then the value pass over the presampled pair list.
+    the whole chunk, accounting as reductions over it (usage as one
+    scatter-add or, by slots, one-hot adds), then the value pass over
+    the presampled pair list.
 
     `failure_ctx` perturbs the schedule before the value pass (scenario
     injection); `cost_model` adds pure reductions over the schedule
@@ -262,7 +275,11 @@ def _presampled_chunk(adj, key, loss_p, check_every, err, tol,
                 # forward leg; straggler stalls burn the full exchange cost
                 cost_t = jnp.where(attempt & ~down_j, s.cost, s.hops)
         with _scope("accounting"):
-            usage = usage.at[s.pos].add(attempt.astype(jnp.int32))
+            if usage_count == "slots":
+                usage = usage + slot_sends(s.i, s.j, attempt,
+                                           usage.shape[0])
+            else:
+                usage = usage.at[s.pos].add(attempt.astype(jnp.int32))
             hops_t = jnp.where(attempt, cost_t, 0)
             msgs = msgs + hops_t.sum(0)
             if sample_retx:
@@ -296,6 +313,19 @@ def _presampled_chunk(adj, key, loss_p, check_every, err, tol,
         return out + (t1,)
 
     return chunk
+
+
+def slot_sends(i, j, attempt, C):
+    """``(C, B)`` int32: how many of the ``(T, B)`` exchanges `i`–`j`
+    that `attempt` marks had slot c of graph b as an endpoint, the
+    initiator and the partner each counted once.  A one-hot
+    compare-and-add over the C slots that XLA fuses into one reduction
+    over the ticks: no scatter, and no ``(T, C, B)`` intermediate in
+    memory.  Exact in int32."""
+    c = jnp.arange(C, dtype=i.dtype)[None, :, None]
+    ends = ((i[:, None, :] == c).astype(jnp.int32)
+            + (j[:, None, :] == c).astype(jnp.int32))
+    return jnp.sum(jnp.where(attempt[:, None, :], ends, 0), axis=0)
 
 
 @partial(

@@ -22,8 +22,8 @@ Adjacency is CSR-addressed (`CsrGraphs`): the ``(B, C, D)`` dense
 padded arrays of the historical path wasted O(B*C*D) memory on the
 degree spread; a sampled tick carries `pos`, the flat index of the drawn
 directed edge in the level's CSR order (one entry per directed edge plus
-a single trailing sentinel), so usage counters live in a flat
-``(nnz+1,)`` buffer and accounting is a 1-D scatter-add.  The lookups
+a single trailing sentinel), so per-edge usage counters live in a flat
+``(nnz+1,)`` buffer and counting them is a 1-D scatter-add.  The lookups
 that turn a draw into a degree, a row start, a partner and a hop count
 are compare-and-selects over the drawn graph's slots, not gathers, but
 for the partner of a level with wide rows (see `CsrGraphs`).

@@ -43,13 +43,13 @@ def sequential_pair_apply(x, i, j, upd_i, upd_j):
 
 def per_tick_chunk(adj, key, loss_p, check_every, err, tol,
                    node_shard=None, failure_ctx=None, cost_model=None,
-                   hop_cap=1):
+                   hop_cap=1, usage_count="edges"):
     """Chunk body that samples and applies each tick in turn."""
     if node_shard is not None or failure_ctx is not None \
-            or cost_model is not None:
+            or cost_model is not None or usage_count != "edges":
         raise NotImplementedError(
             "the per-tick oracle runs neither node shards, failure "
-            "scenarios nor cost pricing")
+            "scenarios, cost pricing nor slot counts")
 
     def tick(state, t):
         x, usage, msgs, done = state

@@ -325,7 +325,9 @@ def test_node_mesh_2d_matches_trial_mesh():
     """The (trials, nodes) 2-D mesh blocks every level's graph batch
     over the nodes axis (halo exchange only at promotion boundaries);
     results must be bitwise-equal to both the unsharded run and the
-    1-axis trial mesh, in the eps-oracle AND fixed-iterations modes."""
+    1-axis trial mesh, in the eps-oracle AND fixed-iterations modes, and
+    its per-slot usage counts attribute the flat per-edge counters'
+    sends."""
     out = _run("""
     from jax.sharding import Mesh
     from repro.core import (
@@ -357,6 +359,22 @@ def test_node_mesh_2d_matches_trial_mesh():
                 node.level_messages, other.level_messages)
         print("NODE MESH OK", kw["eps"])
 
+    # the cell level counts each slot's endpoint sends over the shard's
+    # own columns: node_sends against no mesh, and against the flat
+    # per-edge counters there
+    from repro.core import FailureModel
+    kw = dict(eps=1e-3, fixed_ticks_scale=1.0, seeds=(5,),
+              failures=FailureModel(loss_p=0.8))
+    node = execute_plan(plan, x0, options=ExecOptions(mesh=mesh2d), **kw)
+    dense = execute_plan(plan, x0, **kw)
+    flat = execute_plan(plan, x0, options=ExecOptions(collect_usage=True),
+                        **kw)
+    for other in (dense, flat):
+        np.testing.assert_array_equal(node.node_sends, other.node_sends)
+        np.testing.assert_array_equal(node.messages, other.messages)
+        np.testing.assert_array_equal(node.x_final, other.x_final)
+    print("NODE MESH SLOTS OK")
+
     # guardrail: the node-sharded path cannot collect per-edge usage
     # (counters live sharded)
     try:
@@ -368,4 +386,5 @@ def test_node_mesh_2d_matches_trial_mesh():
     print("NODE MESH GUARDS OK")
     """)
     assert out.count("NODE MESH OK") == 2
+    assert "NODE MESH SLOTS OK" in out
     assert "NODE MESH GUARDS OK" in out

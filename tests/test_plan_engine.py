@@ -1,11 +1,14 @@
 """Plan/execute core: batched-router parity with the scalar router,
-CSR attribution parity with the legacy dict-based crawl, trial vmapping
+CSR attribution parity with the legacy dict-based crawl, slot counts
+against the flat per-edge counters, trial vmapping
 consistency, per-trial mass conservation, and the removed options."""
 import numpy as np
 import pytest
 
 from repro.core import (
+    CostModel,
     ExecOptions,
+    FailureModel,
     Graph,
     batched_greedy_routes,
     batched_routes_to_nodes,
@@ -125,6 +128,50 @@ def test_csr_attribution_matches_legacy_dict(rgg500, x0_500):
         np.add.at(base_sends, ids[nbr[valid]], u[valid])
     expect = base_sends + overlay_total + (1 if plan.disseminate else 0)
     np.testing.assert_array_equal(res.node_sends[0], expect)
+
+
+# ----------------------- slot counts vs flat usage ----------------------
+
+
+@pytest.fixture(scope="module")
+def plan500(rgg500):
+    return build_plan(rgg500, seed=0)
+
+
+FI = dict(eps=1e-3, fixed_ticks_scale=0.3)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(eps=1e-4, seeds=(0,)),
+    dict(eps=1e-4, seeds=(0, 1, 2), weighted=True),
+    dict(FI, seeds=(4,), weighted=True),
+    dict(FI, seeds=(4, 5, 6)),
+    dict(FI, seeds=(7, 8, 9), weighted=True,
+         failures=FailureModel(loss_p=0.8)),
+    dict(FI, seeds=(1, 2), weighted=True,
+         failures=FailureModel(churn_fraction=0.2, churn_time=0.3,
+                               straggler_fraction=0.1, seed=5)),
+    dict(eps=1e-4, seeds=(3,), weighted=True,
+         cost=CostModel(retransmit_p=0.7, congestion_alpha=0.2)),
+], ids=["eps-V1-T1", "eps-V2-T3", "fi-V2-T1", "fi-V1-T3", "fi-loss-T3",
+        "fi-scenario-T2", "eps-cost-T1"])
+def test_slot_counts_match_flat_usage(plan500, x0_500, kw):
+    """A cell level's default count (each slot's endpoint sends, by
+    one-hot adds) against the flat per-edge counter that
+    `collect_usage` keeps: the same bits in every output."""
+    slots = execute_plan(plan500, x0_500, **kw)
+    flat = execute_plan(plan500, x0_500,
+                        options=ExecOptions(collect_usage=True), **kw)
+    assert slots.edge_usage == [] and len(flat.edge_usage) == \
+        len(plan500.levels)
+    for name in ("node_sends", "messages", "level_messages", "level_ticks",
+                 "x_final"):
+        np.testing.assert_array_equal(getattr(slots, name),
+                                      getattr(flat, name), err_msg=name)
+    if "cost" in kw:
+        for name in ("retransmissions", "congestion", "energy"):
+            np.testing.assert_array_equal(getattr(slots.cost, name),
+                                          getattr(flat.cost, name))
 
 
 # --------------------------- trial vmapping ----------------------------

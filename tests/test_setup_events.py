@@ -20,7 +20,7 @@ import pytest
 
 from repro.core import (ExecOptions, build_plan, event_totals, execute_plan,
                         random_geometric_graph, setup_plan)
-from repro.core.engine import _level_consts, fi_ticks
+from repro.core.engine import _level_consts, _usage_count, fi_ticks
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -132,7 +132,8 @@ def test_a_build_records_each_levels_constant_bytes(plan300, x300):
         miss = rec.named("/repro/core/executor_consts")
         execute_plan(plan300, x300, eps=1e-3, seeds=(3, 4))
     want = [{"level": li, "bytes": sum(
-        a.nbytes for a in jax.tree.leaves(_level_consts(lp)))}
+        a.nbytes for a in jax.tree.leaves(
+            _level_consts(lp, _usage_count(lp, False))))}
         for li, lp in enumerate(plan300.levels)]
     assert miss == want
     assert all(w["bytes"] > 0 for w in want)
@@ -193,6 +194,36 @@ def test_a_build_records_each_levels_value_pass_lookup():
     assert [value_read_path(*bc) for bc in shapes] == paths
     assert miss == [{"level": li, "path": p} for li, p in enumerate(paths)]
     assert rec.named("/repro/core/value_pass_lookup") == miss   # a hit
+
+
+@pytest.mark.parametrize("collect_usage", [False, True],
+                         ids=["default", "collect_usage"])
+def test_a_build_records_each_levels_usage_count(plan300, x300,
+                                                 collect_usage):
+    """One event per level on a build, none on a hit: the cell level
+    counts by slots and the overlay levels by edges, or every level by
+    edges where the flat counters are read.  A slot count leaves the
+    cell level's per-edge owner and partner ids out of the program."""
+    plan300.exec_cache.clear()
+    opts = ExecOptions(collect_usage=collect_usage)
+    with _Events() as rec:
+        execute_plan(plan300, x300, eps=1e-3, seeds=(1,), options=opts)
+        miss = rec.named("/repro/core/usage_count")
+        consts = rec.named("/repro/core/executor_consts")
+        execute_plan(plan300, x300, eps=1e-3, seeds=(2,), options=opts)
+    kinds = [lp.kind for lp in plan300.levels]
+    assert kinds == ["cells", "overlay", "overlay", "overlay"]
+    paths = ["edges"] * len(kinds) if collect_usage else \
+        ["slots", "edges", "edges", "edges"]
+    assert miss == [{"level": li, "path": p} for li, p in enumerate(paths)]
+    assert rec.named("/repro/core/usage_count") == miss   # a hit
+    base = plan300.levels[0]
+    flat_ids = base.row_node.nbytes + base.partner_flat.nbytes
+    assert flat_ids > 0
+    edges0 = sum(a.nbytes for a in jax.tree.leaves(
+        _level_consts(base, "edges")))
+    assert consts[0]["bytes"] == (edges0 if collect_usage
+                                  else edges0 - flat_ids)
 
 
 def _digest(a: np.ndarray) -> str:

@@ -1,11 +1,11 @@
-"""Compile-only rehearsals of the value pass, the cell-mixing kernel and
-the schedule's lookups for a TPU v5e.
+"""Compile-only rehearsals of the value pass, the cell-mixing kernel,
+the schedule's lookups and the slot count for a TPU v5e.
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached, so these tests catch what the CPU and the
 Pallas interpreter cannot: block shapes that break the (8, 128) tiling
-rule, primitives Mosaic cannot lower, gathers left in a program meant
-to have none, scratch memory.  Nothing runs.
+rule, primitives Mosaic cannot lower, gathers or scatters left in a
+program meant to have none, scratch memory.  Nothing runs.
 
 Shapes come from `setup_plan(n=10**6, graph_seed=1001000)`: its six
 levels, from the finest cells (B=337,504 graphs of C=13 nodes) to the
@@ -145,6 +145,27 @@ def test_value_pass_compiles_for_v5e(one_chip, trials, B, C):
     scratch = compiled.memory_analysis().temp_size_in_bytes
     assert scratch <= trials * B * C * V * 4 + 2**20
 
+
+# (trials, B, C): the finest cells at n=10^6 and the paper's cells under
+# its ten-trial batch
+@pytest.mark.parametrize("trials,B,C", [(1, 337_504, 13), (10, 875, 8)])
+def test_slot_count_compiles_for_v5e(one_chip, trials, B, C):
+    """A cell level's usage count over a 64-tick chunk lowers to no
+    scatter and keeps its one-hot compares fused: scratch memory no
+    more than one (trials, C, B) int32 table, and 1 MiB besides."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.gossip import slot_sends
+
+    T = 64
+    ij = jax.ShapeDtypeStruct((trials, T, B), jnp.int32, sharding=one_chip)
+    att = jax.ShapeDtypeStruct((trials, T, B), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(jax.vmap(
+        lambda i, j, a: slot_sends(i, j, a, C))).lower(ij, ij, att).compile()
+    assert "scatter" not in compiled.as_text()
+    scratch = compiled.memory_analysis().temp_size_in_bytes
+    assert scratch <= trials * C * B * 4 + 2**20
 
 
 def test_the_executor_holds_each_mask_as_floats_once(one_chip, monkeypatch):
